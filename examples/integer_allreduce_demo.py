@@ -14,19 +14,17 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
-from repro.launch.mesh import compat_make_mesh
-from repro.sharding.ops import compat_shard_map
 from repro.train.intreeger_allreduce import integer_psum, quantization_error_bound
 
-mesh = compat_make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
 rng = np.random.default_rng(0)
 g = rng.normal(size=(8, 4096)).astype(np.float32)  # 8 replicas' gradients
 
-int_sum = compat_shard_map(
+int_sum = jax.shard_map(
     lambda x: integer_psum(x, "data", 8), mesh=mesh,
-    in_specs=P("data"), out_specs=P("data"),
+    in_specs=P("data"), out_specs=P("data"), check_vma=False,
 )(g)
 int_sum = np.asarray(int_sum).reshape(8, -1)[0]
 

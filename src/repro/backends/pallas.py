@@ -1,9 +1,12 @@
 """PallasBackend: the VMEM-tiled TPU kernel behind the TreeBackend protocol.
 
 Wraps ``repro.kernels.ops.packed_predict_integer`` and owns the blocking
-decisions: the row/tree block sizes fed to the kernel (VMEM-budgeted via
-``pick_blocks``) and the ``preferred_block_rows`` hint that makes the serving
-layer pad batches to shapes aligned with the kernel's ``block_b`` tiling.
+decisions: the row/tree block sizes fed to the kernel (budgeted and
+tiling-aligned via ``pick_blocks``) and the ``preferred_block_rows`` hint
+that makes the serving layer pad batches to shapes aligned with the
+kernel's ``block_b`` tiling.  ``interpret=None`` (the default) compiles the
+kernel on TPU and interprets it on CPU; a kernel that fails to compile
+raises, with no fallback.
 
 Layout-specialized: the backend prefers the ``leaf_major`` layout, where the
 linear-scan kernel (``impl="leaf_major"``) walks each tree's internal-node
@@ -59,7 +62,7 @@ class PallasBackend(TreeBackend):
 
     def __init__(self, packed: PackedEnsemble, mode: str = "integer", *,
                  block_b: int = _DEFAULT_BLOCK_B, block_t: Optional[int] = None,
-                 impl: str = "auto", interpret: bool = True):
+                 impl: str = "auto", interpret: Optional[bool] = None):
         super().__init__(packed, mode)
         scannable = getattr(packed, "internal_counts", None) is not None
         was_auto = impl == "auto"

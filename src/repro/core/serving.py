@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ref import tree_predict_integer_ref
-from repro.sharding.ops import compat_shard_map, current_mesh
+from repro.sharding.ops import current_mesh
 
 
 def _local_predict(tables: dict, x_keys, depth: int):
@@ -45,10 +45,11 @@ def tree_serve_step(tables: dict, x_keys, depth: int):
     if mesh is None:
         return _local_predict(tables, x_keys, depth)
     axes = tuple(a for a in ("pod", "data", "model") if a in mesh.shape)
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         lambda t, x: _local_predict(t, x, depth),
         mesh=mesh,
         in_specs=(P(), P(axes, None)),
         out_specs=(P(axes, None), P(axes)),
+        check_vma=False,
     )
     return fn(tables, x_keys)

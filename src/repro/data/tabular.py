@@ -16,8 +16,9 @@ import numpy as np
 
 def make_shuttle_like(n: int = 58000, n_features: int = 7, n_classes: int = 7, seed: int = 0):
     rng = np.random.default_rng(seed)
-    # Shuttle-like imbalance: class 0 dominates.
-    weights = np.array([0.786, 0.1, 0.06, 0.03, 0.015, 0.006, 0.003])
+    # Shuttle-like imbalance: class 0 dominates; the 8th weight only serves
+    # n_classes=8 (the 7-class default slices it off, unchanged)
+    weights = np.array([0.786, 0.1, 0.06, 0.03, 0.015, 0.006, 0.003, 0.002])
     weights = weights[:n_classes] / weights[:n_classes].sum()
     y = rng.choice(n_classes, size=n, p=weights)
     centers = rng.normal(0, 3.0, size=(n_classes, n_features))

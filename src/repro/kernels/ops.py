@@ -1,8 +1,11 @@
-"""Jitted public wrapper around the tree-traversal Pallas kernel.
+"""Jitted public wrapper around the tree-traversal Pallas kernels.
 
-Handles padding (batch to ``block_b`` multiples, trees to ``block_t``
-multiples with inert self-looping zero-probability trees), VMEM budgeting,
-and exposes an ensemble-level entry point.
+Picks tiling-aligned blocks inside the VMEM/SMEM budgets, pads (rows to
+``block_b`` multiples, trees to ``block_t`` multiples and nodes to 128-node
+chunks, all with inert self-looping zero-mass entries), lays the tables out
+for the kernel (``tree_traverse`` module docstring), and exposes an
+ensemble-level entry point.  ``interpret=None`` lets the platform decide:
+compiled on TPU, interpreted on CPU.
 
 Layout contract (ForestIR): the kernel consumes dense ``(T, N)`` node tables
 — the IR's ``padded`` or ``leaf_major`` materializations (the paper's codegen
@@ -18,97 +21,139 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.flint import float_to_key
-from repro.kernels.tree_traverse import tree_traverse_leaf_major, tree_traverse_pallas
+from repro.kernels.tree_traverse import LANES, resolve_interpret, tree_traverse
 
-_VMEM_BUDGET_BYTES = 8 * 1024 * 1024  # stay well under ~16 MiB v5e VMEM
+# per-grid-cell budgets for the pipeline's double-buffered blocks; the v5e
+# compiler reports 1 MiB of SMEM, and VMEM's default scoped limit is larger
+# than this VMEM budget
+_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+_SMEM_BUDGET_BYTES = 512 * 1024
 
 # below this many rows a full-forest grid cell pays the whole per-cell scan
 # for a handful of rows; block_t is scaled down proportionally instead
 _TINY_BATCH_ROWS = 64
 
 
+def _round_up(v, m):
+    return -(-v // m) * m
+
+
 def _block_words(block_b, block_t, n, f, c):
-    """int32/uint32 words resident per grid cell: the x block, the four node
-    tables, the leaf table, the per-tree internal-count vector (leaf_major
-    working set), and the output block."""
-    return (
-        block_b * f
-        + block_t * n * 4
-        + block_t * n * c
-        + block_t
-        + block_b * c
-    )
+    """VMEM words per grid cell, two pipeline buffers per block: the x tiles,
+    the chunked node fields (4 rows, sublane-padded to 8), the chunked leaf
+    table and the output tiles, at the kernel's padded widths."""
+    n, c = _round_up(n, LANES), _round_up(c, 8)
+    return 2 * (block_b * _round_up(f, 8) + block_t * n * (8 + c)
+                + block_b * c)
+
+
+def _smem_words(block_t, n):
+    """SMEM words per grid cell: the scalar node fields (feature, key, left,
+    right) of ``block_t`` trees, two pipeline buffers."""
+    return 2 * block_t * 4 * _round_up(n, LANES)
+
+
+def _fits(block_b, block_t, n, f, c):
+    return (_block_words(block_b, block_t, n, f, c) * 4 <= _VMEM_BUDGET_BYTES
+            and _smem_words(block_t, n) * 4 <= _SMEM_BUDGET_BYTES)
+
+
+def _align_block_b(block_b, b):
+    """Rows per grid cell: a multiple of 128 (rows ride the lanes), no more
+    than the batch padded to 128."""
+    return max(LANES, min(_round_up(block_b, LANES), _round_up(b, LANES)))
+
+
+def _align_block_t(block_t, t):
+    """Trees per grid cell: a multiple of 8, or the whole tree dimension."""
+    return t if block_t >= t else min(t, _round_up(max(1, block_t), 8))
 
 
 def pick_blocks(b, t, n, f, c, block_b=256):
-    """Choose (block_b, block_t) so the working set fits the VMEM budget.
+    """Choose aligned (block_b, block_t) so a cell fits the VMEM/SMEM budgets.
 
-    The tree dimension shrinks first; when even ``block_t == 1`` is over
-    budget (wide leaf tables — ``c`` large relative to ``n`` — make the
-    ``block_b * c`` output block and the ``n * c`` leaf rows dominate), the
-    row block halves and the search repeats.  The floor is (1, 1): a single
-    row against a single tree, the smallest working set any tiling can have.
+    ``block_b`` is a multiple of 128 and ``block_t`` a multiple of 8 or
+    ``t``.  The tree dimension shrinks first; when even the smallest aligned
+    ``block_t`` is over budget (wide leaf tables make the output tiles and
+    leaf chunks dominate), the row block halves and the search repeats.  The
+    floor is (128, min(t, 8)), the smallest aligned tiling.
 
     Tiny batches (``b < 64``) additionally clamp ``block_t`` proportionally
-    to the rows that amortize it: a cell's tree scan costs the same whether
-    2 rows ride it or 256, so a full-forest tile against a handful of rows
-    is the pathological BENCH_7 ``b32`` case — all of the per-cell cost,
-    almost none of the row throughput.  VMEM fit is preserved (the clamp
-    only ever shrinks).
+    to the rows that amortize it (a heuristic from host timings; the clamp
+    only ever shrinks, so the fit is preserved).
     """
-    block_b = min(block_b, b)
+    block_b = _align_block_b(block_b, b)
+    sizes = [t] + list(range(_round_up(t, 8) - 8, 0, -8))
     while True:
-        for block_t in range(t, 0, -1):
-            if _block_words(block_b, block_t, n, f, c) * 4 <= _VMEM_BUDGET_BYTES:
+        for block_t in sizes:
+            if _fits(block_b, block_t, n, f, c):
                 if b < _TINY_BATCH_ROWS:
-                    block_t = min(
-                        block_t, max(1, (t * b) // _TINY_BATCH_ROWS)
-                    )
+                    block_t = min(block_t, _align_block_t(
+                        (t * b) // _TINY_BATCH_ROWS, t))
                 return block_b, block_t
-        if block_b == 1:
-            return 1, 1  # model-fixed minimum; nothing left to shrink
-        block_b //= 2
+        if block_b == LANES:
+            return LANES, sizes[-1]  # nothing left to shrink
+        block_b = _align_block_b(block_b // 2, b)
 
 
 def pick_blocks_candidates(b, t, n, f, c, block_b=256):
     """The measured-autotune grid around the heuristic: the ``pick_blocks``
-    choice plus its VMEM-feasible half/double neighbours along each axis.
+    choice plus its aligned, budget-feasible half/double neighbours along
+    each axis.
 
     The heuristic optimizes a *budget*, not a runtime; ``TreeEngine.warm``'s
     autotuner times these candidates on the live host and pins the winner.
     Deduplicated, heuristic first (ties resolve to it), every entry fits the
-    VMEM budget, so any candidate is safe to pin.
+    budgets, so any candidate is safe to pin.
     """
     auto_b, auto_t = pick_blocks(b, t, n, f, c, block_b)
     cands = [(auto_b, auto_t)]
     for bb, bt in (
-        (auto_b, max(1, auto_t // 2)),
-        (max(1, auto_b // 2), auto_t),
-        (auto_b, min(t, auto_t * 2)),
+        (auto_b, _align_block_t(auto_t // 2, t)),
+        (_align_block_b(auto_b // 2, b), auto_t),
+        (auto_b, _align_block_t(auto_t * 2, t)),
     ):
-        if (bb, bt) not in cands and \
-                _block_words(bb, bt, n, f, c) * 4 <= _VMEM_BUDGET_BYTES:
+        if (bb, bt) not in cands and _fits(bb, bt, n, f, c):
             cands.append((bb, bt))
     return cands
 
 
 @partial(jax.jit, static_argnames=("depth", "block_b", "block_t", "impl", "interpret"))
-def _traverse_padded(x_keys, feature, key, left, right, leaf, *, depth, block_b, block_t, impl, interpret):
-    return tree_traverse_pallas(
-        x_keys, feature, key, left, right, leaf,
-        depth=depth, block_b=block_b, block_t=block_t, impl=impl, interpret=interpret,
-    )
+def _traverse(x_keys, feature, key, left, right, leaf, nint, *,
+              depth, block_b, block_t, impl, interpret):
+    """Pad and lay out the (T, N) tables for the kernel, run it, and return
+    (B, C) uint32 partials."""
+    b, f = x_keys.shape
+    t, n = feature.shape
+    c = leaf.shape[-1]
+    bp, tp, npad = _round_up(b, block_b), _round_up(t, block_t), _round_up(n, LANES)
 
+    # inert padding: feature-less self-looping nodes with zero leaf mass
+    # fill every tree to npad nodes and the forest to tp trees
+    def pad(a, fill=0):
+        return jnp.pad(a, ((0, tp - t), (0, npad - n)), constant_values=fill)
 
-@partial(jax.jit, static_argnames=("block_b", "block_t", "interpret"))
-def _traverse_leaf_major(x_keys, feature, key, left, right, nint, leaf, *, block_b, block_t, interpret):
-    return tree_traverse_leaf_major(
-        x_keys, feature, key, left, right, nint, leaf,
-        block_b=block_b, block_t=block_t, interpret=interpret,
-    )
+    inside = (jnp.arange(tp)[:, None] < t) & (jnp.arange(npad)[None, :] < n)
+    selfloop = jnp.broadcast_to(jnp.arange(npad, dtype=jnp.int32), (tp, npad))
+    fields = jnp.stack([
+        pad(feature, -1),
+        pad(key),
+        jnp.where(inside, pad(left), selfloop),
+        jnp.where(inside, pad(right), selfloop),
+    ], axis=1)
+    leaf = jax.lax.bitcast_convert_type(
+        jnp.pad(leaf, ((0, tp - t), (0, npad - n), (0, 0))), jnp.int32)
+    leaf = leaf.reshape(tp, npad // LANES, LANES, c).transpose(0, 1, 3, 2)
+    x = jnp.pad(x_keys, ((0, bp - b), (0, 0)))
+    x = x.reshape(bp // LANES, LANES, f).transpose(0, 2, 1)
+    if nint is not None:  # padding trees have no internal prefix to scan
+        nint = jnp.pad(nint, (0, tp - t))
+    out = tree_traverse(x, fields, leaf, nint, depth=depth, block_b=block_b,
+                        block_t=block_t, impl=impl, interpret=interpret)
+    out = out.transpose(0, 2, 1).reshape(bp, c)[:b]
+    return jax.lax.bitcast_convert_type(out, jnp.uint32)
 
 
 def tree_predict_integer(
@@ -123,15 +168,18 @@ def tree_predict_integer(
     block_b: int = 256,
     block_t: int | None = None,
     impl: str = "gather",
-    interpret: bool = True,
+    interpret: bool | None = None,
     internal_counts=None,
 ):
     """Integer ensemble inference via the Pallas kernel, any B/T.
 
     ``impl="leaf_major"`` selects the linear-scan kernel and requires
     ``internal_counts`` (the leaf_major layout's per-tree internal-prefix
-    lengths); the other impls walk any node-table ordering.  Returns (B, C)
-    uint32 scores, bit-identical to ``ref.tree_predict_integer_ref``.
+    lengths); the other impls walk any node-table ordering.  ``block_b`` caps
+    the rows per grid cell and an explicit ``block_t`` is aligned up (see
+    :func:`pick_blocks`).  ``interpret=None`` lets the platform decide.
+    Returns (B, C) uint32 scores, bit-identical to
+    ``ref.tree_predict_integer_ref``.
     """
     if impl == "leaf_major" and internal_counts is None:
         raise ValueError(
@@ -142,38 +190,15 @@ def tree_predict_integer(
     b, f = x_keys.shape
     t, n = feature.shape
     c = leaf_fixed.shape[-1]
-    auto_b, auto_t = pick_blocks(b, t, n, f, c, block_b)
-    block_b = min(block_b, auto_b)
-    block_t = block_t or auto_t
-
-    pad_b = (-b) % block_b
-    pad_t = (-t) % block_t
-    if pad_b:
-        x_keys = jnp.pad(x_keys, ((0, pad_b), (0, 0)))
-    if pad_t:
-        # inert trees: all nodes are self-looping leaves with zero mass
-        feature = jnp.pad(feature, ((0, pad_t), (0, 0)), constant_values=-1)
-        threshold_key = jnp.pad(threshold_key, ((0, pad_t), (0, 0)))
-        selfloop = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (pad_t, n))
-        left = jnp.concatenate([left, selfloop], axis=0)
-        right = jnp.concatenate([right, selfloop], axis=0)
-        leaf_fixed = jnp.pad(leaf_fixed, ((0, pad_t), (0, 0), (0, 0)))
-
-    if impl == "leaf_major":
-        nint = jnp.asarray(internal_counts, jnp.int32)
-        if pad_t:  # inert trees have no internal prefix to scan
-            nint = jnp.pad(nint, (0, pad_t))
-        out = _traverse_leaf_major(
-            x_keys, feature, threshold_key, left, right, nint, leaf_fixed,
-            block_b=block_b, block_t=block_t, interpret=interpret,
-        )
-    else:
-        out = _traverse_padded(
-            x_keys, feature, threshold_key, left, right, leaf_fixed,
-            depth=depth, block_b=block_b, block_t=block_t, impl=impl,
-            interpret=interpret,
-        )
-    return out[:b]
+    block_b, auto_t = pick_blocks(b, t, n, f, c, block_b)
+    block_t = auto_t if block_t is None else _align_block_t(block_t, t)
+    nint = (jnp.asarray(internal_counts, jnp.int32)
+            if impl == "leaf_major" else None)
+    return _traverse(
+        x_keys, feature, threshold_key, left, right, leaf_fixed, nint,
+        depth=depth, block_b=block_b, block_t=block_t, impl=impl,
+        interpret=resolve_interpret(interpret),
+    )
 
 
 def packed_predict_integer(packed, X, impl: str = "auto", **kw):

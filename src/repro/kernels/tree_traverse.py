@@ -1,41 +1,39 @@
-"""Pallas TPU kernel: batched integer-only tree-ensemble traversal.
+"""Pallas TPU kernels: batched integer-only tree-ensemble traversal.
 
 TPU adaptation of the paper's if-else trees (DESIGN.md Sec. 2): branches
 become breadth-batched node-table walks.  One grid cell processes a block of
-``block_b`` examples against a block of ``block_t`` trees with all node tables
-resident in VMEM; examples advance one tree level per step; leaves self-loop.
-Class scores are uint32 fixed-point sums (paper Sec. III-A) — overflow-free by
-construction, so accumulation across tree-blocks is plain integer addition
-with no rescaling.
+rows against a block of ``block_t`` trees; leaves self-loop.  Class scores are
+uint32 fixed-point sums (paper Sec. III-A), accumulated here as int32 (the
+same bits: addition is identical mod 2^32) and bitcast back by the wrapper.
+
+Kernel-side layout (built by ``ops.py`` from the ForestIR ``(T, N)`` tables):
+rows ride the 128 lanes and node tables are cut into 128-node chunks, so
+every block is tiling-aligned and every lookup is a form Mosaic lowers.
+    x tiles:      (B/128, F, 128)          int32 keys, row r*128+l at [r, :, l]
+    node fields:  (T, 4, N)                feature, key, left, right (SMEM)
+    node chunks:  (T, N/128, 4, 128)       the same fields, chunked (VMEM)
+    leaf chunks:  (T, N/128, C, 128)       int32 bits of uint32 leaf values
+    out tiles:    (B/128, C, 128)          int32 bits of uint32 partials
+``N`` is padded to a multiple of 128 with inert self-looping nodes.
 
 Grid: ``(B/block_b, T/block_t)`` with the tree dimension innermost, so each
-output block stays resident while all tree-blocks accumulate into it
-(classic revisited-output reduction pattern).
-
-VMEM budget per cell (int32/uint32 words):
-    x block:      block_b * F
-    node tables:  block_t * N * 4          (feature, key, left, right)
-    leaf table:   block_t * N * C
-    out block:    block_b * C
-For the paper-scale ensembles (T<=100, depth<=8 -> N<=511, C<=7) everything
-fits in well under 1 MiB, far below the ~16 MiB v5e VMEM; ``ops.py`` checks
-the budget and splits the tree dimension when needed.
+output block stays resident while all tree-blocks accumulate into it.
+``ops.pick_blocks`` keeps the double-buffered blocks inside the VMEM and SMEM
+budgets; the v5e compiler reports 1 MiB of SMEM.
 
 Three walk strategies, selected statically:
-  * ``impl="gather"`` (default): ``jnp.take`` one-dim table gathers — lowers
-    to Mosaic ``dynamic_gather`` (supported on v4+) and is O(block_b) work per
-    level.
-  * ``impl="onehot"``: branch-free masked reductions (compare-iota + select +
-    sum) — O(block_b * N) work per level but uses only elementwise VPU ops;
-    portable to any Pallas target.  This mirrors how the paper leans on the
-    most basic ALU ops (load/add/compare) instead of specialized units.
-  * ``impl="leaf_major"`` (:func:`tree_traverse_leaf_major`): the layout-
-    specialized variant for ``leaf_major`` tables — a single forward linear
-    scan over each tree's internal-node prefix with compare+select steps
-    (children always sit after parents, so one pass routes every row), one
-    leaf gather per tree at the end.  Depth-many table gathers disappear;
-    the scan reads each node's fields exactly once per row block.
-All are validated against ``ref.py`` in interpret mode.
+  * ``impl="gather"``: per depth level, look up the current node's four
+    fields for every row with lane gathers (``tpu.dynamic_gather``), one
+    128-node chunk at a time; the row's feature value is a masked sum over
+    the feature axis.
+  * ``impl="onehot"``: per depth level, compare every row's node with every
+    node index and select that node's scalar fields (SMEM) on a hit — only
+    elementwise compare+select, O(block_b * N) per level.
+  * ``impl="leaf_major"``: the layout-specialized linear scan over each
+    tree's internal-node prefix (see :func:`_kernel_scan`).
+All three finish a tree with the same chunked leaf lookup, and all are
+bit-identical to ``ref.py`` (interpret-mode tests) and compile for the chip
+(``tests/test_tpu_compile.py``).
 """
 from __future__ import annotations
 
@@ -44,71 +42,117 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128  # rows per lane tile, and nodes per table chunk
+
+IMPLS = ("gather", "onehot", "leaf_major")
 
 
-def _gather_1d(row, idx, impl: str):
-    """row: (N,), idx: (B,) int32 -> (B,)."""
-    if impl == "gather":
-        return jnp.take(row, idx, axis=0)
-    # one-hot: (B, N) mask against iota, reduce over N.
-    n = row.shape[0]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], n), 1)
-    mask = iota == idx[:, None]
-    return jnp.sum(jnp.where(mask, row[None, :], jnp.zeros_like(row[None, :])), axis=1)
+@functools.cache
+def _default_interpret() -> bool:
+    backend = jax.default_backend()
+    if backend == "cpu":
+        return True
+    if backend == "tpu":
+        return False
+    raise RuntimeError(
+        f"the Pallas tree kernels run compiled on 'tpu' or interpreted on "
+        f"'cpu'; the default backend is {backend!r}"
+    )
 
 
-def _gather_rows(table, idx, impl: str):
-    """table: (N, C), idx: (B,) -> (B, C)."""
-    if impl == "gather":
-        return jnp.take(table, idx, axis=0)
-    n, c = table.shape
-    iota = jax.lax.broadcasted_iota(jnp.int32, (idx.shape[0], n), 1)
-    mask = (iota == idx[:, None])[:, :, None]
-    return jnp.sum(jnp.where(mask, table[None], jnp.zeros_like(table[None])), axis=1)
+def resolve_interpret(interpret: bool | None) -> bool:
+    """``None`` -> the platform decides (interpreter on CPU, compiled kernel
+    on TPU, anything else is an error); an explicit bool is kept, so a CPU
+    process can still lower for the chip's compiler."""
+    return _default_interpret() if interpret is None else bool(interpret)
 
 
-def _gather_feature(x, feat, impl: str):
-    """x: (B, F), feat: (B,) -> (B,) = x[i, feat[i]]."""
-    if impl == "gather":
-        return jnp.take_along_axis(x, feat[:, None], axis=1)[:, 0]
-    f = x.shape[1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    mask = iota == feat[:, None]
-    return jnp.sum(jnp.where(mask, x, jnp.zeros_like(x)), axis=1)
+def _chunk_lookup(chunks_ref, t, node):
+    """Column ``node[l]`` of tree ``t``'s chunked table, for every lane ``l``.
+
+    ``chunks_ref``: (block_t, N/128, K, 128); ``node``: (1, 128) int32.
+    Returns (K, 128).  Each chunk is one lane gather within a vreg; the
+    chunk whose index matches ``node >> 7`` wins the select.
+    """
+    k_rows = chunks_ref.shape[2]
+    hi = node >> 7
+    lo = jnp.broadcast_to(node & (LANES - 1), (k_rows, LANES))
+
+    def chunk(k, out):
+        got = jnp.take_along_axis(chunks_ref[t, k], lo, axis=1)
+        return jnp.where(hi == k, got, out)
+
+    return jax.lax.fori_loop(0, chunks_ref.shape[1], chunk,
+                             jnp.zeros((k_rows, LANES), jnp.int32))
 
 
-def _kernel(x_ref, feat_ref, key_ref, left_ref, right_ref, leaf_ref, out_ref, *, depth, block_t, impl):
+def _feature_values(x, feat):
+    """``x[..., feat[l], l]`` for every lane: a masked int32 sum over the
+    feature axis (-2).  ``x``: (..., F, 128); ``feat``: (..., 1, 128)."""
+    iota = jax.lax.broadcasted_iota(jnp.int32, x.shape, x.ndim - 2)
+    return jnp.sum(jnp.where(iota == feat, x, 0), axis=-2, keepdims=True)
+
+
+def _add_leaves(leaf_ref, t, node, out_ref):
+    """out tile r += tree t's leaf row at node[r], for every row tile."""
+    for r in range(node.shape[0]):
+        out_ref[r] += _chunk_lookup(leaf_ref, t, node[r])
+
+
+def _init_out(out_ref):
     @pl.when(pl.program_id(1) == 0)
-    def _init():
+    def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    x = x_ref[...]  # (block_b, F) int32 keys
-    bb = x.shape[0]
 
-    def per_tree(t, acc):
-        feat_t = feat_ref[t, :]
-        key_t = key_ref[t, :]
-        left_t = left_ref[t, :]
-        right_t = right_ref[t, :]
-        node = jnp.zeros((bb,), jnp.int32)
+def _kernel_gather(x_ref, nodes_ref, leaf_ref, out_ref, *, depth):
+    _init_out(out_ref)
+    block_t = nodes_ref.shape[0]
 
+    def per_row_tile(r, carry):
+        def per_tree(t, acc):
+            def level(_, node):
+                fields = _chunk_lookup(nodes_ref, t, node)  # (4, 128)
+                xv = _feature_values(x_ref[r], fields[0:1])
+                return jnp.where(xv <= fields[1:2], fields[2:3], fields[3:4])
+
+            node = jax.lax.fori_loop(0, depth, level,
+                                     jnp.zeros((1, LANES), jnp.int32))
+            return acc + _chunk_lookup(leaf_ref, t, node)
+
+        out_ref[r] += jax.lax.fori_loop(0, block_t, per_tree,
+                                        jnp.zeros(out_ref.shape[1:], jnp.int32))
+        return carry
+
+    jax.lax.fori_loop(0, x_ref.shape[0], per_row_tile, 0)
+
+
+def _kernel_onehot(x_ref, fields_ref, leaf_ref, out_ref, *, depth):
+    _init_out(out_ref)
+    block_t, _, n = fields_ref.shape
+    zeros = jnp.zeros((x_ref.shape[0], 1, LANES), jnp.int32)
+
+    def per_tree(t, carry):
         def level(_, node):
-            f = _gather_1d(feat_t, node, impl)
-            thr = _gather_1d(key_t, node, impl)
-            xv = _gather_feature(x, jnp.maximum(f, 0), impl)
-            nl = _gather_1d(left_t, node, impl)
-            nr = _gather_1d(right_t, node, impl)
+            def pick(j, fields):
+                hit = node == j
+                return tuple(jnp.where(hit, fields_ref[t, k, j], v)
+                             for k, v in enumerate(fields))
+
+            feat, thr, nl, nr = jax.lax.fori_loop(0, n, pick, (zeros,) * 4)
+            xv = _feature_values(x_ref[...], feat)
             return jnp.where(xv <= thr, nl, nr)
 
-        node = jax.lax.fori_loop(0, depth, level, node)
-        return acc + _gather_rows(leaf_ref[t, :, :], node, impl)
+        _add_leaves(leaf_ref, t, jax.lax.fori_loop(0, depth, level, zeros),
+                    out_ref)
+        return carry
 
-    acc = jax.lax.fori_loop(0, block_t, per_tree, jnp.zeros_like(out_ref[...]))
-    out_ref[...] += acc
+    jax.lax.fori_loop(0, block_t, per_tree, 0)
 
 
-def _kernel_leaf_major(x_ref, feat_ref, key_ref, left_ref, right_ref,
-                       nint_ref, leaf_ref, out_ref, *, block_t):
+def _kernel_scan(nint_ref, x_ref, fields_ref, leaf_ref, out_ref):
     """Linear-scan walk over the leaf_major layout's internal-node prefix.
 
     The layout guarantees (a) tree nodes are permuted internal-first, so
@@ -116,124 +160,90 @@ def _kernel_leaf_major(x_ref, feat_ref, key_ref, left_ref, right_ref,
     sits at a strictly larger index than its parent.  One forward pass over
     the prefix therefore routes every row to its leaf: when the scan reaches
     node j, any row currently parked at j steps to a child with index > j,
-    which a later scan step (or the final leaf gather) picks up.  Per node
-    the work is elementwise compare+select over the row block — no per-depth
-    node-table gathers at all; the only gather left is one leaf-row fetch per
-    (row, tree) at the end.  Rows parked on leaves are untouched by
-    construction (leaves self-loop), so scanning past a tree's real prefix
-    (padding nodes) is harmless and inert trees (n_internal == 0) skip the
-    scan entirely.
+    which a later scan step (or the final leaf lookup) picks up.  The
+    scanned node's fields are SMEM scalars and its feature is one row of the
+    x tiles, so each step is a broadcast compare+select over the row block.
+    Padding trees have ``n_internal == 0`` and skip the scan entirely.
     """
-    @pl.when(pl.program_id(1) == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
+    _init_out(out_ref)
+    block_t = fields_ref.shape[0]
+    t0 = pl.program_id(1) * block_t
 
-    x = x_ref[...]  # (block_b, F) int32 keys
-    bb = x.shape[0]
-
-    def per_tree(t, acc):
-        n_int = nint_ref[t]
-
+    def per_tree(t, carry):
         def scan_node(j, node):
-            feat = feat_ref[t, j]
-            thr = key_ref[t, j]
-            nl = left_ref[t, j]
-            nr = right_ref[t, j]
-            # the scanned node reads ONE feature column — a single dynamic
-            # slice, O(block_b) work, no per-row gather
-            xv = jax.lax.dynamic_slice_in_dim(
-                x, jnp.maximum(feat, 0), 1, axis=1
-            )[:, 0]
-            nxt = jnp.where(xv <= thr, nl, nr)
+            feat = jnp.maximum(fields_ref[t, 0, j], 0)
+            xv = x_ref[:, pl.ds(feat, 1), :]  # (R, 1, 128)
+            nxt = jnp.where(xv <= fields_ref[t, 1, j],
+                            fields_ref[t, 2, j], fields_ref[t, 3, j])
             return jnp.where(node == j, nxt, node)
 
-        node = jax.lax.fori_loop(0, n_int, scan_node, jnp.zeros((bb,), jnp.int32))
-        return acc + _gather_rows(leaf_ref[t, :, :], node, "gather")
+        node = jax.lax.fori_loop(
+            0, nint_ref[t0 + t], scan_node,
+            jnp.zeros((x_ref.shape[0], 1, LANES), jnp.int32))
+        _add_leaves(leaf_ref, t, node, out_ref)
+        return carry
 
-    acc = jax.lax.fori_loop(0, block_t, per_tree, jnp.zeros_like(out_ref[...]))
-    out_ref[...] += acc
+    jax.lax.fori_loop(0, block_t, per_tree, 0)
 
 
-def tree_traverse_leaf_major(
-    x_keys,
-    feature,
-    threshold_key,
-    left,
-    right,
-    internal_counts,
-    leaf_fixed,
-    *,
-    block_b: int = 256,
-    block_t: int | None = None,
-    interpret: bool = True,
-):
-    """Raw pallas_call over leaf_major tables; shapes must divide evenly.
+def tree_traverse(x_tiles, fields, leaf_chunks, internal_counts=None, *,
+                  depth: int, block_b: int, block_t: int, impl: str,
+                  interpret: bool | None = None):
+    """Raw pallas_call over the kernel-side layout (module docstring).
 
-    Same (B, C) uint32 contract as :func:`tree_traverse_pallas` but walks the
-    internal-node prefix front-to-back (``internal_counts`` is the layout's
-    per-tree prefix length) instead of gathering node fields per depth level.
+    Shapes must already divide evenly: ``block_b`` is a multiple of 128
+    dividing ``B``, and ``block_t`` divides ``T`` and is a multiple of 8 or
+    ``T`` itself (``ops.tree_predict_integer`` pads and aligns).  ``impl=
+    "leaf_major"`` needs ``internal_counts`` (T,).  Returns the (B/128, C,
+    128) int32 partial tiles.
     """
-    b, f = x_keys.shape
-    t, n = feature.shape
-    c = leaf_fixed.shape[-1]
-    block_t = block_t or t
-    assert b % block_b == 0 and t % block_t == 0
-    grid = (b // block_b, t // block_t)
+    r_tot, f, _ = x_tiles.shape
+    t, _, n = fields.shape
+    c = leaf_chunks.shape[2]
+    tiles = block_b // LANES
+    assert block_b % LANES == 0 and r_tot % tiles == 0 and t % block_t == 0
+    grid = (r_tot // tiles, t // block_t)
+    interpret = resolve_interpret(interpret)
 
-    kernel = functools.partial(_kernel_leaf_major, block_t=block_t)
+    def spec(shape, memory_space=None):
+        lead = len(shape) - 1
+        return pl.BlockSpec(
+            shape, lambda i, j, *_: (j,) + (0,) * lead,
+            **({} if memory_space is None else {"memory_space": memory_space}))
+
+    x_spec = pl.BlockSpec((tiles, f, LANES), lambda i, j, *_: (i, 0, 0))
+    out_spec = pl.BlockSpec((tiles, c, LANES), lambda i, j, *_: (i, 0, 0))
+    leaf_spec = spec((block_t,) + leaf_chunks.shape[1:])
+    smem_fields = spec((block_t, 4, n), pltpu.SMEM)
+    if impl == "leaf_major":
+        if internal_counts is None:
+            raise ValueError("impl='leaf_major' needs internal_counts")
+        kernel = _kernel_scan
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=grid,
+            in_specs=[x_spec, smem_fields, leaf_spec], out_specs=out_spec)
+        args = (internal_counts, x_tiles, fields, leaf_chunks)
+    elif impl == "gather":
+        kernel = functools.partial(_kernel_gather, depth=depth)
+        nodes = fields.reshape(t, 4, n // LANES, LANES).transpose(0, 2, 1, 3)
+        grid_spec = pl.GridSpec(
+            grid=grid, in_specs=[x_spec, spec((block_t,) + nodes.shape[1:]),
+                                 leaf_spec], out_specs=out_spec)
+        args = (x_tiles, nodes, leaf_chunks)
+    elif impl == "onehot":
+        kernel = functools.partial(_kernel_onehot, depth=depth)
+        grid_spec = pl.GridSpec(grid=grid,
+                                in_specs=[x_spec, smem_fields, leaf_spec],
+                                out_specs=out_spec)
+        args = (x_tiles, fields, leaf_chunks)
+    else:
+        raise ValueError(f"unknown impl {impl!r}; have {IMPLS}")
     return pl.pallas_call(
         kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, f), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_t, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t,), lambda i, j: (j,)),
-            pl.BlockSpec((block_t, n, c), lambda i, j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, c), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.uint32),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((r_tot, c, LANES), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x_keys, feature, threshold_key, left, right, internal_counts, leaf_fixed)
-
-
-def tree_traverse_pallas(
-    x_keys,
-    feature,
-    threshold_key,
-    left,
-    right,
-    leaf_fixed,
-    *,
-    depth: int,
-    block_b: int = 256,
-    block_t: int | None = None,
-    impl: str = "gather",
-    interpret: bool = True,
-):
-    """Raw pallas_call; shapes must already divide evenly (see ops.py)."""
-    b, f = x_keys.shape
-    t, n = feature.shape
-    c = leaf_fixed.shape[-1]
-    block_t = block_t or t
-    assert b % block_b == 0 and t % block_t == 0
-    grid = (b // block_b, t // block_t)
-
-    kernel = functools.partial(_kernel, depth=depth, block_t=block_t, impl=impl)
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_b, f), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_t, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, n), lambda i, j: (j, 0)),
-            pl.BlockSpec((block_t, n, c), lambda i, j: (j, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((block_b, c), lambda i, j: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, c), jnp.uint32),
-        interpret=interpret,
-    )(x_keys, feature, threshold_key, left, right, leaf_fixed)
+        name=f"tree_traverse_{impl}",
+    )(*args)

@@ -3,28 +3,19 @@ this module never touches jax device state)."""
 from __future__ import annotations
 
 import jax
-
-
-def compat_make_mesh(shape, axes):
-    """jax.make_mesh across jax versions: >= 0.5 wants explicit axis_types;
-    0.4.x has neither the kwarg nor jax.sharding.AxisType — feature-detect
-    instead of version-parsing."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat_make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():
     """Single-device mesh for CPU smoke tests (axis sizes 1)."""
-    return compat_make_mesh((1, 1), ("data", "model"))
+    return jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def elastic_mesh_shape(n_devices: int, *, model: int = 16):

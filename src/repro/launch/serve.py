@@ -435,6 +435,9 @@ def main(argv=None):
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args(argv)
+    from repro.launch.device import enable_compile_cache
+
+    enable_compile_cache()
     if args.worker_listen:
         from repro.serve import worker
 

@@ -323,13 +323,12 @@ def _attn_core(xq, xk, xv, *, causal=True, window=0, q_chunk=512, q_offset=0,
             win = static_window if static_window is not None else w
             return _flash_decode_partial(q, k, v, win, off, klen, seq_axis, seq_shards)
 
-    from repro.sharding.ops import compat_shard_map
-
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, P(), P(), P()),
         out_specs=q_spec,
+        check_vma=False,
     )
     return fn(xq, xk, xv, w_arr, off_arr, len_arr)
 

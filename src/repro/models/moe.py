@@ -21,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.core.flint import float_to_key
 from repro.models.layers import act_fn, dense_init
-from repro.sharding.ops import compat_shard_map, constrain
+from repro.sharding.ops import constrain
 
 
 def moe_params(key, d_model: int, n_experts: int, d_ff: int):
@@ -194,7 +194,7 @@ def moe_block_ep(params, x, *, n_experts: int, k: int, act: str,
         _ep_body, n_experts=n_experts, e_loc=e_loc, k=k, act=act,
         capacity_factor=capacity_factor, batch_axes=batch_axes,
     )
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -205,6 +205,7 @@ def moe_block_ep(params, x, *, n_experts: int, k: int, act: str,
             bspec,  # tokens: local batch shard, replicated over model
         ),
         out_specs=(bspec, P()),
+        check_vma=False,
     )
     return fn(params["w_router"], params["w_gate_e"], params["w_up_e"],
               params["w_down_e"], x)

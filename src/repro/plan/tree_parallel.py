@@ -137,10 +137,9 @@ class TreeParallelPlan(ExecutionPlan):
         """
         import jax
         import jax.numpy as jnp
-        from jax.sharding import Mesh, PartitionSpec as P
+        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         from repro.core.ensemble import _predict
-        from repro.sharding.ops import compat_shard_map
 
         subs = [self.ir.subset(a, b).materialize("padded") for a, b in self.ranges]
         S = len(subs)
@@ -162,10 +161,13 @@ class TreeParallelPlan(ExecutionPlan):
             lf[:T0, :N0] = s.leaf_fixed
             feats.append(f); keys.append(k); lefts.append(l); rights.append(r)
             leaves.append(lf)
-        stacked = tuple(jnp.asarray(np.stack(a))
-                        for a in (feats, keys, lefts, rights, leaves))
         depth = int(self.ir.max_depth)
         mesh = Mesh(np.asarray(jax.devices()[:S]), ("s",))
+        # each device holds only its own shard's tables, placed once here
+        stacked = tuple(jax.device_put(np.stack(a), NamedSharding(mesh, P("s")))
+                        for a in (feats, keys, lefts, rights, leaves))
+        self._table_devices = sorted(
+            int(d.id) for d in stacked[0].sharding.device_set)
 
         def shard_fn(feature, key, left, right, leaf, xk):
             # per-device view: the (1, T', N) block of this shard's trees
@@ -173,19 +175,19 @@ class TreeParallelPlan(ExecutionPlan):
                           right=right[0], leaf=leaf[0])
             return _predict(arrays, xk, depth, jnp.uint32)[None]
 
-        sm = compat_shard_map(
+        sm = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P("s"), P("s"), P("s"), P("s"), P("s"), P()),
-            out_specs=P("s"),
+            out_specs=P("s"), check_vma=False,
         )
 
         @jax.jit
-        def fused(xk):
+        def fused(tables, xk):
             # uint32 merge on device: associative, so the (S, B, C) shard
             # partials collapse to the single-shard accumulator bit-exactly
-            return jnp.sum(sm(*stacked, xk), axis=0, dtype=jnp.uint32)
+            return jnp.sum(sm(*tables, xk), axis=0, dtype=jnp.uint32)
 
-        self._fused = fused
+        self._fused = lambda xk: fused(stacked, xk)
         self._fused_label = f"fused:reference[x{S}]"
 
     # ------------------------------------------------------------ execution
@@ -272,4 +274,6 @@ class TreeParallelPlan(ExecutionPlan):
     def describe(self) -> dict:
         d = super().describe()
         d.update(shards=self.n_shards, tree_ranges=self.ranges, fused=self.fused)
+        if self._fused is not None:  # the devices holding the shard tables
+            d["devices"] = self._table_devices
         return d

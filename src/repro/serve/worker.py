@@ -212,6 +212,22 @@ class WorkerServer:
 # local spawning (tests, bench, --workers N)
 # ---------------------------------------------------------------------------
 
+def _held_accelerator():
+    """The non-CPU backend this process has already initialised, or None.
+
+    A device belongs to one process at a time; asking without initialising a
+    backend needs the private ``xla_bridge`` query."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
+        return None
+    backend = jax.default_backend()
+    return None if backend == "cpu" else backend
+
+
 def spawn_local_workers(n: int, *, delays=None, span_dir=None,
                         ready_timeout_s: float = 60.0):
     """Spawn ``n`` loopback worker processes; -> (procs, ["host:port"]).
@@ -224,6 +240,12 @@ def spawn_local_workers(n: int, *, delays=None, span_dir=None,
     """
     import subprocess
 
+    backend = _held_accelerator()
+    if backend is not None:
+        raise RuntimeError(
+            f"this process already holds the {backend!r} device, so spawned "
+            "workers could not reach it; spawn workers before the first JAX "
+            "call, or serve the shards in-process (the tree_parallel plan)")
     if span_dir is None:
         span_dir = os.environ.get("REPRO_WORKER_SPAN_DIR")
     src_root = os.path.dirname(os.path.dirname(os.path.dirname(

@@ -46,21 +46,6 @@ def current_mesh() -> Optional[Mesh]:
     return _ACTIVE[-1] if _ACTIVE else None
 
 
-def compat_shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """shard_map across jax versions: >= 0.5 exposes ``jax.shard_map`` with
-    ``check_vma``; 0.4.x has ``jax.experimental.shard_map`` with the same
-    knob named ``check_rep``.  Feature-detect instead of version-parsing."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check
-    )
-
-
 def constrain(x, *logical):
     """Apply with_sharding_constraint with logical names; no-op without mesh."""
     mesh = current_mesh()

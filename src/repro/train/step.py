@@ -86,8 +86,6 @@ def make_integer_dp_train_step(cfg: ModelConfig, mesh, opt_cfg: Optional[opt.Ada
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.ops import compat_shard_map
-
     def grad_fn(params, batch):
         def lf(p):
             return tfm.loss_fn(cfg, p, batch)
@@ -97,11 +95,12 @@ def make_integer_dp_train_step(cfg: ModelConfig, mesh, opt_cfg: Optional[opt.Ada
         loss = jax.lax.pmean(loss, "data")
         return loss, grads
 
-    sharded_grad = compat_shard_map(
+    sharded_grad = jax.shard_map(
         grad_fn,
         mesh=mesh,
         in_specs=(P(), P("data")),
         out_specs=(P(), P()),
+        check_vma=False,
     )
 
     def train_step(params, opt_state, batch):

@@ -14,10 +14,12 @@ stores the fixed-point leaf table through the exact group codec
 table, and any tuned-host entries without loading array pages.
 
 ``--selftest`` is the end-to-end proof CI runs: train a small forest, write
-its JSON, convert, then reload the artifact **in a fresh process** via mmap
-and assert the reloaded reference partials are bit-identical to the
-in-process ones (``--verify`` is that subprocess entry point: it prints
-``PARTIALS_SHA256 <hex>`` for deterministic probe rows).
+its JSON, convert, then digest the source forest and the mmap-reloaded
+artifact, each **in a fresh process**, and assert the two reference partials
+are bit-identical (``--verify`` is that subprocess entry point: it prints
+``PARTIALS_SHA256 <hex>`` for deterministic probe rows of a ``.json`` model
+or an ``.itrf`` artifact).  The parent never touches JAX, so on an
+accelerator host the children can each hold the device in turn.
 """
 from __future__ import annotations
 
@@ -72,16 +74,32 @@ def _inspect(path) -> int:
 def _verify(path) -> int:
     from repro.ir import ForestIR
 
-    ir = ForestIR.from_itrf(path, mmap=True)
+    if str(path).endswith(".json"):
+        from repro.trees.io import forest_from_json
+
+        with open(path) as fh:
+            ir = ForestIR.from_forest(forest_from_json(fh.read()))
+    else:
+        ir = ForestIR.from_itrf(path, mmap=True)
     print(f"PARTIALS_SHA256 {_partials_digest(ir)}")
     return 0
 
 
-def _selftest(out_path) -> int:
-    import numpy as np
+def _child_digest(path):
+    """``--verify path`` in a fresh interpreter -> (returncode, digest)."""
+    out = subprocess.run([sys.executable, "-m", "repro.trees.convert",
+                          "--verify", str(path)],
+                         capture_output=True, text=True, timeout=600)
+    sys.stderr.write(out.stderr)
+    got = None
+    for line in out.stdout.splitlines():
+        if line.startswith("PARTIALS_SHA256 "):
+            got = line.split(None, 1)[1].strip()
+    return out.returncode, got
 
+
+def _selftest(out_path) -> int:
     from repro.data.tabular import make_shuttle_like, train_test_split
-    from repro.ir import ForestIR
     from repro.trees.forest import RandomForestClassifier
     from repro.trees.io import forest_to_json
 
@@ -96,19 +114,13 @@ def _selftest(out_path) -> int:
     rc = main([json_path, out_path, "--pack-leaves"])
     if rc:
         return rc
-    expect = _partials_digest(ForestIR.from_forest(rf))
-    # the fresh-process reload: a new interpreter mmaps the artifact and
-    # must reproduce the in-process partials bit-for-bit
-    out = subprocess.run([sys.executable, "-m", "repro.trees.convert",
-                          "--verify", out_path],
-                         capture_output=True, text=True, timeout=600)
-    sys.stderr.write(out.stderr)
-    got = None
-    for line in out.stdout.splitlines():
-        if line.startswith("PARTIALS_SHA256 "):
-            got = line.split(None, 1)[1].strip()
-    if out.returncode or got != expect:
-        print(f"SELFTEST FAIL: fresh-process digest {got} != {expect}")
+    # this process stays off JAX, so each child may claim the device: the
+    # first digests the source forest, the second mmaps the artifact and
+    # must reproduce those partials bit-for-bit
+    rc_src, expect = _child_digest(json_path)
+    rc_art, got = _child_digest(out_path)
+    if rc_src or rc_art or expect is None or got != expect:
+        print(f"SELFTEST FAIL: artifact digest {got} != source {expect}")
         return 1
     print(f"SELFTEST OK: fresh-process mmap reload bit-identical ({expect})")
     return 0
@@ -128,8 +140,9 @@ def main(argv=None) -> int:
                     help="codec group size (default 64)")
     ap.add_argument("--inspect", metavar="ITRF",
                     help="dump an artifact's header/section table as JSON")
-    ap.add_argument("--verify", metavar="ITRF",
-                    help="mmap-load an artifact and print its partials digest")
+    ap.add_argument("--verify", metavar="ITRF|JSON",
+                    help="load an artifact (mmap) or a model JSON and print "
+                         "its partials digest")
     ap.add_argument("--selftest", metavar="OUT_ITRF",
                     help="train, convert, and verify in a fresh process")
     args = ap.parse_args(argv)

@@ -1,7 +1,5 @@
 import os
 import shutil
-import sys
-import types
 
 import numpy as np
 import pytest
@@ -10,53 +8,15 @@ import pytest
 # and benches must see 1 device; only launch/dryrun.py uses 512 placeholders.
 # Tests that need a few devices spawn subprocesses (see test_distributed.py).
 
-# The whole suite is host-CPU-only (accelerator paths run in interpret mode
-# or on forced host devices).  On images that bundle libtpu, leaving the
+# The whole suite is host-CPU-only (Pallas kernels run interpreted, sharded
+# paths on forced host devices, and tests/test_tpu_compile.py only lowers for
+# a described TPU).  On images that bundle libtpu, leaving the
 # platform unpinned makes every fresh jax process — this one, the
 # test_distributed subprocesses, the remote shard workers — probe the cloud
 # metadata service for a TPU, which stalls for minutes when that endpoint
 # blackholes instead of refusing.  Pin before anything imports jax; spawned
 # children inherit it.  setdefault so a caller pinning a real platform wins.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
-# ---------------------------------------------------------------------------
-# hypothesis fallback shim: the property tests import `given`/`settings`/
-# `strategies` at module scope, so a missing hypothesis breaks *collection*
-# of four whole modules.  When it is absent, install a stub whose `given`
-# marks the test skipped; all non-property tests in those modules still run.
-# ---------------------------------------------------------------------------
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-
-    class _Strategy:
-        """Inert stand-in: any strategy combinator returns another stub."""
-
-        def __call__(self, *a, **k):
-            return self
-
-        def __getattr__(self, name):
-            return self
-
-    def _given(*a, **k):
-        return pytest.mark.skip(reason="hypothesis not installed")
-
-    def _settings(*a, **k):
-        if a and callable(a[0]):  # bare @settings usage
-            return a[0]
-        return lambda fn: fn
-
-    _st = types.ModuleType("hypothesis.strategies")
-    _st.__getattr__ = lambda name: _Strategy()
-
-    _hyp = types.ModuleType("hypothesis")
-    _hyp.given = _given
-    _hyp.settings = _settings
-    _hyp.assume = lambda *a, **k: True
-    _hyp.strategies = _st
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st
-
 
 # ---------------------------------------------------------------------------
 # shared `requires_gcc` marker: codegen / native-backend tests need a C

@@ -5,21 +5,24 @@ import json
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from repro.launch.mesh import elastic_mesh_shape
 
+REPO = Path(__file__).resolve().parents[1]
+
 
 def _run(py: str, devices: int = 8, timeout: int = 560) -> str:
     code = (
         "import os\n"
         f"os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count={devices}'\n"
-        # version-agnostic mesh construction, available to every script
-        # (importing it does not initialize the jax backend)
-        "from repro.launch.mesh import compat_make_mesh\n"
-        "from repro.sharding.ops import compat_shard_map\n"
+        # Auto-sharded meshes, as the program builds them (importing these
+        # does not initialize the jax backend)
+        "from jax.sharding import AxisType\n"
+        "import jax\n"
         + textwrap.dedent(py)
     )
     out = subprocess.run(
@@ -31,7 +34,7 @@ def _run(py: str, devices: int = 8, timeout: int = 560) -> str:
         # via XLA_FLAGS, so a bundled libtpu must never probe the cloud
         # metadata service for a TPU (minutes of retry when it blackholes).
         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"},
-        cwd="/root/repo",
+        cwd=REPO,
     )
     assert out.returncode == 0, out.stderr[-3000:]
     return out.stdout
@@ -43,11 +46,11 @@ def test_integer_allreduce_matches_float_psum():
         import jax, jax.numpy as jnp, numpy as np, json
         from jax.sharding import PartitionSpec as P
         from repro.train.intreeger_allreduce import integer_psum, quantization_error_bound
-        mesh = compat_make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         x = np.random.default_rng(0).normal(size=(8, 1024)).astype(np.float32)
         def f(xs):
             return integer_psum(xs, "data", 8)
-        y = compat_shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P("data"), check=True)(x)
+        y = jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P("data"))(x)
         y = np.asarray(y).reshape(8, -1)[0]
         exact = x.sum(axis=0)
         bound = quantization_error_bound(8, float(np.abs(x).max()))
@@ -81,7 +84,7 @@ def test_sharded_train_step_matches_single_device():
         p1, o1, m1 = jax.jit(step)(params, ostate, batch)
 
         # 2x4 mesh
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         with mesh, use_mesh(mesh):
             sh = rules.params_shardings(params, mesh)
             pp = jax.tree.map(jax.device_put, params, sh)
@@ -110,7 +113,7 @@ def test_dryrun_entry_on_small_mesh():
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         cfg = smoke_config("olmoe-1b-7b")
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         with mesh, use_mesh(mesh):
             shapes = tfm.param_shapes(cfg)
             sh = rules.params_shardings(shapes, mesh)
@@ -166,7 +169,7 @@ def test_integer_dp_training_converges():
         from repro.train.step import make_integer_dp_train_step, make_train_step
 
         cfg = smoke_config("granite-3-2b")
-        mesh = compat_make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         pipe = pipeline_for(cfg, 16, 64)
         ocfg = opt.AdamWConfig(lr=5e-3, warmup_steps=5, total_steps=25)
 
@@ -199,7 +202,7 @@ def test_distributed_attention_matches_local():
         from repro.models.layers import _attn_core
         from repro.sharding.ops import use_mesh
         rng = np.random.default_rng(0)
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         errs = {}
         # (name, q_shape, kv_shape, kwargs)
         cases = {
@@ -244,7 +247,7 @@ def test_tree_serve_step_sharded_matches_local():
                   ("feature", "threshold_key", "left", "right", "leaf_fixed")}
         keys = float_to_key(jnp.asarray(X[:1024]))
         acc_ref, preds_ref = tree_serve_step(tables, keys, packed.max_depth)
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         with mesh, use_mesh(mesh):
             fn = jax.jit(lambda t, x: tree_serve_step(t, x, packed.max_depth))
             acc, preds = fn(tables, keys)

@@ -1,5 +1,7 @@
 """Pallas tree-traversal kernel vs the pure-jnp oracle: shape/dtype sweeps,
-both gather strategies, padding paths — bit-identical uint32 scores."""
+both gather strategies, padding paths — bit-identical uint32 scores — and
+the tiling-aligned block picker (rows in 128-lane multiples, trees in
+multiples of 8 or the whole forest)."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,7 +10,10 @@ from hypothesis import strategies as st
 
 from repro.core.flint import float_to_key
 from repro.core.packing import pack_forest
-from repro.kernels.ops import packed_predict_integer, pick_blocks, tree_predict_integer
+from repro.kernels.ops import (
+    _VMEM_BUDGET_BYTES, _align_block_t, _block_words, _fits, _smem_words,
+    packed_predict_integer, pick_blocks, tree_predict_integer,
+)
 from repro.kernels.ref import tree_predict_integer_ref
 from repro.trees.forest import RandomForestClassifier
 
@@ -58,8 +63,13 @@ def test_kernel_matches_ref_sweep(impl, n_trees, depth, n_features, n_classes):
 )
 @settings(max_examples=12, deadline=None)
 def test_kernel_block_shapes_property(bb, bt, rows):
-    """Any (block_b, block_t, n_rows) combination is bit-identical to ref."""
+    """Any requested (block_b, block_t, n_rows) combination is aligned by the
+    wrapper and stays bit-identical to ref."""
     packed, X = _forest(7, 4, 5, 3, seed=2)
+    t, n = packed.feature.shape
+    auto_b, auto_t = pick_blocks(rows, t, n, 5, 3, bb)
+    assert _aligned(auto_b, auto_t, t)
+    assert _aligned(auto_b, _align_block_t(bt, t), t)
     keys = float_to_key(jnp.asarray(X[:rows]))
     feature, tkey, left, right, leaf = _args(packed)
     ref = tree_predict_integer_ref(keys, feature, tkey, left, right, leaf, packed.max_depth)
@@ -80,20 +90,28 @@ def test_packed_entry_point(small_packed, shuttle_small):
     np.testing.assert_array_equal(np.asarray(pred_k), np.asarray(pred_ref))
 
 
+def _aligned(bb, bt, t):
+    return bb % 128 == 0 and bb >= 128 and (bt % 8 == 0 or bt == t)
+
+
 def test_vmem_budget_picker():
     bb, bt = pick_blocks(b=4096, t=128, n=2047, f=87, c=8)
-    words = bb * 87 + bt * 2047 * 4 + bt * 2047 * 8 + bb * 8
+    # x tiles, node chunks (4 fields padded to 8 rows), leaf chunks, out
+    # tiles at padded widths, two pipeline buffers each
+    words = 2 * (bb * 88 + bt * 2048 * 8 + bt * 2048 * 8 + bb * 8)
+    assert words == _block_words(bb, bt, 2047, 87, 8)
     assert words * 4 <= 8 * 1024 * 1024
-    assert bb >= 1 and bt >= 1
+    assert _smem_words(bt, 2047) * 4 <= 512 * 1024
+    assert _aligned(bb, bt, 128)
+    assert (bb, bt) == (256, 8)  # SMEM bounds the tree block at depth 10
 
 
 def test_vmem_budget_picker_wide_leaf_tables():
-    """Regression: with c large relative to n the ``block_b * c`` output
-    block alone can bust the budget at ``block_t == 1`` — the picker used to
-    return it unchecked.  The row block must shrink until the whole
-    leaf-major working set (incl. the internal-counts vector) fits."""
-    from repro.kernels.ops import _VMEM_BUDGET_BYTES, _block_words
-
+    """Regression: with c large relative to n the output tiles and leaf
+    chunks can bust the budget at the smallest tree block — the picker used
+    to return it unchecked.  The row block must shrink until the working set
+    fits, and every choice stays tiling-aligned; the floor is the smallest
+    aligned tiling, (128, min(t, 8))."""
     cases = [
         dict(b=4096, t=4, n=31, f=16, c=16384),   # output block dominates
         dict(b=4096, t=2, n=3, f=8, c=400000),    # degenerate: even bt=1 huge
@@ -101,10 +119,13 @@ def test_vmem_budget_picker_wide_leaf_tables():
     ]
     for kw in cases:
         bb, bt = pick_blocks(**kw)
-        assert bb >= 1 and bt >= 1
-        words = _block_words(bb, bt, kw["n"], kw["f"], kw["c"])
-        if _block_words(1, 1, kw["n"], kw["f"], kw["c"]) * 4 <= _VMEM_BUDGET_BYTES:
-            assert words * 4 <= _VMEM_BUDGET_BYTES, kw
+        assert _aligned(bb, bt, kw["t"]), kw
+        floor = (128, min(kw["t"], 8), kw["n"], kw["f"], kw["c"])
+        if _fits(*floor):
+            assert _fits(bb, bt, kw["n"], kw["f"], kw["c"]), kw
+        else:
+            assert (bb, bt) == floor[:2], kw
+            assert _block_words(*floor) * 4 > _VMEM_BUDGET_BYTES
 
 
 @pytest.mark.parametrize(

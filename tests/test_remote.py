@@ -325,3 +325,15 @@ def test_worker_span_jsonl(small_packed, shuttle_small, tmp_path):
         assert "predict" in names
     finally:
         _kill_all(procs)
+
+
+def test_spawn_refuses_when_caller_holds_an_accelerator(monkeypatch):
+    """A device belongs to one process: once the caller has initialised a
+    non-CPU backend, spawned workers could not reach it, so spawning fails
+    up front with a clear error instead of contending for the chip."""
+    from repro.serve import worker
+
+    assert worker._held_accelerator() is None  # this suite runs on CPU
+    monkeypatch.setattr(worker, "_held_accelerator", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="already holds the 'tpu' device"):
+        spawn_local_workers(1)

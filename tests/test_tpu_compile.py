@@ -1,0 +1,102 @@
+"""Compile the main path for a TPU v5e without a chip.
+
+The chip's compiler is installed with libtpu and lowers for a described
+topology, so these tests catch what interpret mode cannot: unaligned blocks,
+unsupported lowerings and on-chip memory limits.  They compile the three
+Pallas walks and the jnp reference walk at the widths of
+``configs/intreeger_rf.py`` (128 trees, depth 10, 87 features, 8 classes).
+Nothing runs; results are checked by the interpret-mode bit-identity tests.
+
+The topology is described inside a fixture (never at import): only one
+process may load libtpu, and every test worker imports this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs.intreeger_rf import CONFIG
+from repro.core.ensemble import _predict
+from repro.kernels.ops import pick_blocks, tree_predict_integer
+
+T, D, F, C = CONFIG.n_trees, CONFIG.tree_depth, CONFIG.n_tab_features, CONFIG.n_classes
+N = 2 ** (D + 1) - 1  # padded nodes per tree
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A program compiled for a described chip cannot be read back from the
+    persistent cache without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shape(sharding, shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _tables(sharding):
+    return ([_shape(sharding, (T, N)) for _ in range(4)]
+            + [_shape(sharding, (T, N, C), jnp.uint32)])
+
+
+def _compile_kernel(sharding, impl, rows, block_t=None):
+    def run(x, feature, key, left, right, leaf, counts):
+        return tree_predict_integer(
+            x, feature, key, left, right, leaf, depth=D, impl=impl,
+            block_t=block_t, interpret=False,
+            internal_counts=counts if impl == "leaf_major" else None)
+
+    return jax.jit(run).lower(
+        _shape(sharding, (rows, F)), *_tables(sharding), _shape(sharding, (T,))
+    ).compile()
+
+
+@pytest.mark.parametrize("rows", [8, 256])
+@pytest.mark.parametrize("impl", ["leaf_major", "gather", "onehot"])
+def test_pallas_walk_compiles_for_v5e(one_chip, no_compile_cache, impl, rows):
+    compiled = _compile_kernel(one_chip, impl, rows)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("rows", [8, 256])
+def test_reference_walk_compiles_for_v5e(one_chip, no_compile_cache, rows):
+    feature, key, left, right, leaf = _tables(one_chip)
+    arrays = dict(feature=feature, threshold=key, left=left, right=right, leaf=leaf)
+    compiled = _predict.lower(arrays, _shape(one_chip, (rows, F)), depth=D,
+                              acc_dtype=jnp.uint32).compile()
+    assert compiled.memory_analysis().argument_size_in_bytes > 0
+
+
+def test_smem_budget_counts_both_pipeline_buffers(one_chip, no_compile_cache):
+    """The picked tree block fits the chip's 1 MiB of SMEM; twice that block
+    (the node fields' two pipeline buffers at 1 MiB) is refused, so the
+    budget's double-buffer accounting matches the compiler's."""
+    _, block_t = pick_blocks(256, T, N, F, C)
+    _compile_kernel(one_chip, "leaf_major", 256, block_t=block_t)
+    with pytest.raises(Exception, match="smem"):
+        _compile_kernel(one_chip, "leaf_major", 256, block_t=2 * block_t)
