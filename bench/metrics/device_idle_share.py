@@ -1,0 +1,7 @@
+"""Device: share of the traced window in which no operation ran (%)."""
+
+
+def read(ctx):
+    if ctx.trace is None or not ctx.trace_window_s:
+        return None
+    return 100.0 * (1.0 - ctx.trace.busy_s / ctx.trace_window_s)
