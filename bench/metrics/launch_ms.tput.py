@@ -1,0 +1,7 @@
+"""Program launch: mean host time of one batch's ``launch``
+stage inside the dispatch (``repro.obs.stages``; ms), in the throughput cell."""
+from bench.readers import stage_mean_ms
+
+
+def read(ctx):
+    return stage_mean_ms(ctx, "launch")

@@ -38,6 +38,26 @@ import abc
 from dataclasses import dataclass
 from typing import ClassVar, Optional
 
+import jax.numpy as jnp
+import numpy as np
+
+from repro.obs import stage
+
+
+def device_call(fn, X):
+    """``fn`` (one jitted program) on the device copy of the host array
+    ``X``, fetched back to numpy (a tuple result element-wise): the shard
+    call's ``upload``, ``launch`` and ``fetch`` stages (``repro.obs.
+    stages``), for a backend whose whole device path is that one call."""
+    with stage("upload", bytes=X.nbytes if isinstance(X, np.ndarray) else 0):
+        x = jnp.asarray(X)
+    with stage("launch", programs=1):
+        out = fn(x)
+    with stage("fetch"):
+        if isinstance(out, tuple):
+            return tuple(np.asarray(a) for a in out)
+        return np.asarray(out)
+
 
 class BackendUnavailable(RuntimeError):
     """The backend cannot run on this host (e.g. no C toolchain)."""

@@ -16,10 +16,10 @@ streams the same tables sequentially with the sorted-list early exit.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
-from repro.backends.base import BackendCapabilities, TreeBackend, register_backend
+from repro.backends.base import (BackendCapabilities, TreeBackend, device_call,
+                                 register_backend)
 from repro.kernels.bitvector import make_bitvector_partials_fn
 
 
@@ -42,4 +42,4 @@ class BitvectorBackend(TreeBackend):
         self._partials_fn = make_bitvector_partials_fn(packed)
 
     def predict_partials(self, X):
-        return np.asarray(self._partials_fn(jnp.asarray(X, jnp.float32)))
+        return device_call(self._partials_fn, np.asarray(X, np.float32))

@@ -33,6 +33,7 @@ import numpy as np
 
 from repro.backends.base import BackendCapabilities, TreeBackend, register_backend
 from repro.core.packing import PackedEnsemble
+from repro.obs import stage
 
 _DEFAULT_BLOCK_B = 256  # the kernel wrapper's row-tile default
 
@@ -94,4 +95,5 @@ class PallasBackend(TreeBackend):
         if self._auto_small_batch and len(X) < _SMALL_BATCH_GATHER_ROWS:
             kw = dict(kw, impl="gather")
         acc, _ = packed_predict_integer(self.packed, X, **kw)
-        return np.asarray(acc)
+        with stage("fetch"):  # the wait on the device and the copy back
+            return np.asarray(acc)

@@ -8,10 +8,10 @@ shared numpy finalize); the float mode keeps its fused jitted predict.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
 import numpy as np
 
-from repro.backends.base import BackendCapabilities, TreeBackend, register_backend
+from repro.backends.base import (BackendCapabilities, TreeBackend, device_call,
+                                 register_backend)
 from repro.core.ensemble import MODES, make_partials_fn, make_predict_fn
 from repro.core.packing import PackedEnsemble
 
@@ -51,9 +51,9 @@ class ReferenceBackend(TreeBackend):
     def predict_partials(self, X):
         if not self.deterministic:
             return super().predict_partials(X)  # raises with the shared message
-        return np.asarray(self._partials_fn(jnp.asarray(X, jnp.float32)))
+        return device_call(self._partials_fn, np.asarray(X, np.float32))
 
     def predict_scores(self, X):
         if self.deterministic:
             return super().predict_scores(X)  # finalize(partials)
-        return self._fn(jnp.asarray(X, jnp.float32))
+        return device_call(self._fn, np.asarray(X, np.float32))

@@ -14,6 +14,11 @@ step re-targeted at tensors).  ``packed_predict_integer`` accepts a
 (``leaf_major`` for the linear-scan kernel, ``padded`` otherwise); the
 ``ragged`` layout has no VMEM-tileable shape and belongs to the table-walk C
 backend instead.
+
+Host steps are marked as the shard call's stages (``repro.obs.stages``):
+handing host arrays to the device is ``upload`` (``bytes``), and the key
+transform, the block choice, the kernel's jitted call and the argmax are
+``launch`` (``programs``: device programs started).
 """
 from __future__ import annotations
 
@@ -21,9 +26,11 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.flint import float_to_key
 from repro.kernels.tree_traverse import LANES, resolve_interpret, tree_traverse
+from repro.obs import stage
 
 # per-grid-cell budgets for the pipeline's double-buffered blocks; the v5e
 # compiler reports 1 MiB of SMEM, and VMEM's default scoped limit is larger
@@ -35,9 +42,24 @@ _SMEM_BUDGET_BYTES = 512 * 1024
 # for a handful of rows; block_t is scaled down proportionally instead
 _TINY_BATCH_ROWS = 64
 
+# float_to_key runs eagerly, one program each: bitcast, less, subtract, where
+_KEY_PROGRAMS = 4
+
 
 def _round_up(v, m):
     return -(-v // m) * m
+
+
+def _upload(*arrays):
+    """Each host (numpy) array put on the device as the ``upload`` stage,
+    whose ``bytes`` are theirs; without one, ``arrays`` come back as they
+    are (device arrays, tracers, None), and no stage is marked."""
+    host = [a for a in arrays if isinstance(a, np.ndarray)]
+    if not host:
+        return arrays
+    with stage("upload", bytes=sum(a.nbytes for a in host)):
+        return tuple(jnp.asarray(a) if isinstance(a, np.ndarray) else a
+                     for a in arrays)
 
 
 def _block_words(block_b, block_t, n, f, c):
@@ -186,19 +208,23 @@ def tree_predict_integer(
             "impl='leaf_major' needs the layout's internal_counts; "
             "materialize the forest as leaf_major (see repro.ir.layouts)"
         )
-    x_keys = jnp.asarray(x_keys, jnp.int32)
-    b, f = x_keys.shape
-    t, n = feature.shape
-    c = leaf_fixed.shape[-1]
-    block_b, auto_t = pick_blocks(b, t, n, f, c, block_b)
-    block_t = auto_t if block_t is None else _align_block_t(block_t, t)
-    nint = (jnp.asarray(internal_counts, jnp.int32)
-            if impl == "leaf_major" else None)
-    return _traverse(
-        x_keys, feature, threshold_key, left, right, leaf_fixed, nint,
-        depth=depth, block_b=block_b, block_t=block_t, impl=impl,
-        interpret=resolve_interpret(interpret),
-    )
+    x_keys, feature, threshold_key, left, right, leaf_fixed, nint = _upload(
+        x_keys, feature, threshold_key, left, right, leaf_fixed,
+        internal_counts if impl == "leaf_major" else None)
+    with stage("launch", programs=1):
+        x_keys = jnp.asarray(x_keys, jnp.int32)
+        b, f = x_keys.shape
+        t, n = feature.shape
+        c = leaf_fixed.shape[-1]
+        block_b, auto_t = pick_blocks(b, t, n, f, c, block_b)
+        block_t = auto_t if block_t is None else _align_block_t(block_t, t)
+        if nint is not None:
+            nint = jnp.asarray(nint, jnp.int32)
+        return _traverse(
+            x_keys, feature, threshold_key, left, right, leaf_fixed, nint,
+            depth=depth, block_b=block_b, block_t=block_t, impl=impl,
+            interpret=resolve_interpret(interpret),
+        )
 
 
 def packed_predict_integer(packed, X, impl: str = "auto", **kw):
@@ -234,17 +260,15 @@ def packed_predict_integer(packed, X, impl: str = "auto", **kw):
         from repro.ir import resolve_artifact
 
         packed = resolve_artifact(packed, "leaf_major")
-    keys = float_to_key(jnp.asarray(X, jnp.float32))
-    acc = tree_predict_integer(
-        keys,
-        jnp.asarray(packed.feature),
-        jnp.asarray(packed.threshold_key),
-        jnp.asarray(packed.left),
-        jnp.asarray(packed.right),
-        jnp.asarray(packed.leaf_fixed),
-        depth=packed.max_depth,
-        impl=impl,
-        internal_counts=packed.internal_counts if impl == "leaf_major" else None,
-        **kw,
-    )
-    return acc, jnp.argmax(acc, axis=1).astype(jnp.int32)
+    if isinstance(X, np.ndarray):
+        X = np.asarray(X, np.float32)
+    x, = _upload(X)
+    with stage("launch", programs=_KEY_PROGRAMS):
+        keys = float_to_key(x)
+    *tables, nint = _upload(
+        packed.feature, packed.threshold_key, packed.left, packed.right,
+        packed.leaf_fixed, packed.internal_counts if impl == "leaf_major" else None)
+    acc = tree_predict_integer(keys, *tables, depth=packed.max_depth, impl=impl,
+                               internal_counts=nint, **kw)
+    with stage("launch", programs=1):
+        return acc, jnp.argmax(acc, axis=1).astype(jnp.int32)

@@ -25,6 +25,10 @@ Design constraints, in order:
     spans recorded from the batcher worker, the plan's shard pool, or a
     ctypes call — :meth:`Tracer.record` takes explicit ``t0_ns``/``t1_ns``
     for stages measured where the tracer isn't reachable.
+  * **Process spans.**  A collection or a compile belongs to no request:
+    :meth:`Tracer.record_process` commits it as a root of its own (parent
+    0, not named ``request``), so it can name a device idle gap without
+    being taken for a request (see :mod:`repro.obs.stages`).
   * **Batch fan-in.**  A micro-batched execute serves many requests at
     once; the batch span is parented to its first sampled rider and lists
     every rider span id in ``attrs["riders"]``, so the export layer can
@@ -153,7 +157,9 @@ class Tracer:
         self.sample = float(sample)
         self.capacity = int(capacity)
         self._buf: list = []
-        self._lock = threading.Lock()
+        # re-entrant: a gc callback may record a span from inside a
+        # collection that an allocation under this lock set off
+        self._lock = threading.RLock()
         self._next_id = 1
         self._acc = 0.0  # deterministic sampling accumulator
         self.started = 0  # root spans handed out (sampled)
@@ -197,6 +203,17 @@ class Tracer:
         sid = self._ids()
         s = Span(self, name, parent.trace_id, sid, parent.span_id,
                  int(t0_ns), attrs)
+        s.t1 = int(t1_ns)
+        self._push(s)
+
+    def record_process(self, name: str, t0_ns: int, t1_ns: int, **attrs):
+        """Commit an already-measured span that belongs to the process, not
+        to a request (a collection, a compile): a root with a trace of its
+        own, recorded whenever the tracer is enabled, whatever ``sample``."""
+        if not self.enabled:
+            return
+        sid = self._ids()
+        s = Span(self, name, sid, sid, 0, int(t0_ns), attrs)
         s.t1 = int(t1_ns)
         self._push(s)
 

@@ -52,6 +52,7 @@ from typing import ClassVar, Optional
 import numpy as np
 
 from repro.core.ensemble import finalize_partials, mode_spec
+from repro.obs import stages
 
 
 def build_backend(backend, model, mode: str, layout: Optional[str],
@@ -130,6 +131,8 @@ class ExecutionPlan(abc.ABC):
         # submit time, so concurrent dispatches never cross-parent spans)
         self._tracer = None
         self._trace_tls = threading.local()
+        # compiles inside a shard call become its ``compile`` stage
+        stages.watch_compiles()
 
     # ------------------------------------------------------------ execution
     @abc.abstractmethod
@@ -233,22 +236,39 @@ class ExecutionPlan(abc.ABC):
 
     def _record_stage(self, stage: str, seconds: float) -> None:
         """Accumulate one pipeline-stage sample (pad/merge/finalize — the
-        engine adds pad); drained separately from shard labels."""
+        engine adds pad — and the shard call's upload/launch/fetch/compile);
+        drained separately from shard labels."""
         with self._timings_lock:
             ms, calls = self._stages.get(stage, (0.0, 0))
             self._stages[stage] = (ms + seconds * 1e3, calls + 1)
 
     def _timed(self, label: str, fn, *args, span_parent=None):
-        """Run ``fn`` timing it into the shard ledger; when ``span_parent``
-        is a live span, also commit a ``shard:<label>`` trace span.  Shard
-        pool threads receive the parent explicitly (captured by the
+        """Run ``fn`` timing it into the shard ledger, with a stage sink
+        (:mod:`repro.obs.stages`) held for its length: each stage the code
+        below marks (upload / launch / fetch / compile) lands in the stage
+        ledger as one sample per call, the sum of its intervals.  When
+        ``span_parent`` is a live span, a ``shard:<label>`` span is opened
+        before the call and each run of one stage becomes a span under it.
+        Shard pool threads receive the parent explicitly (captured by the
         dispatching thread), never via the thread-local."""
-        t0 = time.perf_counter_ns()
-        out = fn(*args)
-        t1 = time.perf_counter_ns()
+        span = None
+        if span_parent and self._tracer is not None:
+            span = self._tracer.child(span_parent, f"shard:{label}", label=label)
+        sink = stages.Sink(traced=bool(span))
+        prev = stages.install(sink)
+        try:
+            t0 = time.perf_counter_ns()
+            out = fn(*args)
+            t1 = time.perf_counter_ns()
+        finally:
+            stages.install(prev)
         self._record(label, (t1 - t0) / 1e9)
-        if span_parent:
-            self._span(f"shard:{label}", t0, t1, span_parent, label=label)
+        for name, ns in sink.totals.items():
+            self._record_stage(name, ns / 1e9)
+        if span:
+            for name, s0, s1, attrs in sink.runs:
+                self._tracer.record(name, s0, s1, parent=span, **attrs)
+            span.end()
         return out
 
     def drain_timings(self) -> dict:
@@ -261,7 +281,8 @@ class ExecutionPlan(abc.ABC):
 
     def drain_stage_timings(self) -> dict:
         """Pipeline-stage wall time since the last drain:
-        ``{stage: (ms_total, calls)}`` — pad / merge / finalize, fed into
+        ``{stage: (ms_total, calls)}`` — pad / merge / finalize and the
+        shard call's upload / launch / fetch / compile, fed into
         the per-stage metric histograms alongside the shard ledger."""
         with self._timings_lock:
             out, self._stages = self._stages, {}

@@ -197,14 +197,14 @@ class TreeParallelPlan(ExecutionPlan):
         # threads get it via submit args, not via the thread-local
         parent = self.trace_parent
         if self._fused is not None:
+            from repro.backends.base import device_call
             from repro.core.flint import float_to_key_np
 
-            # materialize inside the timed region: the jitted call dispatches
+            # fetch inside the timed region: the jitted call dispatches
             # asynchronously, so timing it alone would record ~0ms.  The
             # device-side uint32 merge rides inside this span too.
-            run = lambda xk: np.asarray(self._fused(xk))
-            return self._timed(self._fused_label, run, float_to_key_np(X),
-                               span_parent=parent)
+            return self._timed(self._fused_label, device_call, self._fused,
+                               float_to_key_np(X), span_parent=parent)
         labels = [
             f"s{i}:{b.name}[{a}:{e}]"
             for i, (b, (a, e)) in enumerate(zip(self._shard_backends, self.ranges))

@@ -32,7 +32,11 @@ request carries a span tree — ``request`` → ``cache_probe`` / ``queue`` /
 subtree, parented under the first live rider and tagged with every rider's
 span id (``attrs["riders"]``) so the export layer grafts it under each.
 Untraced gateways pay one falsy-check per stage (``NULL_TRACER`` /
-``NULL_SPAN`` propagate through every hook).
+``NULL_SPAN`` propagate through every hook).  Under each ``shard:*`` span
+sit the call's ``upload`` / ``launch`` / ``fetch`` (and any ``compile``)
+stages; with an enabled tracer the gateway also records the process's
+``gc`` and ``compile`` spans (:class:`repro.obs.ProcessSpans`) until
+:meth:`Gateway.close`.
 """
 from __future__ import annotations
 
@@ -41,7 +45,7 @@ import time
 import numpy as np
 
 from repro.backends import backend_class
-from repro.obs import NULL_TRACER
+from repro.obs import NULL_TRACER, ProcessSpans
 from repro.serve.cache import QuantizedKeyCache, row_keys
 from repro.serve.metrics import MetricsRegistry
 from repro.serve.queue import AdmissionError, MicroBatcher
@@ -142,6 +146,10 @@ class Gateway:
             tracer=self.tracer,
             pass_spans=True,
         )
+        # collections and compiles name device idle gaps that no request
+        # explains; the hooks exist only while an enabled tracer is held
+        self._process_spans = (ProcessSpans(self.tracer).install()
+                               if self.tracer.enabled else None)
 
     def _record_queue_waits(self, model_id: str, waits_ms: list) -> None:
         mm = self.metrics.model(model_id)
@@ -308,11 +316,16 @@ class Gateway:
         ``close()`` joins plan executors and, for the remote plan, sends
         CLOSE to every worker connection and reaps spawned worker
         processes — so no in-flight shard dispatch is ever abandoned.
+        Last, the tracer's ``gc`` hook and compile watch are removed.
         """
-        await self.batcher.close()
-        for eng in self._engines.values():
-            eng.close()
-        self._engines.clear()
+        try:
+            await self.batcher.close()
+            for eng in self._engines.values():
+                eng.close()
+            self._engines.clear()
+        finally:
+            if self._process_spans is not None:
+                self._process_spans.close()
 
     def stats(self) -> dict:
         return {

@@ -16,7 +16,13 @@ batch wait), ``cache`` (probe), ``pad`` (bucket padding), ``shard`` (per-
 shard execute), ``merge`` (partial sum), ``finalize`` (reciprocal-multiply +
 argmax), ``stitch`` (response reassembly) — drained from the execution plan
 after every batch (``TreeEngine.drain_stage_timings``) and surfaced as the
-``*_ms`` columns.  Shard timings come per label (e.g. ``s0:reference[0:5]``,
+``*_ms`` columns.  Inside ``shard``, for backends that cross the device
+boundary (``repro.obs.stages``): ``upload`` (host arrays handed to the
+device), ``launch`` (program launches), ``fetch`` (the wait on the device
+and the copy back), one sample each per shard call, their sum the call
+less the marking's own cost; and ``compile``, a sample per shard call that
+compiled, so its count says how often a warm server recompiled.  Shard
+timings come per label (e.g. ``s0:reference[0:5]``,
 ``fused:reference[x8]``, ``r1/4``): cumulative wall-ms and call counts — the
 observable that shows whether a tree-/row-parallel plan balances its shards.
 ``compile_ms_by_bucket`` tracks the one-time compile/warm cost of each

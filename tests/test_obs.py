@@ -247,8 +247,12 @@ def _assert_trace_integrity(spans, *, expect_shards=None):
         saw_stages.update(n.split(":")[0] for n in ns)
         shard_counts.append(sum(1 for n in ns if n.startswith("shard:")))
     for stage in ("request", "cache_probe", "queue", "batch", "pad",
-                  "shard", "finalize", "stitch"):
+                  "shard", "upload", "launch", "fetch", "finalize", "stitch"):
         assert stage in saw_stages, f"stage {stage!r} missing from traces"
+    # the shard call's stages are spans under its shard:* span
+    for s in spans:
+        if s.name in ("upload", "launch", "fetch"):
+            assert by_id[s.parent_id].name.startswith("shard:"), s
     if expect_shards is not None:
         assert max(shard_counts) >= expect_shards, (
             f"expected >= {expect_shards} shard spans per batch, "
@@ -365,6 +369,186 @@ def test_gateway_disabled_tracing_collects_nothing(small_forest, shuttle_small):
     # stage metrics still flow (they are always-on, tracing is opt-in)
     st = gw.stats()["per_model"]["m"]
     assert st["stages"]["pad"]["count"] > 0
+
+
+# ------------------------------------------- shard-call stages, process spans
+
+def _stage_plan(small_packed):
+    from repro.plan import create_plan
+
+    return create_plan("single", small_packed, mode="integer",
+                       backend="reference")
+
+
+def test_stage_sink_one_sample_per_call_is_the_sum_of_intervals(small_packed):
+    """Two upload intervals in one shard call make ONE upload sample, equal
+    to the sum of the two (read back from their spans, which are not merged
+    because a launch lies between them)."""
+    import time
+
+    from repro.obs import stage
+
+    plan = _stage_plan(small_packed)
+    tracer = Tracer()
+    plan.attach_tracer(tracer)
+    root = tracer.request_span("request")
+
+    def call():
+        with stage("upload", bytes=3):
+            time.sleep(0.002)
+        with stage("launch", programs=2):
+            pass
+        with stage("upload", bytes=4):
+            time.sleep(0.001)
+        with stage("fetch"):
+            pass
+        return 7
+
+    assert plan._timed("x", call, span_parent=root) == 7
+    root.end()
+    st = plan.drain_stage_timings()
+    assert set(st) == {"upload", "launch", "fetch"}
+    assert all(calls == 1 for _, calls in st.values())
+    spans = tracer.spans()
+    shard = next(s for s in spans if s.name == "shard:x")
+    kids = [s for s in spans if s.parent_id == shard.span_id]
+    assert [s.name for s in sorted(kids, key=lambda s: s.t0)] == [
+        "upload", "launch", "upload", "fetch"]
+    ups = [s for s in kids if s.name == "upload"]
+    assert st["upload"][0] == pytest.approx(
+        sum(s.t1 - s.t0 for s in ups) / 1e6, abs=1e-9)
+    assert st["upload"][0] >= 3.0
+    assert sorted(s.attrs["bytes"] for s in ups) == [3, 4]
+    (shard_ms, calls), = plan.drain_timings().values()
+    assert calls == 1 and sum(ms for ms, _ in st.values()) <= shard_ms
+    # adjacent intervals of one stage merge into one span, attrs summed
+
+    def three_launches():
+        for _ in range(3):
+            with stage("launch", programs=1):
+                pass
+
+    plan._timed("y", three_launches, span_parent=tracer.request_span("request"))
+    launches = [s for s in tracer.spans() if s.name == "launch"]
+    assert launches[-1].attrs == {"programs": 3}
+
+
+def test_stage_outside_a_plan_is_a_noop_and_cheap():
+    """With no shard call on this thread, ``stage`` hands out one shared
+    do-nothing context: one thread-local lookup, well under 5 us a call."""
+    import time
+
+    from repro.obs import stage
+    from repro.obs.stages import _NULL_STAGE
+
+    assert stage("upload", bytes=1) is _NULL_STAGE
+    n = 20000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with stage("upload", bytes=1):
+            pass
+    per_call = (time.perf_counter() - t0) / n
+    assert per_call < 5e-6, f"{per_call * 1e6:.2f}us per stage outside a plan"
+
+
+def test_gateway_pallas_route_stages(small_forest, shuttle_small):
+    """The interpreted Pallas route: upload, launch and fetch are each one
+    sample per batch and sum to no more than the shard stage; traced, each
+    is a span under ``shard:*`` carrying its bytes and program count."""
+    _, _, Xte, _ = shuttle_small
+    reg = ModelRegistry()
+    reg.register_forest("m", small_forest)
+    tracer = Tracer()
+    gw = Gateway(reg, "integer:pallas@leaf_major", max_delay_ms=1.0,
+                 cache_rows=0, tracer=tracer)
+    reg.get("m").engine(gw.spec).warm(4)  # no compile inside the batches
+
+    async def run():
+        for i in range(3):
+            await gw.submit("m", Xte[i * 4:(i + 1) * 4])
+        await gw.close()
+
+    asyncio.run(run())
+    st = gw.stats()["per_model"]["m"]
+    stages = st["stages"]
+    for name in ("upload", "launch", "fetch"):
+        assert stages[name]["count"] == st["batches"] == 3, name
+    assert (sum(stages[n]["sum"] for n in ("upload", "launch", "fetch"))
+            <= stages["shard"]["sum"])
+    spans = tracer.spans()
+    _assert_trace_integrity(spans, expect_shards=1)
+    shards = [s for s in spans if s.name == "shard:s0:pallas"]
+    assert len(shards) == 3
+    for shard in shards:
+        kids = [s for s in spans if s.parent_id == shard.span_id]
+        assert {s.name for s in kids} == {"upload", "launch", "fetch"}
+        # the rows, then five node tables and the internal counts; the key
+        # transform's four programs, the kernel and the argmax
+        ups = [s.attrs["bytes"] for s in sorted(kids, key=lambda s: s.t0)
+               if s.name == "upload"]
+        assert ups[0] == 4 * Xte.shape[1] * 4  # a 4-row bucket of float32
+        assert len(ups) == 2 and ups[1] > small_forest.n_estimators * 4 * 5
+        assert sum(s.attrs["programs"] for s in kids if s.name == "launch") == 6
+
+
+def test_gc_spans_follow_an_enabled_tracer(small_forest):
+    """A disabled tracer leaves ``gc.callbacks`` alone; an enabled one gets
+    a hook for its gateway's life, whose spans are process roots that no
+    request tree takes for a request."""
+    import gc
+
+    reg = ModelRegistry()
+    reg.register_forest("m", small_forest)
+    before = list(gc.callbacks)
+    Gateway(reg, mode="integer")
+    Gateway(reg, mode="integer", tracer=Tracer(enabled=False))
+    assert gc.callbacks == before
+    tracer = Tracer()
+    gw = Gateway(reg, mode="integer", tracer=tracer)
+    assert len(gc.callbacks) == len(before) + 1
+    gc.collect()
+    spans = [s for s in tracer.spans() if s.name == "gc"]
+    assert any(s.attrs["generation"] == 2 for s in spans)
+    assert all("collected" in s.attrs and s.parent_id == 0 for s in spans)
+    assert request_trees(tracer.spans()) == []
+    assert "gc" in render_flame(tracer.spans())
+    asyncio.run(gw.close())
+    assert gc.callbacks == before
+
+
+def test_cold_bucket_records_a_compile_stage_and_span(small_forest, shuttle_small):
+    """A first batch compiles: always-on ``compile`` stage sample, a span
+    under its shard span; a compile outside any shard call is a process
+    span of the watching tracer."""
+    import jax
+    import jax.numpy as jnp
+
+    _, _, Xte, _ = shuttle_small
+    reg = ModelRegistry()
+    reg.register_forest("m", small_forest)
+    tracer = Tracer()
+    gw = Gateway(reg, mode="integer", cache_rows=0, tracer=tracer)
+
+    async def run():
+        await gw.submit("m", Xte[:5])
+
+    asyncio.run(run())
+    st = gw.stats()["per_model"]["m"]
+    assert st["stages"]["compile"]["count"] >= 1
+    spans = tracer.spans()
+    by_id = {s.span_id: s for s in spans}
+    inside = [s for s in spans if s.name == "compile" and s.parent_id]
+    assert inside and all(by_id[s.parent_id].name.startswith("shard:")
+                          for s in inside)
+    assert all(isinstance(s.attrs["cached"], bool) for s in inside)
+    jax.jit(lambda x: x * 5 - 2)(jnp.arange(3)).block_until_ready()
+    outside = [s for s in tracer.spans() if s.name == "compile"
+               and not s.parent_id]
+    assert outside and all(s.t1 >= s.t0 for s in outside)
+    asyncio.run(gw.close())
+    n = len(tracer.spans())
+    jax.jit(lambda x: x * 7 - 2)(jnp.arange(3)).block_until_ready()
+    assert len(tracer.spans()) == n  # closed: no longer watched
 
 
 # ---------------------------------------------------------------- exposition
