@@ -8,6 +8,13 @@ kernel's ``block_b`` tiling.  ``interpret=None`` (the default) compiles the
 kernel on TPU and interprets it on CPU; a kernel that fails to compile
 raises, with no fallback.
 
+The node tables are device-resident: the first ``predict_partials`` places
+them on the default device (``kernels.ops.place_tables``, the ``place``
+stage) and every later batch walks that copy, uploading only its rows.
+The copy lives as long as the backend, i.e. its ``ModelVersion``'s
+engine, so a hot-swapped version places its own.  The kernel wrappers'
+host-array entry points still upload the tables with every call.
+
 Layout-specialized: the backend prefers the ``leaf_major`` layout, where the
 linear-scan kernel (``impl="leaf_major"``) walks each tree's internal-node
 prefix front-to-back with compare+select steps — no per-depth node-table
@@ -27,6 +34,7 @@ tree-parallel plan can merge per-shard kernel partials bit-exactly.
 """
 from __future__ import annotations
 
+import threading
 from typing import Optional
 
 import numpy as np
@@ -87,6 +95,18 @@ class PallasBackend(TreeBackend):
         self._kernel_kwargs = dict(
             block_b=block_b, block_t=block_t, impl=impl, interpret=interpret
         )
+        self._tables = None  # the device-resident node tables, once placed
+        self._place_lock = threading.Lock()  # shard threads share a backend
+
+    def _resident_tables(self):
+        """The node tables on the device, placed by the first call."""
+        if self._tables is None:
+            from repro.kernels.ops import place_tables
+
+            with self._place_lock:
+                if self._tables is None:
+                    self._tables = place_tables(self.packed)
+        return self._tables
 
     def predict_partials(self, X):
         from repro.kernels.ops import packed_predict_integer
@@ -94,6 +114,7 @@ class PallasBackend(TreeBackend):
         kw = self._kernel_kwargs
         if self._auto_small_batch and len(X) < _SMALL_BATCH_GATHER_ROWS:
             kw = dict(kw, impl="gather")
-        acc, _ = packed_predict_integer(self.packed, X, **kw)
+        acc, _ = packed_predict_integer(self.packed, X,
+                                        tables=self._resident_tables(), **kw)
         with stage("fetch"):  # the wait on the device and the copy back
             return np.asarray(acc)

@@ -19,6 +19,12 @@ Host steps are marked as the shard call's stages (``repro.obs.stages``):
 handing host arrays to the device is ``upload`` (``bytes``), and the key
 transform, the block choice, the kernel's jitted call and the argmax are
 ``launch`` (``programs``: device programs started).
+
+The entry points take host arrays and upload them on every call.  A
+serving backend instead places its node tables once with
+:func:`place_tables` (the ``place`` stage) and hands the device-resident
+copy to ``packed_predict_integer(..., tables=...)``; each batch then
+uploads only its rows.
 """
 from __future__ import annotations
 
@@ -50,16 +56,32 @@ def _round_up(v, m):
     return -(-v // m) * m
 
 
-def _upload(*arrays):
-    """Each host (numpy) array put on the device as the ``upload`` stage,
-    whose ``bytes`` are theirs; without one, ``arrays`` come back as they
-    are (device arrays, tracers, None), and no stage is marked."""
+def _upload(*arrays, name="upload"):
+    """Each host (numpy) array put on the device as stage ``name``, whose
+    ``bytes`` are theirs; without one, ``arrays`` come back as they are
+    (device arrays, tracers, None), and no stage is marked."""
     host = [a for a in arrays if isinstance(a, np.ndarray)]
     if not host:
         return arrays
-    with stage("upload", bytes=sum(a.nbytes for a in host)):
+    with stage(name, bytes=sum(a.nbytes for a in host)):
         return tuple(jnp.asarray(a) if isinstance(a, np.ndarray) else a
                      for a in arrays)
+
+
+def _host_tables(packed):
+    """The node tables the kernel walks: feature, threshold key, left,
+    right, fixed-point leaves and the leaf_major internal counts (None off
+    that layout, or where its node order is not scannable)."""
+    return (packed.feature, packed.threshold_key, packed.left, packed.right,
+            packed.leaf_fixed, packed.internal_counts)
+
+
+def place_tables(packed):
+    """``packed``'s node tables put on the default device as the ``place``
+    stage, for ``packed_predict_integer``'s ``tables``: the conversion each
+    call's upload makes, so the kernel's jitted call sees the same shapes
+    and dtypes either way."""
+    return _upload(*_host_tables(packed), name="place")
 
 
 def _block_words(block_b, block_t, n, f, c):
@@ -227,7 +249,7 @@ def tree_predict_integer(
         )
 
 
-def packed_predict_integer(packed, X, impl: str = "auto", **kw):
+def packed_predict_integer(packed, X, impl: str = "auto", tables=None, **kw):
     """Node-table entry point: float features in, (scores, preds) out.
 
     ``packed``: a node-table artifact (``PackedEnsemble`` in ``padded`` or
@@ -237,6 +259,10 @@ def packed_predict_integer(packed, X, impl: str = "auto", **kw):
     resolved impl walks (``leaf_major`` for the scan, ``padded`` otherwise).
     Pinning ``impl="leaf_major"`` on a padded artifact re-materializes it as
     leaf_major through the IR back-reference.
+
+    ``tables``: :func:`place_tables` of this same artifact, walked in place
+    of its host arrays, so that only ``X`` is uploaded; without it the
+    tables are uploaded with every call.
     """
     if hasattr(packed, "materialize"):  # a ForestIR: take the kernel's layout
         packed = packed.materialize(
@@ -265,10 +291,9 @@ def packed_predict_integer(packed, X, impl: str = "auto", **kw):
     x, = _upload(X)
     with stage("launch", programs=_KEY_PROGRAMS):
         keys = float_to_key(x)
-    *tables, nint = _upload(
-        packed.feature, packed.threshold_key, packed.left, packed.right,
-        packed.leaf_fixed, packed.internal_counts if impl == "leaf_major" else None)
-    acc = tree_predict_integer(keys, *tables, depth=packed.max_depth, impl=impl,
+    *nodes, nint = tables or _host_tables(packed)
+    *nodes, nint = _upload(*nodes, nint if impl == "leaf_major" else None)
+    acc = tree_predict_integer(keys, *nodes, depth=packed.max_depth, impl=impl,
                                internal_counts=nint, **kw)
     with stage("launch", programs=1):
         return acc, jnp.argmax(acc, axis=1).astype(jnp.int32)

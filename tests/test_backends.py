@@ -354,6 +354,31 @@ def test_pallas_auto_falls_back_to_gather_on_unscannable_order():
         create_backend("pallas", lm, mode="integer", impl="leaf_major")
 
 
+def test_pallas_places_its_tables_once(random_case, monkeypatch):
+    """The serving backend puts its node tables on the device on its first
+    batch and walks that one copy after, through the scan and the
+    small-batch gather fallback alike, with partials bit-identical to the
+    reference walk."""
+    import repro.kernels.ops as ops
+
+    packed, rows = random_case
+    ref = create_backend("reference", packed, mode="integer")
+    eng = TreeEngine(packed.to_ir(), "integer:pallas@leaf_major")
+    walked, real = [], ops.packed_predict_integer
+
+    def spy(packed, X, **kw):
+        walked.append((kw["impl"], kw["tables"]))
+        return real(packed, X, **kw)
+
+    monkeypatch.setattr(ops, "packed_predict_integer", spy)
+    for n in (3, 97, 17, 40):  # buckets 4, 128, 32, 64: gather, scan, gather, scan
+        np.testing.assert_array_equal(eng.predict_partials(rows[:n]),
+                                      np.asarray(ref.predict_partials(rows[:n])))
+    assert [impl for impl, _ in walked] == ["gather", "leaf_major"] * 2
+    assert all(tables is walked[0][1] for _, tables in walked)
+    assert eng.drain_stage_timings()["place"][1] == 1
+
+
 @pytest.mark.requires_gcc
 @pytest.mark.parametrize("block_rows", BLOCK_ROWS)
 @pytest.mark.parametrize("mode", ["flint", "integer"])

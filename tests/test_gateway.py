@@ -241,6 +241,38 @@ def test_gateway_hot_swap_routes_new_version(small_forest, shuttle_small):
     assert not np.array_equal(s_v1, s_v2)
 
 
+def test_gateway_hot_swap_places_the_new_forest(small_forest, shuttle_small):
+    """On the Pallas route a hot-swapped version walks tables of its own:
+    the gateway answers with the new forest's scores, and the new version's
+    backend places its own copy on the device (a second ``place``)."""
+    Xtr, ytr, Xte, _ = shuttle_small
+    from repro.trees.forest import RandomForestClassifier
+
+    other = RandomForestClassifier(n_estimators=3, max_depth=4, seed=42).fit(
+        Xtr[:1500], ytr[:1500]
+    )
+    reg = ModelRegistry()
+    reg.register_forest("m", small_forest)
+    gw = Gateway(reg, "integer:pallas@leaf_major", max_delay_ms=1.0,
+                 cache_rows=0)
+
+    async def run():
+        await gw.submit("m", Xte[:8])
+        placed = [reg.get("m").engine(gw.spec).backend]
+        reg.register_forest("m", other)  # hot-swap under the gateway
+        s_v2, _ = await gw.submit("m", Xte[:8])
+        placed.append(reg.get("m").engine(gw.spec).backend)
+        await gw.close()
+        return s_v2, placed
+
+    s_v2, placed = asyncio.run(run())
+    d_v2, _ = reg.get("m").engine("integer").predict_scores(Xte[:8])
+    np.testing.assert_array_equal(s_v2, d_v2)
+    assert gw.stats()["per_model"]["m"]["stages"]["place"]["count"] == 2
+    assert [b._tables[0].shape[0] for b in placed] == [
+        small_forest.n_estimators, other.n_estimators]
+
+
 def test_gateway_survives_event_loop_reuse(small_forest, shuttle_small):
     """asyncio.run tears down lane workers with its loop; a later loop must
     respawn them instead of hanging on a dead queue."""

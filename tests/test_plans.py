@@ -216,6 +216,27 @@ def test_plan_bit_identity_degenerate(case, plan, shards):
         np.testing.assert_array_equal(p, p_ref, err_msg=f"{plan}/{case}/{mode}")
 
 
+@pytest.mark.parametrize("plan,shards", [("tree_parallel", 3),
+                                         ("row_parallel", 4)])
+def test_threaded_pallas_shards_place_their_tables_once(
+        small_packed, probe_rows, reference_scores, plan, shards):
+    """Threaded plans over Pallas shards place each backend's tables once,
+    on the first batch: a tree shard its own sub-forest, the row shards the
+    one backend they share (its threads race to place it), and the merge
+    stays bit-exact on every batch."""
+    s_ref, p_ref = reference_scores["integer"]
+    eng = TreeEngine(small_packed, f"integer:pallas+{plan}:{shards}")
+    for _ in range(3):
+        s, p = _scores(eng, probe_rows)
+        np.testing.assert_array_equal(s, s_ref, err_msg=plan)
+        np.testing.assert_array_equal(p, p_ref, err_msg=plan)
+    backends = eng.plan.backends
+    assert eng.drain_stage_timings()["place"][1] == len(backends)
+    assert [b._tables[0].shape for b in backends] == [
+        b.packed.feature.shape for b in backends]
+    assert sum(b.packed.n_trees for b in backends) == small_packed.n_trees
+
+
 def test_heterogeneous_tree_parallel_reference_plus_pallas(
         small_packed, probe_rows, reference_scores):
     """A tree-parallel plan mixing two *different* backends — half the forest
