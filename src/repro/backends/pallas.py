@@ -21,6 +21,9 @@ prefix front-to-back with compare+select steps — no per-depth node-table
 gathers.  ``impl="auto"`` (the default) resolves per layout: linear scan on
 ``leaf_major`` tables, the per-level ``gather`` walk on ``padded`` ones —
 i.e. pinning ``layout="padded"`` falls back to padded+gather untouched.
+Only the scan cuts the node axis, so it alone serves trees too large for
+one grid cell (unpruned forests); a gather or onehot walk that cannot hold
+a whole tree is refused at construction.
 
 The kernel implements exactly the paper's integer accumulation (int32 FlInt
 compares, uint32 fixed-point adds) — which, since the partials/finalize
@@ -49,7 +52,9 @@ _DEFAULT_BLOCK_B = 256  # the kernel wrapper's row-tile default
 # run the gather walk instead: the scan's per-cell prefix pass costs the same
 # for 2 rows as for 256, so at tiny batches the cheaper per-call gather wins
 # (measured on the BENCH_7 b32 pathology).  Both impls produce identical
-# uint32 partials, so the switch is invisible to conformance.
+# uint32 partials, so the switch is invisible to conformance.  Only forests
+# whose whole trees fit a grid cell take it: the gather walk cannot cut the
+# node axis as the scan does.
 _SMALL_BATCH_GATHER_ROWS = 64
 
 
@@ -72,6 +77,8 @@ class PallasBackend(TreeBackend):
     def __init__(self, packed: PackedEnsemble, mode: str = "integer", *,
                  block_b: int = _DEFAULT_BLOCK_B, block_t: Optional[int] = None,
                  impl: str = "auto", interpret: Optional[bool] = None):
+        from repro.kernels.ops import holds_whole_trees
+
         super().__init__(packed, mode)
         scannable = getattr(packed, "internal_counts", None) is not None
         was_auto = impl == "auto"
@@ -88,10 +95,21 @@ class PallasBackend(TreeBackend):
                 f"this backend was materialized on the {self.layout!r} layout"
                 + ("" if scannable else " without a scannable node order")
             )
+        t, n = self.packed.feature.shape
+        f, c = self.packed.n_features, self.packed.leaf_fixed.shape[-1]
+        if impl in ("gather", "onehot") and not holds_whole_trees(t, n, f, c):
+            raise ValueError(
+                f"impl={impl!r} holds whole trees in a grid cell, and {t} "
+                f"trees of {n} nodes ({f} features, {c} classes) do not fit "
+                "one; the leaf_major scan (impl='leaf_major', or 'auto' on "
+                "the leaf_major layout) cuts the node axis into blocks")
         self.impl = impl
         # only an *auto* resolution may fall back per batch — an explicitly
-        # pinned impl is a routing decision the caller owns
-        self._auto_small_batch = impl == "leaf_major" and was_auto
+        # pinned impl is a routing decision the caller owns — and only where
+        # gather holds whole trees at the tiling it would run: the pinned
+        # block_t, or its own floor
+        self._auto_small_batch = (impl == "leaf_major" and was_auto
+                                  and holds_whole_trees(t, n, f, c, block_t))
         self._kernel_kwargs = dict(
             block_b=block_b, block_t=block_t, impl=impl, interpret=interpret
         )

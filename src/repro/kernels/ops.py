@@ -1,10 +1,10 @@
 """Jitted public wrapper around the tree-traversal Pallas kernels.
 
 Picks tiling-aligned blocks inside the VMEM/SMEM budgets, pads (rows to
-``block_b`` multiples, trees to ``block_t`` multiples and nodes to 128-node
-chunks, all with inert self-looping zero-mass entries), lays the tables out
-for the kernel (``tree_traverse`` module docstring), and exposes an
-ensemble-level entry point.  ``interpret=None`` lets the platform decide:
+``block_b`` multiples, trees to ``block_t`` multiples and nodes to whole
+node blocks of 128-node chunks, all with inert self-looping zero-mass
+entries), lays the tables out for the kernel (``tree_traverse`` module
+docstring), and exposes an ensemble-level entry point.  ``interpret=None`` lets the platform decide:
 compiled on TPU, interpreted on CPU.
 
 Layout contract (ForestIR): the kernel consumes dense ``(T, N)`` node tables
@@ -18,7 +18,8 @@ backend instead.
 Host steps are marked as the shard call's stages (``repro.obs.stages``):
 handing host arrays to the device is ``upload`` (``bytes``), and the key
 transform, the block choice, the kernel's jitted call and the argmax are
-``launch`` (``programs``: device programs started).
+``launch`` (``programs``: device programs started; the kernel's call also
+notes ``impl``, the walk, and ``node_blocks``, the scan's node blocks).
 
 The entry points take host arrays and upload them on every call.  A
 serving backend instead places its node tables once with
@@ -87,7 +88,8 @@ def place_tables(packed):
 def _block_words(block_b, block_t, n, f, c):
     """VMEM words per grid cell, two pipeline buffers per block: the x tiles,
     the chunked node fields (4 rows, sublane-padded to 8), the chunked leaf
-    table and the output tiles, at the kernel's padded widths."""
+    table and the output tiles, at the kernel's padded widths.  ``n`` is the
+    nodes a cell holds: whole trees, or one node block of the scan."""
     n, c = _round_up(n, LANES), _round_up(c, 8)
     return 2 * (block_b * _round_up(f, 8) + block_t * n * (8 + c)
                 + block_b * c)
@@ -95,13 +97,36 @@ def _block_words(block_b, block_t, n, f, c):
 
 def _smem_words(block_t, n):
     """SMEM words per grid cell: the scalar node fields (feature, key, left,
-    right) of ``block_t`` trees, two pipeline buffers."""
+    right) of ``block_t`` trees' ``n`` nodes, two pipeline buffers."""
     return 2 * block_t * 4 * _round_up(n, LANES)
 
 
-def _fits(block_b, block_t, n, f, c):
-    return (_block_words(block_b, block_t, n, f, c) * 4 <= _VMEM_BUDGET_BYTES
+def _fits(block_b, block_t, n, f, c, chunked=False):
+    vmem = _block_words(block_b, block_t, n, f, c)
+    if chunked:  # the scan's scratch: each row's node per tree, (1, 128)
+        vmem += block_t * block_b * 8  # rows padded to 8 sublanes
+    return (vmem * 4 <= _VMEM_BUDGET_BYTES
             and _smem_words(block_t, n) * 4 <= _SMEM_BUDGET_BYTES)
+
+
+def _node_block(block_b, block_t, n, f, c):
+    """Nodes per grid cell of the scan at (block_b, block_t): every node of
+    a tree when whole trees fit, else the fewest node blocks that fit, as
+    even as 128-node chunks allow; None when not even one chunk fits."""
+    npad = _round_up(n, LANES)
+    if _fits(block_b, block_t, npad, f, c):
+        return npad
+    lo, hi = 0, npad // LANES - 1  # chunks a block may hold: lo fits, hi not
+    while lo < hi:
+        mid = (lo + hi + 1) // 2
+        if _fits(block_b, block_t, mid * LANES, f, c, chunked=True):
+            lo = mid
+        else:
+            hi = mid - 1
+    if lo == 0:
+        return None
+    blocks = -(-npad // (lo * LANES))
+    return _round_up(-(-npad // blocks), LANES)
 
 
 def _align_block_b(block_b, b):
@@ -115,64 +140,104 @@ def _align_block_t(block_t, t):
     return t if block_t >= t else min(t, _round_up(max(1, block_t), 8))
 
 
-def pick_blocks(b, t, n, f, c, block_b=256):
-    """Choose aligned (block_b, block_t) so a cell fits the VMEM/SMEM budgets.
+def holds_whole_trees(t, n, f, c, block_t=None):
+    """Whether whole trees fit one grid cell of 128 rows at ``block_t``
+    trees (aligned), or at the smallest aligned tiling, (128, min(t, 8)),
+    when it is None: the gather and onehot walks need it."""
+    block_t = min(t, 8) if block_t is None else _align_block_t(block_t, t)
+    return _fits(LANES, block_t, n, f, c)
 
-    ``block_b`` is a multiple of 128 and ``block_t`` a multiple of 8 or
-    ``t``.  The tree dimension shrinks first; when even the smallest aligned
-    ``block_t`` is over budget (wide leaf tables make the output tiles and
-    leaf chunks dominate), the row block halves and the search repeats.  The
-    floor is (128, min(t, 8)), the smallest aligned tiling.
+
+def pick_blocks(b, t, n, f, c, block_b=256, *, chunk_nodes=False):
+    """Choose aligned (block_b, block_t, block_n) so a cell fits the
+    VMEM/SMEM budgets.
+
+    ``block_b`` is a multiple of 128, ``block_t`` a multiple of 8 or ``t``,
+    and ``block_n`` a multiple of 128: the nodes of a tree a cell holds,
+    every node (``n`` padded to 128) unless ``chunk_nodes``.  The tree
+    dimension shrinks first.  Once ``block_t`` is at its floor, ``min(t,
+    8)``, the scan (``chunk_nodes``) cuts the node axis into the fewest
+    blocks that fit (:func:`_node_block`).  When that is over budget too
+    (wide leaf tables make the output tiles and leaf chunks dominate), the
+    row block halves and the search repeats.  Nothing fitting even at
+    (128, min(t, 8), 128) raises ``ValueError``.
 
     Tiny batches (``b < 64``) additionally clamp ``block_t`` proportionally
     to the rows that amortize it (a heuristic from host timings; the clamp
     only ever shrinks, so the fit is preserved).
     """
     block_b = _align_block_b(block_b, b)
+    npad = _round_up(n, LANES)
     sizes = [t] + list(range(_round_up(t, 8) - 8, 0, -8))
     while True:
         for block_t in sizes:
-            if _fits(block_b, block_t, n, f, c):
+            if _fits(block_b, block_t, npad, f, c):
                 if b < _TINY_BATCH_ROWS:
                     block_t = min(block_t, _align_block_t(
                         (t * b) // _TINY_BATCH_ROWS, t))
-                return block_b, block_t
+                return block_b, block_t, npad
+        block_n = (_node_block(block_b, sizes[-1], n, f, c)
+                   if chunk_nodes else None)
+        if block_n is not None:
+            return block_b, sizes[-1], block_n
         if block_b == LANES:
-            return LANES, sizes[-1]  # nothing left to shrink
+            raise ValueError(
+                f"no tiling of {t} trees of {n} nodes, {f} features and {c} "
+                f"classes fits the budgets ({_VMEM_BUDGET_BYTES} B of VMEM, "
+                f"{_SMEM_BUDGET_BYTES} B of SMEM) even at (128, {sizes[-1]}, "
+                f"{128 if chunk_nodes else npad})"
+                + ("" if chunk_nodes or npad == LANES else
+                   "; the leaf_major scan can cut the node axis"))
         block_b = _align_block_b(block_b // 2, b)
 
 
-def pick_blocks_candidates(b, t, n, f, c, block_b=256):
+def pick_blocks_candidates(b, t, n, f, c, block_b=256, *, chunk_nodes=False):
     """The measured-autotune grid around the heuristic: the ``pick_blocks``
     choice plus its aligned, budget-feasible half/double neighbours along
-    each axis.
+    the row and tree axes, each with the ``block_n`` the budgets then give.
 
     The heuristic optimizes a *budget*, not a runtime; ``TreeEngine.warm``'s
-    autotuner times these candidates on the live host and pins the winner.
-    Deduplicated, heuristic first (ties resolve to it), every entry fits the
-    budgets, so any candidate is safe to pin.
+    autotuner times these candidates on the live host and pins the winner's
+    (block_b, block_t).  Deduplicated, heuristic first (ties resolve to it),
+    every entry fits the budgets, so any candidate is safe to pin.  Node
+    blocks are offered only for trees that no whole-tree tiling holds: where
+    whole trees fit, a backend sends small batches to the gather walk at the
+    pinned ``block_t``, and that walk cannot cut the node axis.
     """
-    auto_b, auto_t = pick_blocks(b, t, n, f, c, block_b)
-    cands = [(auto_b, auto_t)]
+    chunk_nodes = chunk_nodes and not holds_whole_trees(t, n, f, c)
+    auto = pick_blocks(b, t, n, f, c, block_b, chunk_nodes=chunk_nodes)
+    auto_b, auto_t, _ = auto
+    cands = [auto]
     for bb, bt in (
         (auto_b, _align_block_t(auto_t // 2, t)),
         (_align_block_b(auto_b // 2, b), auto_t),
         (auto_b, _align_block_t(auto_t * 2, t)),
     ):
-        if (bb, bt) not in cands and _fits(bb, bt, n, f, c):
-            cands.append((bb, bt))
+        bn = _pinned_node_block(bb, bt, n, f, c, chunk_nodes)
+        if bn is not None and (bb, bt, bn) not in cands:
+            cands.append((bb, bt, bn))
     return cands
 
 
-@partial(jax.jit, static_argnames=("depth", "block_b", "block_t", "impl", "interpret"))
+def _pinned_node_block(block_b, block_t, n, f, c, chunk_nodes):
+    """``block_n`` for a pinned (block_b, block_t): whole trees where they
+    fit, node blocks on the scan, None where nothing fits."""
+    if chunk_nodes:
+        return _node_block(block_b, block_t, n, f, c)
+    npad = _round_up(n, LANES)
+    return npad if _fits(block_b, block_t, npad, f, c) else None
+
+
+@partial(jax.jit, static_argnames=("depth", "block_b", "block_t", "block_n",
+                                   "impl", "interpret"))
 def _traverse(x_keys, feature, key, left, right, leaf, nint, *,
-              depth, block_b, block_t, impl, interpret):
+              depth, block_b, block_t, block_n, impl, interpret):
     """Pad and lay out the (T, N) tables for the kernel, run it, and return
     (B, C) uint32 partials."""
     b, f = x_keys.shape
     t, n = feature.shape
     c = leaf.shape[-1]
-    bp, tp, npad = _round_up(b, block_b), _round_up(t, block_t), _round_up(n, LANES)
+    bp, tp, npad = _round_up(b, block_b), _round_up(t, block_t), _round_up(n, block_n)
 
     # inert padding: feature-less self-looping nodes with zero leaf mass
     # fill every tree to npad nodes and the forest to tp trees
@@ -195,7 +260,8 @@ def _traverse(x_keys, feature, key, left, right, leaf, nint, *,
     if nint is not None:  # padding trees have no internal prefix to scan
         nint = jnp.pad(nint, (0, tp - t))
     out = tree_traverse(x, fields, leaf, nint, depth=depth, block_b=block_b,
-                        block_t=block_t, impl=impl, interpret=interpret)
+                        block_t=block_t, block_n=block_n, impl=impl,
+                        interpret=interpret)
     out = out.transpose(0, 2, 1).reshape(bp, c)[:b]
     return jax.lax.bitcast_convert_type(out, jnp.uint32)
 
@@ -221,9 +287,11 @@ def tree_predict_integer(
     ``internal_counts`` (the leaf_major layout's per-tree internal-prefix
     lengths); the other impls walk any node-table ordering.  ``block_b`` caps
     the rows per grid cell and an explicit ``block_t`` is aligned up (see
-    :func:`pick_blocks`).  ``interpret=None`` lets the platform decide.
-    Returns (B, C) uint32 scores, bit-identical to
-    ``ref.tree_predict_integer_ref``.
+    :func:`pick_blocks`); the scan's node block follows from the budgets,
+    the other walks hold whole trees.  ``interpret=None`` lets the platform
+    decide.  The ``launch`` stage of the kernel's call records the walk
+    (``impl``) and its ``node_blocks``.  Returns (B, C) uint32 scores,
+    bit-identical to ``ref.tree_predict_integer_ref``.
     """
     if impl == "leaf_major" and internal_counts is None:
         raise ValueError(
@@ -233,19 +301,31 @@ def tree_predict_integer(
     x_keys, feature, threshold_key, left, right, leaf_fixed, nint = _upload(
         x_keys, feature, threshold_key, left, right, leaf_fixed,
         internal_counts if impl == "leaf_major" else None)
-    with stage("launch", programs=1):
+    with stage("launch", programs=1) as launch:
         x_keys = jnp.asarray(x_keys, jnp.int32)
         b, f = x_keys.shape
         t, n = feature.shape
         c = leaf_fixed.shape[-1]
-        block_b, auto_t = pick_blocks(b, t, n, f, c, block_b)
-        block_t = auto_t if block_t is None else _align_block_t(block_t, t)
+        chunk_nodes = impl == "leaf_major"
+        block_b, auto_t, block_n = pick_blocks(b, t, n, f, c, block_b,
+                                               chunk_nodes=chunk_nodes)
+        if block_t is None:
+            block_t = auto_t
+        else:
+            block_t = _align_block_t(block_t, t)
+            block_n = _pinned_node_block(block_b, block_t, n, f, c,
+                                         chunk_nodes)
+            if block_n is None:
+                raise ValueError(
+                    f"block_t={block_t} at block_b={block_b} holds no "
+                    f"{impl} block of {n}-node trees inside the budgets")
         if nint is not None:
             nint = jnp.asarray(nint, jnp.int32)
+        launch.note(impl=impl, node_blocks=-(-_round_up(n, LANES) // block_n))
         return _traverse(
             x_keys, feature, threshold_key, left, right, leaf_fixed, nint,
-            depth=depth, block_b=block_b, block_t=block_t, impl=impl,
-            interpret=resolve_interpret(interpret),
+            depth=depth, block_b=block_b, block_t=block_t, block_n=block_n,
+            impl=impl, interpret=resolve_interpret(interpret),
         )
 
 

@@ -14,10 +14,14 @@ every block is tiling-aligned and every lookup is a form Mosaic lowers.
     node chunks:  (T, N/128, 4, 128)       the same fields, chunked (VMEM)
     leaf chunks:  (T, N/128, C, 128)       int32 bits of uint32 leaf values
     out tiles:    (B/128, C, 128)          int32 bits of uint32 partials
-``N`` is padded to a multiple of 128 with inert self-looping nodes.
+``N`` is padded to a multiple of 128 (of the node block, on the scan) with
+inert self-looping nodes.
 
 Grid: ``(B/block_b, T/block_t)`` with the tree dimension innermost, so each
-output block stays resident while all tree-blocks accumulate into it.
+output block stays resident while all tree-blocks accumulate into it.  The
+linear scan adds a third, innermost axis over ``N/block_n`` node blocks, so
+a tree of any size streams through SMEM and VMEM a block of nodes at a time
+(:func:`_kernel_scan`); the other walks hold whole trees in a grid cell.
 ``ops.pick_blocks`` keeps the double-buffered blocks inside the VMEM and SMEM
 budgets; the v5e compiler reports 1 MiB of SMEM.
 
@@ -30,7 +34,8 @@ Three walk strategies, selected statically:
     node index and select that node's scalar fields (SMEM) on a hit — only
     elementwise compare+select, O(block_b * N) per level.
   * ``impl="leaf_major"``: the layout-specialized linear scan over each
-    tree's internal-node prefix (see :func:`_kernel_scan`).
+    tree's internal-node prefix, node block by node block (see
+    :func:`_kernel_scan`).
 All three finish a tree with the same chunked leaf lookup, and all are
 bit-identical to ``ref.py`` (interpret-mode tests) and compile for the chip
 (``tests/test_tpu_compile.py``).
@@ -96,13 +101,20 @@ def _feature_values(x, feat):
 
 
 def _add_leaves(leaf_ref, t, node, out_ref):
-    """out tile r += tree t's leaf row at node[r], for every row tile."""
+    """out tile r += tree t's leaf row at node[r], for every row tile; a
+    node outside the block's chunks adds nothing."""
     for r in range(node.shape[0]):
         out_ref[r] += _chunk_lookup(leaf_ref, t, node[r])
 
 
-def _init_out(out_ref):
-    @pl.when(pl.program_id(1) == 0)
+def _init_out(out_ref, *axes):
+    """Zero the resident output block on the first step of every
+    accumulating grid axis (the tree axis, and the node axis if any)."""
+    first = pl.program_id(1) == 0
+    for axis in axes:
+        first = first & (pl.program_id(axis) == 0)
+
+    @pl.when(first)
     def _():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -152,35 +164,69 @@ def _kernel_onehot(x_ref, fields_ref, leaf_ref, out_ref, *, depth):
     jax.lax.fori_loop(0, block_t, per_tree, 0)
 
 
-def _kernel_scan(nint_ref, x_ref, fields_ref, leaf_ref, out_ref):
+def _kernel_scan(nint_ref, x_ref, fields_ref, leaf_ref, out_ref, *node_ref,
+                 node_blocks):
     """Linear-scan walk over the leaf_major layout's internal-node prefix.
 
     The layout guarantees (a) tree nodes are permuted internal-first, so
-    indices [0, n_internal) are exactly the split nodes, and (b) every child
-    sits at a strictly larger index than its parent.  One forward pass over
-    the prefix therefore routes every row to its leaf: when the scan reaches
-    node j, any row currently parked at j steps to a child with index > j,
-    which a later scan step (or the final leaf lookup) picks up.  The
-    scanned node's fields are SMEM scalars and its feature is one row of the
-    x tiles, so each step is a broadcast compare+select over the row block.
-    Padding trees have ``n_internal == 0`` and skip the scan entirely.
+    indices [0, n_internal) are exactly the split nodes and every leaf lies
+    after every internal node, and (b) every child sits at a strictly larger
+    index than its parent.  One forward pass over the prefix therefore
+    routes every row to its leaf: when the scan reaches node j, any row
+    currently parked at j steps to a child with index > j, which a later
+    scan step (or the leaf lookup) picks up.  The scanned node's fields are
+    SMEM scalars and its feature is one row of the x tiles, so each step is
+    a broadcast compare+select over the row block.  Padding trees have
+    ``n_internal == 0`` and skip the scan entirely.
+
+    The forward pass may be cut anywhere, which is what the node axis of
+    the grid does: node block ``k`` holds nodes ``[k*block_n,
+    (k+1)*block_n)``.  Each row's current node, per tree of the block, lives
+    in the VMEM scratch ``node_ref`` across node blocks (zeroed, the root,
+    at block 0).  Block ``k`` scans the internal nodes ``[k*block_n,
+    min((k+1)*block_n, n_internal))`` and then adds the leaf of every row
+    whose node lies in the block.  Before block ``k`` every row's node is a
+    leaf or at least ``k*block_n`` (by (b), a scan only moves rows forward,
+    and block ``k-1`` moved every row parked inside it).  So after block
+    ``k``'s scan a row whose node lies in the block sits on a leaf, its walk
+    done: by (a) no later scan step can reach it.  Each row's leaf lies in
+    exactly one block, and is added exactly once.
+
+    With one node block (``node_blocks == 1``) the scan keeps its nodes in
+    registers and the program is the whole-tree walk.
     """
-    _init_out(out_ref)
-    block_t = fields_ref.shape[0]
+    _init_out(out_ref, 2)
+    block_t, _, block_n = fields_ref.shape
     t0 = pl.program_id(1) * block_t
+    rows = (x_ref.shape[0], 1, LANES)
+    if node_blocks == 1:
+        lo = 0
+    else:
+        node_ref, = node_ref
+        lo = pl.program_id(2) * block_n
+
+        @pl.when(pl.program_id(2) == 0)
+        def _():
+            node_ref[...] = jnp.zeros_like(node_ref)
 
     def per_tree(t, carry):
         def scan_node(j, node):
-            feat = jnp.maximum(fields_ref[t, 0, j], 0)
+            i = j if node_blocks == 1 else j - lo  # index within the block
+            feat = jnp.maximum(fields_ref[t, 0, i], 0)
             xv = x_ref[:, pl.ds(feat, 1), :]  # (R, 1, 128)
-            nxt = jnp.where(xv <= fields_ref[t, 1, j],
-                            fields_ref[t, 2, j], fields_ref[t, 3, j])
+            nxt = jnp.where(xv <= fields_ref[t, 1, i],
+                            fields_ref[t, 2, i], fields_ref[t, 3, i])
             return jnp.where(node == j, nxt, node)
 
-        node = jax.lax.fori_loop(
-            0, nint_ref[t0 + t], scan_node,
-            jnp.zeros((x_ref.shape[0], 1, LANES), jnp.int32))
-        _add_leaves(leaf_ref, t, node, out_ref)
+        if node_blocks == 1:
+            node = jax.lax.fori_loop(0, nint_ref[t0 + t], scan_node,
+                                     jnp.zeros(rows, jnp.int32))
+            _add_leaves(leaf_ref, t, node, out_ref)
+        else:
+            end = jnp.minimum(nint_ref[t0 + t], lo + block_n)
+            node = jax.lax.fori_loop(lo, end, scan_node, node_ref[t])
+            node_ref[t] = node
+            _add_leaves(leaf_ref, t, node - lo, out_ref)
         return carry
 
     jax.lax.fori_loop(0, block_t, per_tree, 0)
@@ -188,14 +234,16 @@ def _kernel_scan(nint_ref, x_ref, fields_ref, leaf_ref, out_ref):
 
 def tree_traverse(x_tiles, fields, leaf_chunks, internal_counts=None, *,
                   depth: int, block_b: int, block_t: int, impl: str,
-                  interpret: bool | None = None):
+                  block_n: int | None = None, interpret: bool | None = None):
     """Raw pallas_call over the kernel-side layout (module docstring).
 
     Shapes must already divide evenly: ``block_b`` is a multiple of 128
     dividing ``B``, and ``block_t`` divides ``T`` and is a multiple of 8 or
     ``T`` itself (``ops.tree_predict_integer`` pads and aligns).  ``impl=
-    "leaf_major"`` needs ``internal_counts`` (T,).  Returns the (B/128, C,
-    128) int32 partial tiles.
+    "leaf_major"`` needs ``internal_counts`` (T,) and takes ``block_n``, a
+    multiple of 128 dividing ``N`` (default: ``N``, one node block); the
+    other walks hold whole trees.  Returns the (B/128, C, 128) int32
+    partial tiles.
     """
     r_tot, f, _ = x_tiles.shape
     t, _, n = fields.shape
@@ -215,13 +263,31 @@ def tree_traverse(x_tiles, fields, leaf_chunks, internal_counts=None, *,
     out_spec = pl.BlockSpec((tiles, c, LANES), lambda i, j, *_: (i, 0, 0))
     leaf_spec = spec((block_t,) + leaf_chunks.shape[1:])
     smem_fields = spec((block_t, 4, n), pltpu.SMEM)
+    semantics = ("parallel", "arbitrary")
     if impl == "leaf_major":
         if internal_counts is None:
             raise ValueError("impl='leaf_major' needs internal_counts")
-        kernel = _kernel_scan
+        block_n = n if block_n is None else block_n
+        assert block_n % LANES == 0 and n % block_n == 0
+        node_blocks = n // block_n
+        grid += (node_blocks,)
+        semantics += ("arbitrary",)
+        kernel = functools.partial(_kernel_scan, node_blocks=node_blocks)
+        # the row's current node per tree of the block, kept across the
+        # node blocks; one node block keeps it in registers instead
+        scratch = ([] if node_blocks == 1 else
+                   [pltpu.VMEM((block_t, tiles, 1, LANES), jnp.int32)])
         grid_spec = pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=grid,
-            in_specs=[x_spec, smem_fields, leaf_spec], out_specs=out_spec)
+            in_specs=[
+                x_spec,
+                pl.BlockSpec((block_t, 4, block_n),
+                             lambda i, j, k, *_: (j, 0, k),
+                             memory_space=pltpu.SMEM),
+                pl.BlockSpec((block_t, block_n // LANES, c, LANES),
+                             lambda i, j, k, *_: (j, k, 0, 0)),
+            ],
+            out_specs=out_spec, scratch_shapes=scratch)
         args = (internal_counts, x_tiles, fields, leaf_chunks)
     elif impl == "gather":
         kernel = functools.partial(_kernel_gather, depth=depth)
@@ -242,8 +308,7 @@ def tree_traverse(x_tiles, fields, leaf_chunks, internal_counts=None, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((r_tot, c, LANES), jnp.int32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=semantics),
         interpret=interpret,
         name=f"tree_traverse_{impl}",
     )(*args)

@@ -49,7 +49,7 @@ class Sink:
 
     ``totals``: stage -> summed ns.  ``runs`` (traced calls only, else
     None): ``[stage, t0_ns, t1_ns, attrs]`` in order, adjacent intervals of
-    one stage merged and their attributes summed."""
+    one stage merged and their numeric attributes summed."""
 
     __slots__ = ("totals", "runs")
 
@@ -66,7 +66,8 @@ class Sink:
         if merge and last is not None and last[0] == name:
             last[2] = t1
             for k, v in attrs.items():
-                last[3][k] = last[3].get(k, 0) + v
+                numeric = isinstance(v, (int, float))
+                last[3][k] = last[3].get(k, 0) + v if numeric else v
         else:
             self.runs.append([name, t0, t1, dict(attrs)])
 
@@ -92,6 +93,10 @@ class _Stage:
         self.sink.add(self.name, self.t0, time.perf_counter_ns(), self.attrs)
         return False
 
+    def note(self, **attrs) -> None:
+        """Add attributes known only inside the block."""
+        self.attrs.update(attrs)
+
 
 class _NullStage:
     __slots__ = ()
@@ -102,13 +107,18 @@ class _NullStage:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def note(self, **attrs) -> None:
+        pass
+
 
 _NULL_STAGE = _NullStage()
 
 
 def stage(name: str, **attrs):
     """Time the ``with`` block as stage ``name`` of the current shard call;
-    numeric ``attrs`` are summed over the stage's intervals in its span."""
+    numeric ``attrs`` are summed over the stage's intervals in its span, and
+    any other keeps its last value.  ``note(**attrs)`` on the entered stage
+    adds attributes from inside the block."""
     sink = getattr(_tls, "sink", None)
     return _NULL_STAGE if sink is None else _Stage(sink, name, attrs)
 

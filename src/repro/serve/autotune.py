@@ -68,10 +68,14 @@ def candidate_grid(backend_name: str, artifact, rows: int = _TUNE_ROWS) -> list:
 
         t, n = artifact.feature.shape
         c = artifact.leaf_fixed.shape[-1]
+        # the scan (auto on a scannable leaf_major artifact) cuts the node
+        # axis; its node block follows from the pinned (block_b, block_t)
+        scan = (getattr(artifact, "layout", "padded") == "leaf_major"
+                and getattr(artifact, "internal_counts", None) is not None)
         return [
             {"block_b": bb, "block_t": bt}
-            for bb, bt in pick_blocks_candidates(
-                rows, t, n, artifact.n_features, c
+            for bb, bt, _ in pick_blocks_candidates(
+                rows, t, n, artifact.n_features, c, chunk_nodes=scan
             )
         ]
     return []

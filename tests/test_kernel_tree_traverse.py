@@ -67,7 +67,8 @@ def test_kernel_block_shapes_property(bb, bt, rows):
     wrapper and stays bit-identical to ref."""
     packed, X = _forest(7, 4, 5, 3, seed=2)
     t, n = packed.feature.shape
-    auto_b, auto_t = pick_blocks(rows, t, n, 5, 3, bb)
+    auto_b, auto_t, auto_n = pick_blocks(rows, t, n, 5, 3, bb)
+    assert auto_n == -(-n // 128) * 128  # whole trees
     assert _aligned(auto_b, auto_t, t)
     assert _aligned(auto_b, _align_block_t(bt, t), t)
     keys = float_to_key(jnp.asarray(X[:rows]))
@@ -95,7 +96,8 @@ def _aligned(bb, bt, t):
 
 
 def test_vmem_budget_picker():
-    bb, bt = pick_blocks(b=4096, t=128, n=2047, f=87, c=8)
+    bb, bt, bn = pick_blocks(b=4096, t=128, n=2047, f=87, c=8)
+    assert bn == 2048
     # x tiles, node chunks (4 fields padded to 8 rows), leaf chunks, out
     # tiles at padded widths, two pipeline buffers each
     words = 2 * (bb * 88 + bt * 2048 * 8 + bt * 2048 * 8 + bb * 8)
@@ -110,22 +112,27 @@ def test_vmem_budget_picker_wide_leaf_tables():
     """Regression: with c large relative to n the output tiles and leaf
     chunks can bust the budget at the smallest tree block — the picker used
     to return it unchecked.  The row block must shrink until the working set
-    fits, and every choice stays tiling-aligned; the floor is the smallest
-    aligned tiling, (128, min(t, 8))."""
+    fits, and every choice stays tiling-aligned; where even the smallest
+    aligned tiling, (128, min(t, 8)), is over budget the picker raises
+    instead of returning it."""
     cases = [
         dict(b=4096, t=4, n=31, f=16, c=16384),   # output block dominates
         dict(b=4096, t=2, n=3, f=8, c=400000),    # degenerate: even bt=1 huge
         dict(b=4096, t=128, n=2047, f=87, c=8),   # the historical case
+        dict(b=4096, t=16, n=127, f=8, c=850),    # fits once the rows halve
     ]
     for kw in cases:
-        bb, bt = pick_blocks(**kw)
-        assert _aligned(bb, bt, kw["t"]), kw
         floor = (128, min(kw["t"], 8), kw["n"], kw["f"], kw["c"])
-        if _fits(*floor):
-            assert _fits(bb, bt, kw["n"], kw["f"], kw["c"]), kw
-        else:
-            assert (bb, bt) == floor[:2], kw
+        if not _fits(*floor):
             assert _block_words(*floor) * 4 > _VMEM_BUDGET_BYTES
+            for chunk_nodes in (False, True):  # one chunk: nothing to cut
+                with pytest.raises(ValueError, match="no tiling"):
+                    pick_blocks(**kw, chunk_nodes=chunk_nodes)
+            continue
+        bb, bt, bn = pick_blocks(**kw)
+        assert _aligned(bb, bt, kw["t"]), kw
+        assert _fits(bb, bt, bn, kw["f"], kw["c"]), kw
+        assert bn == -(-kw["n"] // 128) * 128
 
 
 @pytest.mark.parametrize(

@@ -496,6 +496,50 @@ def test_gateway_pallas_route_stages(small_forest, shuttle_small):
         assert sum(s.attrs["programs"] for s in kids if s.name == "launch") == 6
 
 
+def _launch_notes(forest, batches):
+    """The ``impl`` and ``node_blocks`` of each traced batch's launch span,
+    on the Pallas route."""
+    reg = ModelRegistry()
+    reg.register_forest("m", forest)
+    tracer = Tracer()
+    gw = Gateway(reg, "integer:pallas@leaf_major", max_delay_ms=1.0,
+                 cache_rows=0, tracer=tracer)
+
+    async def run():
+        for X in batches:
+            await gw.submit("m", X)
+        await gw.close()
+
+    asyncio.run(run())
+    # a compile inside the launch splits its span; the kernel's part notes
+    return [(s.attrs["impl"], s.attrs["node_blocks"]) for s in tracer.spans()
+            if s.name == "launch" and "impl" in s.attrs]
+
+
+def test_launch_span_names_the_walk(small_forest, shuttle_small):
+    """The kernel's launch notes which walk ran over how many node blocks:
+    whole trees are one block, and a small batch takes the gather walk."""
+    _, _, Xte, _ = shuttle_small
+    notes = _launch_notes(small_forest, [Xte[:4], Xte[:100]])
+    assert notes == [("gather", 1), ("leaf_major", 1)]
+
+
+def test_launch_span_counts_node_blocks_of_deep_trees(monkeypatch):
+    """Trees too large for a grid cell (the SMEM budget lowered so 1-2.5k
+    nodes are): every batch runs the scan over several node blocks."""
+    from repro.kernels import ops
+    from repro.trees.forest import RandomForestClassifier
+
+    rng = np.random.default_rng(0)
+    X = rng.normal(size=(6000, 6)).astype(np.float32)
+    forest = RandomForestClassifier(n_estimators=3, max_depth=16, seed=0).fit(
+        X, rng.integers(0, 3, 6000))
+    monkeypatch.setattr(ops, "_SMEM_BUDGET_BYTES", 96 * 1024)
+    notes = _launch_notes(forest, [X[:4], X[:100]])
+    assert [impl for impl, _ in notes] == ["leaf_major"] * 2
+    assert all(blocks > 1 for _, blocks in notes), notes
+
+
 def test_gc_spans_follow_an_enabled_tracer(small_forest):
     """A disabled tracer leaves ``gc.callbacks`` alone; an enabled one gets
     a hook for its gateway's life, whose spans are process roots that no

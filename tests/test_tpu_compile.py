@@ -4,8 +4,8 @@ The chip's compiler is installed with libtpu and lowers for a described
 topology, so these tests catch what interpret mode cannot: unaligned blocks,
 unsupported lowerings and on-chip memory limits.  They compile the three
 Pallas walks and the jnp reference walk at the widths of
-``configs/intreeger_rf.py`` (128 trees, depth 10, 87 features, 8 classes).
-Nothing runs; results are checked by the interpret-mode bit-identity tests.
+``configs/intreeger_rf.py`` (128 trees, depth 10, 87 features, 8 classes),
+and the node-chunked scan at the widths of an unpruned forest.  Nothing runs; results are checked by the interpret-mode bit-identity tests.
 
 The topology is described inside a fixture (never at import): only one
 process may load libtpu, and every test worker imports this file.
@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs.intreeger_rf import CONFIG
 from repro.core.ensemble import _predict
 from repro.kernels.ops import pick_blocks, tree_predict_integer
+from repro.kernels.tree_traverse import tree_traverse
 
 T, D, F, C = CONFIG.n_trees, CONFIG.tree_depth, CONFIG.n_tab_features, CONFIG.n_classes
 N = 2 ** (D + 1) - 1  # padded nodes per tree
@@ -92,11 +93,54 @@ def test_reference_walk_compiles_for_v5e(one_chip, no_compile_cache, rows):
     assert compiled.memory_analysis().argument_size_in_bytes > 0
 
 
+def _compile_raw_scan(sharding, block_t, block_n):
+    """The scan's pallas_call alone, at a tiling the caller pins (the
+    wrapper would cut the node axis to fit the budgets instead)."""
+    npad = -(-N // block_n) * block_n
+
+    def run(x, fields, leaf, counts):
+        return tree_traverse(x, fields, leaf, counts, depth=D, block_b=256,
+                             block_t=block_t, block_n=block_n,
+                             impl="leaf_major", interpret=False)
+
+    return jax.jit(run).lower(
+        _shape(sharding, (2, F, 128)), _shape(sharding, (T, 4, npad)),
+        _shape(sharding, (T, npad // 128, C, 128)), _shape(sharding, (T,)),
+    ).compile()
+
+
 def test_smem_budget_counts_both_pipeline_buffers(one_chip, no_compile_cache):
     """The picked tree block fits the chip's 1 MiB of SMEM; twice that block
     (the node fields' two pipeline buffers at 1 MiB) is refused, so the
     budget's double-buffer accounting matches the compiler's."""
-    _, block_t = pick_blocks(256, T, N, F, C)
-    _compile_kernel(one_chip, "leaf_major", 256, block_t=block_t)
+    _, block_t, block_n = pick_blocks(256, T, N, F, C, chunk_nodes=True)
+    assert block_n == 2048  # whole trees: one node block
+    _compile_raw_scan(one_chip, block_t, block_n)
     with pytest.raises(Exception, match="smem"):
-        _compile_kernel(one_chip, "leaf_major", 256, block_t=2 * block_t)
+        _compile_raw_scan(one_chip, 2 * block_t, block_n)
+
+
+# an unpruned forest: scikit-learn's default random forest on Covertype's
+# widths, its largest tree padded to 417 chunks of 128 nodes
+DEEP_T, DEEP_D, DEEP_F, DEEP_C, DEEP_N = 100, 48, 54, 7, 53_376
+
+
+@pytest.mark.parametrize("rows", [8, 256])
+def test_node_chunked_scan_compiles_for_v5e(one_chip, no_compile_cache, rows):
+    """Trees of 53,376 nodes need 13.7 MB of SMEM whole; the scan cuts the
+    node axis into blocks that fit, and the chip's compiler takes it."""
+    _, block_t, block_n = pick_blocks(rows, DEEP_T, DEEP_N, DEEP_F, DEEP_C,
+                                      chunk_nodes=True)
+    assert block_t == 8 and block_n < DEEP_N and DEEP_N // block_n > 1
+
+    def run(x, feature, key, left, right, leaf, counts):
+        return tree_predict_integer(
+            x, feature, key, left, right, leaf, depth=DEEP_D,
+            impl="leaf_major", interpret=False, internal_counts=counts)
+
+    tables = ([_shape(one_chip, (DEEP_T, DEEP_N)) for _ in range(4)]
+              + [_shape(one_chip, (DEEP_T, DEEP_N, DEEP_C), jnp.uint32)])
+    compiled = jax.jit(run).lower(
+        _shape(one_chip, (rows, DEEP_F)), *tables,
+        _shape(one_chip, (DEEP_T,))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
