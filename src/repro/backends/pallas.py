@@ -1,12 +1,17 @@
 """PallasBackend: the VMEM-tiled TPU kernel behind the TreeBackend protocol.
 
-Wraps ``repro.kernels.ops.packed_predict_integer`` and owns the blocking
+Wraps ``repro.kernels.ops.packed_partials`` and owns the blocking
 decisions: the row/tree block sizes fed to the kernel (budgeted and
 tiling-aligned via ``pick_blocks``) and the ``preferred_block_rows`` hint
 that makes the serving layer pad batches to shapes aligned with the
 kernel's ``block_b`` tiling.  ``interpret=None`` (the default) compiles the
 kernel on TPU and interprets it on CPU; a kernel that fails to compile
 raises, with no fallback.
+
+A batch is one device program: the rows are uploaded as float32 and the
+kernel's jitted call keys them (FlInt), pads them and runs the kernel; the
+partials come back and nothing else is launched (the ``launch`` stage's
+``programs`` reads 1).
 
 The node tables are device-resident: the first ``predict_partials`` places
 them on the default device (``kernels.ops.place_tables``, the ``place``
@@ -127,12 +132,12 @@ class PallasBackend(TreeBackend):
         return self._tables
 
     def predict_partials(self, X):
-        from repro.kernels.ops import packed_predict_integer
+        from repro.kernels.ops import packed_partials
 
         kw = self._kernel_kwargs
         if self._auto_small_batch and len(X) < _SMALL_BATCH_GATHER_ROWS:
             kw = dict(kw, impl="gather")
-        acc, _ = packed_predict_integer(self.packed, X,
-                                        tables=self._resident_tables(), **kw)
+        acc = packed_partials(self.packed, X, tables=self._resident_tables(),
+                              **kw)
         with stage("fetch"):  # the wait on the device and the copy back
             return np.asarray(acc)

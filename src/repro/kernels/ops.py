@@ -9,27 +9,33 @@ compiled on TPU, interpreted on CPU.
 
 Layout contract (ForestIR): the kernel consumes dense ``(T, N)`` node tables
 — the IR's ``padded`` or ``leaf_major`` materializations (the paper's codegen
-step re-targeted at tensors).  ``packed_predict_integer`` accepts a
+step re-targeted at tensors).  ``packed_partials`` accepts a
 ``ForestIR`` directly and materializes the layout its resolved impl walks
 (``leaf_major`` for the linear-scan kernel, ``padded`` otherwise); the
 ``ragged`` layout has no VMEM-tileable shape and belongs to the table-walk C
 backend instead.
 
+A batch is one device program: the kernel's jitted call (``_traverse``)
+takes the float32 rows and the tables, and keys the rows (``float_to_key``),
+pads them and the tables, lays them out and runs the kernel inside it.  No
+argmax is launched for the partials; ``packed_predict_integer`` takes its
+classes from the fetched partials on the host.
+
 Host steps are marked as the shard call's stages (``repro.obs.stages``):
-handing host arrays to the device is ``upload`` (``bytes``), and the key
-transform, the block choice, the kernel's jitted call and the argmax are
-``launch`` (``programs``: device programs started; the kernel's call also
-notes ``impl``, the walk, and ``node_blocks``, the scan's node blocks).
+handing host arrays to the device is ``upload`` (``bytes``), and the block
+choice and the jitted call are ``launch`` (``programs=1``, the one program
+a batch; it also notes ``impl``, the walk, and ``node_blocks``, the scan's
+node blocks).
 
 The entry points take host arrays and upload them on every call.  A
 serving backend instead places its node tables once with
 :func:`place_tables` (the ``place`` stage) and hands the device-resident
-copy to ``packed_predict_integer(..., tables=...)``; each batch then
+copy to ``packed_partials(..., tables=...)``; each batch then
 uploads only its rows.
 """
 from __future__ import annotations
 
-from functools import partial
+from functools import lru_cache, partial
 
 import jax
 import jax.numpy as jnp
@@ -48,9 +54,6 @@ _SMEM_BUDGET_BYTES = 512 * 1024
 # below this many rows a full-forest grid cell pays the whole per-cell scan
 # for a handful of rows; block_t is scaled down proportionally instead
 _TINY_BATCH_ROWS = 64
-
-# float_to_key runs eagerly, one program each: bitcast, less, subtract, where
-_KEY_PROGRAMS = 4
 
 
 def _round_up(v, m):
@@ -79,7 +82,7 @@ def _host_tables(packed):
 
 def place_tables(packed):
     """``packed``'s node tables put on the default device as the ``place``
-    stage, for ``packed_predict_integer``'s ``tables``: the conversion each
+    stage, for ``packed_partials``' ``tables``: the conversion each
     call's upload makes, so the kernel's jitted call sees the same shapes
     and dtypes either way."""
     return _upload(*_host_tables(packed), name="place")
@@ -165,7 +168,18 @@ def pick_blocks(b, t, n, f, c, block_b=256, *, chunk_nodes=False):
     Tiny batches (``b < 64``) additionally clamp ``block_t`` proportionally
     to the rows that amortize it (a heuristic from host timings; the clamp
     only ever shrinks, so the fit is preserved).
+
+    Memoized: a serving backend asks again at the same shapes every batch.
     """
+    return _pick_blocks(b, t, n, f, c, block_b, chunk_nodes,
+                        _VMEM_BUDGET_BYTES, _SMEM_BUDGET_BYTES)
+
+
+@lru_cache(maxsize=1024)
+def _pick_blocks(b, t, n, f, c, block_b, chunk_nodes, *budgets):
+    """:func:`pick_blocks`' search: pure in its arguments and the module's
+    budgets, which ride in the key so that a changed budget is searched
+    anew."""
     block_b = _align_block_b(block_b, b)
     npad = _round_up(n, LANES)
     sizes = [t] + list(range(_round_up(t, 8) - 8, 0, -8))
@@ -230,11 +244,16 @@ def _pinned_node_block(block_b, block_t, n, f, c, chunk_nodes):
 
 @partial(jax.jit, static_argnames=("depth", "block_b", "block_t", "block_n",
                                    "impl", "interpret"))
-def _traverse(x_keys, feature, key, left, right, leaf, nint, *,
+def _traverse(x, feature, key, left, right, leaf, nint, *,
               depth, block_b, block_t, block_n, impl, interpret):
-    """Pad and lay out the (T, N) tables for the kernel, run it, and return
-    (B, C) uint32 partials."""
-    b, f = x_keys.shape
+    """Key the rows, pad and lay out them and the (T, N) tables for the
+    kernel, run it, and return (B, C) uint32 partials: one device program.
+
+    ``x``: float32 rows, keyed here (``float_to_key``) before they are
+    padded, so padded rows carry key 0; or int32 keys, walked as given."""
+    if x.dtype == jnp.float32:
+        x = float_to_key(x)
+    b, f = x.shape
     t, n = feature.shape
     c = leaf.shape[-1]
     bp, tp, npad = _round_up(b, block_b), _round_up(t, block_t), _round_up(n, block_n)
@@ -255,15 +274,49 @@ def _traverse(x_keys, feature, key, left, right, leaf, nint, *,
     leaf = jax.lax.bitcast_convert_type(
         jnp.pad(leaf, ((0, tp - t), (0, npad - n), (0, 0))), jnp.int32)
     leaf = leaf.reshape(tp, npad // LANES, LANES, c).transpose(0, 1, 3, 2)
-    x = jnp.pad(x_keys, ((0, bp - b), (0, 0)))
+    x = jnp.pad(x, ((0, bp - b), (0, 0)))
     x = x.reshape(bp // LANES, LANES, f).transpose(0, 2, 1)
     if nint is not None:  # padding trees have no internal prefix to scan
-        nint = jnp.pad(nint, (0, tp - t))
+        nint = jnp.pad(nint.astype(jnp.int32), (0, tp - t))
     out = tree_traverse(x, fields, leaf, nint, depth=depth, block_b=block_b,
                         block_t=block_t, block_n=block_n, impl=impl,
                         interpret=interpret)
     out = out.transpose(0, 2, 1).reshape(bp, c)[:b]
     return jax.lax.bitcast_convert_type(out, jnp.uint32)
+
+
+def _launch(x, feature, threshold_key, left, right, leaf_fixed, nint, *,
+            depth, impl, block_b=256, block_t=None, interpret=None):
+    """The ``launch`` stage: choose the blocks and start ``_traverse`` on
+    device arrays, one program; -> its (B, C) uint32 partials."""
+    if impl == "leaf_major" and nint is None:
+        raise ValueError(
+            "impl='leaf_major' needs the layout's internal_counts; "
+            "materialize the forest as leaf_major (see repro.ir.layouts)"
+        )
+    with stage("launch", programs=1) as launch:
+        b, f = x.shape
+        t, n = feature.shape
+        c = leaf_fixed.shape[-1]
+        chunk_nodes = impl == "leaf_major"
+        block_b, auto_t, block_n = pick_blocks(b, t, n, f, c, block_b,
+                                               chunk_nodes=chunk_nodes)
+        if block_t is None:
+            block_t = auto_t
+        else:
+            block_t = _align_block_t(block_t, t)
+            block_n = _pinned_node_block(block_b, block_t, n, f, c,
+                                         chunk_nodes)
+            if block_n is None:
+                raise ValueError(
+                    f"block_t={block_t} at block_b={block_b} holds no "
+                    f"{impl} block of {n}-node trees inside the budgets")
+        launch.note(impl=impl, node_blocks=-(-_round_up(n, LANES) // block_n))
+        return _traverse(
+            x, feature, threshold_key, left, right, leaf_fixed, nint,
+            depth=depth, block_b=block_b, block_t=block_t, block_n=block_n,
+            impl=impl, interpret=resolve_interpret(interpret),
+        )
 
 
 def tree_predict_integer(
@@ -293,44 +346,17 @@ def tree_predict_integer(
     (``impl``) and its ``node_blocks``.  Returns (B, C) uint32 scores,
     bit-identical to ``ref.tree_predict_integer_ref``.
     """
-    if impl == "leaf_major" and internal_counts is None:
-        raise ValueError(
-            "impl='leaf_major' needs the layout's internal_counts; "
-            "materialize the forest as leaf_major (see repro.ir.layouts)"
-        )
     x_keys, feature, threshold_key, left, right, leaf_fixed, nint = _upload(
         x_keys, feature, threshold_key, left, right, leaf_fixed,
         internal_counts if impl == "leaf_major" else None)
-    with stage("launch", programs=1) as launch:
-        x_keys = jnp.asarray(x_keys, jnp.int32)
-        b, f = x_keys.shape
-        t, n = feature.shape
-        c = leaf_fixed.shape[-1]
-        chunk_nodes = impl == "leaf_major"
-        block_b, auto_t, block_n = pick_blocks(b, t, n, f, c, block_b,
-                                               chunk_nodes=chunk_nodes)
-        if block_t is None:
-            block_t = auto_t
-        else:
-            block_t = _align_block_t(block_t, t)
-            block_n = _pinned_node_block(block_b, block_t, n, f, c,
-                                         chunk_nodes)
-            if block_n is None:
-                raise ValueError(
-                    f"block_t={block_t} at block_b={block_b} holds no "
-                    f"{impl} block of {n}-node trees inside the budgets")
-        if nint is not None:
-            nint = jnp.asarray(nint, jnp.int32)
-        launch.note(impl=impl, node_blocks=-(-_round_up(n, LANES) // block_n))
-        return _traverse(
-            x_keys, feature, threshold_key, left, right, leaf_fixed, nint,
-            depth=depth, block_b=block_b, block_t=block_t, block_n=block_n,
-            impl=impl, interpret=resolve_interpret(interpret),
-        )
+    return _launch(jnp.asarray(x_keys, jnp.int32), feature, threshold_key,
+                   left, right, leaf_fixed, nint, depth=depth, impl=impl,
+                   block_b=block_b, block_t=block_t, interpret=interpret)
 
 
-def packed_predict_integer(packed, X, impl: str = "auto", tables=None, **kw):
-    """Node-table entry point: float features in, (scores, preds) out.
+def packed_partials(packed, X, impl: str = "auto", tables=None, **kw):
+    """Node-table entry point: float features in, (B, C) uint32 partials
+    out, as one device program (:func:`_traverse` keys the rows itself).
 
     ``packed``: a node-table artifact (``PackedEnsemble`` in ``padded`` or
     ``leaf_major`` layout) or a ``ForestIR``.  ``impl="auto"`` resolves per
@@ -338,7 +364,9 @@ def packed_predict_integer(packed, X, impl: str = "auto", tables=None, **kw):
     ``padded`` — and a ForestIR is materialized into whichever layout the
     resolved impl walks (``leaf_major`` for the scan, ``padded`` otherwise).
     Pinning ``impl="leaf_major"`` on a padded artifact re-materializes it as
-    leaf_major through the IR back-reference.
+    leaf_major through the IR back-reference.  ``kw``: ``block_b``,
+    ``block_t`` and ``interpret``, as :func:`tree_predict_integer` takes
+    them.
 
     ``tables``: :func:`place_tables` of this same artifact, walked in place
     of its host arrays, so that only ``X`` is uploaded; without it the
@@ -366,14 +394,18 @@ def packed_predict_integer(packed, X, impl: str = "auto", tables=None, **kw):
         from repro.ir import resolve_artifact
 
         packed = resolve_artifact(packed, "leaf_major")
-    if isinstance(X, np.ndarray):
-        X = np.asarray(X, np.float32)
-    x, = _upload(X)
-    with stage("launch", programs=_KEY_PROGRAMS):
-        keys = float_to_key(x)
+    # float32 on either side: the program keys float32 rows
+    X = (jnp.asarray(X, jnp.float32) if isinstance(X, jax.Array)
+         else np.asarray(X, np.float32))
     *nodes, nint = tables or _host_tables(packed)
-    *nodes, nint = _upload(*nodes, nint if impl == "leaf_major" else None)
-    acc = tree_predict_integer(keys, *nodes, depth=packed.max_depth, impl=impl,
-                               internal_counts=nint, **kw)
-    with stage("launch", programs=1):
-        return acc, jnp.argmax(acc, axis=1).astype(jnp.int32)
+    x, *nodes, nint = _upload(X, *nodes,
+                              nint if impl == "leaf_major" else None)
+    return _launch(x, *nodes, nint, depth=packed.max_depth, impl=impl, **kw)
+
+
+def packed_predict_integer(packed, X, impl: str = "auto", tables=None, **kw):
+    """:func:`packed_partials` and each row's class: (scores, preds).  The
+    class, the first largest score as ``argmax`` takes it, is read on the
+    host from the fetched partials, so the device runs the one program."""
+    acc = packed_partials(packed, X, impl, tables, **kw)
+    return acc, np.argmax(np.asarray(acc), axis=1).astype(np.int32)

@@ -67,3 +67,34 @@ def small_packed(small_forest):
     from repro.core.packing import pack_forest
 
     return pack_forest(small_forest)
+
+
+@pytest.fixture(scope="session")
+def deep():
+    """3 trees of depth 16 with 2,545-3,255 nodes over Covertype-like rows,
+    leaves near one-hot, and 300 rows to score: (the drawn forest, its
+    ForestIR, the rows).  Under ``small_smem`` they span several node blocks
+    of the scan; at the real budgets whole trees fit a grid cell."""
+    from bench.catalog import Catalog
+    from bench.forest import draw_forest
+    from repro.ir import ForestIR
+
+    catalog = Catalog()
+    cfg = dict(catalog.config("hb-rf-covtype"), n_trees=3, max_depth=16,
+               shape={"full_depth": 5, "split_prob": 0.65},
+               leaves={"dirichlet_alpha": 0.1})
+    rows = catalog.rows(cfg["rows"]["generator"]).Rows(cfg, 2**31 + 3)
+    forest = draw_forest(cfg, rows, 2**31 + 3)
+    assert 2000 < forest.node_counts.min() and forest.node_counts.max() < 4000
+    assert forest.max_depth == 16
+    ir = ForestIR.from_forest(forest)
+    return forest, ir, rows.take(3, 0, 300)
+
+
+@pytest.fixture
+def small_smem(monkeypatch):
+    """The kernel's SMEM budget lowered to 96 KiB: at 3 trees a block,
+    1,024 nodes a block."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_SMEM_BUDGET_BYTES", 96 * 1024)

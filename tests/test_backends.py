@@ -364,13 +364,13 @@ def test_pallas_places_its_tables_once(random_case, monkeypatch):
     packed, rows = random_case
     ref = create_backend("reference", packed, mode="integer")
     eng = TreeEngine(packed.to_ir(), "integer:pallas@leaf_major")
-    walked, real = [], ops.packed_predict_integer
+    walked, real = [], ops.packed_partials
 
     def spy(packed, X, **kw):
         walked.append((kw["impl"], kw["tables"]))
         return real(packed, X, **kw)
 
-    monkeypatch.setattr(ops, "packed_predict_integer", spy)
+    monkeypatch.setattr(ops, "packed_partials", spy)
     for n in (3, 97, 17, 40):  # buckets 4, 128, 32, 64: gather, scan, gather, scan
         np.testing.assert_array_equal(eng.predict_partials(rows[:n]),
                                       np.asarray(ref.predict_partials(rows[:n])))

@@ -1,15 +1,20 @@
 """Pallas tree-traversal kernel vs the pure-jnp oracle: shape/dtype sweeps,
-both gather strategies, padding paths — bit-identical uint32 scores — and
-the tiling-aligned block picker (rows in 128-lane multiples, trees in
-multiples of 8 or the whole forest)."""
+both gather strategies, padding paths — bit-identical uint32 scores — the
+tiling-aligned block picker (rows in 128-lane multiples, trees in
+multiples of 8 or the whole forest), and the float entry point that keys
+the rows inside the kernel's one program."""
+import asyncio
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 from hypothesis import given, settings
 from hypothesis import strategies as st
 
-from repro.core.flint import float_to_key
+from repro.core.flint import float_to_key, float_to_key_np
 from repro.core.packing import pack_forest
+from repro.kernels import ops
 from repro.kernels.ops import (
     _VMEM_BUDGET_BYTES, _align_block_t, _block_words, _fits, _smem_words,
     packed_predict_integer, pick_blocks, tree_predict_integer,
@@ -185,3 +190,106 @@ def test_packed_entry_point_auto_impl(small_packed, shuttle_small):
         acc, pred = packed_predict_integer(packed, Xte[:150], block_b=32, **kw)
         np.testing.assert_array_equal(np.asarray(acc), np.asarray(acc_ref))
         np.testing.assert_array_equal(np.asarray(pred), np.asarray(pred_ref))
+
+
+# FlInt's edge cases: both zeros (one key), infinities, subnormals of both
+# signs, the largest subnormal and finite values, negatives
+_SPECIAL = np.array([-0.0, 0.0, np.inf, -np.inf, 1e-45, -1e-45,
+                     1.1754942e-38, -1.1754942e-38, 3.4e38, -2.5],
+                    np.float32)
+
+
+def _special_rows(X, rows=203):
+    """``rows`` of ``X`` (not a multiple of 128) with every other row
+    negated and the first 40 rows made of ``_SPECIAL`` values."""
+    X = np.array(X[:rows], np.float32)
+    X[1::2] *= -1
+    i, j = np.indices((40, X.shape[1]))
+    X[:40] = _SPECIAL[(i + j) % len(_SPECIAL)]
+    return X
+
+
+# walk -> (impl of the direct call, request sizes sent through the Gateway,
+# the walk its batches take there)
+_FUSED_WALKS = {
+    "scan": ("leaf_major", [203], {"leaf_major"}),
+    "gather": ("gather", [29] * 7, {"gather"}),
+    "chunked_scan": ("leaf_major", [5, 198], {"leaf_major"}),
+}
+
+
+@pytest.mark.parametrize("walk", list(_FUSED_WALKS))
+def test_fused_key_transform_is_bit_identical(deep, walk, request, tmp_path):
+    """The float rows keyed inside the kernel's program: partials equal to
+    the jnp oracle on ``float_to_key_np`` keys, and the Gateway's answers to
+    the plain reference, on -0.0/+0.0, infinities, subnormals and negatives,
+    for the scan, the gather walk and the node-chunked scan."""
+    from bench import reference
+    from repro.obs import Tracer
+    from repro.serve.gateway import Gateway
+    from repro.serve.registry import ModelRegistry
+
+    impl, sizes, walks = _FUSED_WALKS[walk]
+    if walk == "chunked_scan":
+        request.getfixturevalue("small_smem")
+    forest, ir, X = deep
+    X = _special_rows(X)
+    lm, padded = ir.materialize("leaf_major"), ir.materialize("padded")
+    t, n = lm.feature.shape
+    f, c = lm.n_features, lm.leaf_fixed.shape[-1]
+    block_n = pick_blocks(len(X), t, n, f, c, chunk_nodes=True)[2]
+    assert (block_n < n) == (walk == "chunked_scan")
+
+    got = ops.packed_partials(lm, X, impl=impl, tables=ops.place_tables(lm))
+    want = tree_predict_integer_ref(
+        jnp.asarray(float_to_key_np(X)), *_args(padded), padded.max_depth)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+    path = tmp_path / "deep.itrf"
+    ir.to_itrf(str(path))
+    reg = ModelRegistry()
+    reg.register_artifact("m", str(path))
+    tracer = Tracer()
+    gw = Gateway(reg, "integer:pallas@leaf_major", max_delay_ms=1.0,
+                 cache_rows=0, tracer=tracer)
+    starts = np.cumsum([0] + sizes)
+
+    async def run():
+        try:
+            return [await gw.submit("m", X[a:b])
+                    for a, b in zip(starts, starts[1:])]
+        finally:
+            await gw.close()
+
+    answers = asyncio.run(run())
+    scores = np.concatenate([np.asarray(s) for s, _ in answers])
+    preds = np.concatenate([np.asarray(p) for _, p in answers])
+    np.testing.assert_array_equal(scores.astype(np.uint64),
+                                  reference.partials(forest, X))
+    np.testing.assert_array_equal(preds, reference.scores(forest, X)[1])
+    assert {s.attrs["impl"] for s in tracer.spans()
+            if s.name == "launch" and "impl" in s.attrs} == walks
+
+
+def test_fused_path_traces_the_key_transform_once(small_packed, shuttle_small,
+                                                  monkeypatch):
+    """Two calls at one shape: ``float_to_key`` is traced into the kernel's
+    program once, and no call runs it eagerly."""
+    _, _, Xte, _ = shuttle_small
+    lm = small_packed.to_ir().materialize("leaf_major")
+    seen, real = [], ops.float_to_key
+
+    def counting(x):
+        seen.append(isinstance(x, jax.core.Tracer))
+        return real(x)
+
+    monkeypatch.setattr(ops, "float_to_key", counting)
+    ops._traverse.clear_cache()  # a program of this shape is traced here
+    tables = ops.place_tables(lm)
+    want = tree_predict_integer_ref(
+        float_to_key(jnp.asarray(Xte[:37])), *_args(small_packed),
+        small_packed.max_depth)
+    for _ in range(2):
+        got = ops.packed_partials(lm, Xte[:37], tables=tables)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert seen == [True]

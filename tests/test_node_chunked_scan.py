@@ -1,10 +1,10 @@
 """The leaf_major scan with its node axis cut over the grid: trees too large
 for one grid cell, served bit-identically to both references.
 
-The budgets are lowered so that a seeded forest of depth-16 trees with
-2-4k nodes spans several node blocks; at the real budgets the same cut
-serves unpruned forests of ~50k-node trees (``test_tpu_compile.py``
-compiles that for the chip).
+The budgets are lowered (the ``small_smem`` fixture) so that a seeded
+forest of depth-16 trees with 2-4k nodes (``deep``) spans several node
+blocks; at the real budgets the same cut serves unpruned forests of
+~50k-node trees (``test_tpu_compile.py`` compiles that for the chip).
 """
 import asyncio
 
@@ -23,40 +23,16 @@ from repro.kernels.ref import tree_predict_integer_ref
 from repro.serve.gateway import Gateway
 from repro.serve.registry import ModelRegistry
 
-SMALL_SMEM = 96 * 1024  # at 3 trees a block: 1,024 nodes a block
-
-
-@pytest.fixture(scope="module")
-def deep():
-    """3 trees of depth 16 with 2,545-3,255 nodes over Covertype-like rows,
-    leaves near one-hot, and 300 rows to score."""
-    catalog = Catalog()
-    cfg = dict(catalog.config("hb-rf-covtype"), n_trees=3, max_depth=16,
-               shape={"full_depth": 5, "split_prob": 0.65},
-               leaves={"dirichlet_alpha": 0.1})
-    rows = catalog.rows(cfg["rows"]["generator"]).Rows(cfg, 2**31 + 3)
-    forest = draw_forest(cfg, rows, 2**31 + 3)
-    assert 2000 < forest.node_counts.min() and forest.node_counts.max() < 4000
-    assert forest.max_depth == 16
-    ir = ForestIR.from_forest(forest)
-    return forest, ir, rows.take(3, 0, 300)
-
-
-@pytest.fixture
-def small_smem(monkeypatch):
-    monkeypatch.setattr(ops, "_SMEM_BUDGET_BYTES", SMALL_SMEM)
-
-
 @pytest.fixture
 def walks(monkeypatch):
     """The ``impl`` of every kernel call the Pallas backend makes."""
-    seen, sound = [], ops.packed_predict_integer
+    seen, sound = [], ops.packed_partials
 
     def spy(packed, X, impl="auto", **kw):
         seen.append(impl)
         return sound(packed, X, impl=impl, **kw)
 
-    monkeypatch.setattr(ops, "packed_predict_integer", spy)
+    monkeypatch.setattr(ops, "packed_partials", spy)
     return seen
 
 

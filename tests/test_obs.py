@@ -489,11 +489,11 @@ def test_gateway_pallas_route_stages(small_forest, shuttle_small):
     for shard in shards:
         kids = [s for s in spans if s.parent_id == shard.span_id]
         assert {s.name for s in kids} == {"upload", "launch", "fetch"}
-        # the rows alone, a 4-row bucket of float32; the key transform's four
-        # programs, the kernel and the argmax
+        # the rows alone, a 4-row bucket of float32; one program, which keys
+        # the rows, pads them and runs the kernel
         ups = [s.attrs["bytes"] for s in kids if s.name == "upload"]
         assert ups == [4 * Xte.shape[1] * 4]
-        assert sum(s.attrs["programs"] for s in kids if s.name == "launch") == 6
+        assert sum(s.attrs["programs"] for s in kids if s.name == "launch") == 1
 
 
 def _launch_notes(forest, batches):

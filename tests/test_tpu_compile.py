@@ -19,6 +19,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.intreeger_rf import CONFIG
 from repro.core.ensemble import _predict
+from repro.kernels import ops
 from repro.kernels.ops import pick_blocks, tree_predict_integer
 from repro.kernels.tree_traverse import tree_traverse
 
@@ -81,6 +82,22 @@ def _compile_kernel(sharding, impl, rows, block_t=None):
 @pytest.mark.parametrize("impl", ["leaf_major", "gather", "onehot"])
 def test_pallas_walk_compiles_for_v5e(one_chip, no_compile_cache, impl, rows):
     compiled = _compile_kernel(one_chip, impl, rows)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("impl,rows", [("leaf_major", 256), ("gather", 8)])
+def test_fused_key_transform_compiles_for_v5e(one_chip, no_compile_cache,
+                                               impl, rows):
+    """The serving path's one program: float32 rows keyed, padded and walked
+    by the kernel inside one jitted call."""
+    def run(x, feature, key, left, right, leaf, counts):
+        return ops._launch(x, feature, key, left, right, leaf,
+                           counts if impl == "leaf_major" else None,
+                           depth=D, impl=impl, interpret=False)
+
+    compiled = jax.jit(run).lower(
+        _shape(one_chip, (rows, F), jnp.float32), *_tables(one_chip),
+        _shape(one_chip, (T,))).compile()
     assert "tpu_custom_call" in compiled.as_text()
 
 
