@@ -67,9 +67,9 @@ worker-fleet:
 		$(PY) -m pytest -q tests/test_remote.py tests/test_spec.py
 
 # ITRF artifact gate: the pytest artifact suite (round-trip bit-identity,
-# mmap safety, registry retention, tune-db persistence), then the converter
-# selftest, which trains a forest, converts it, and reloads the .itrf in a
-# FRESH process via mmap asserting bit-identical reference partials.  Leaves
+# mmap safety, registry retention), then the converter selftest, which
+# trains a forest, converts it, and reloads the .itrf in a FRESH process
+# via mmap asserting bit-identical reference partials.  Leaves
 # benchmarks/artifacts/model.itrf for the CI artifact upload.
 artifact-check:
 	mkdir -p benchmarks/artifacts

@@ -315,15 +315,15 @@ def energy_model():
 def kernel_identity():
     """Pallas kernel (interpret mode) == jnp oracle; per-row cost of the jnp
     deployment path (interpret-mode kernel timing is not meaningful)."""
+    from repro.backends.pallas import PallasBackend
     from repro.core.ensemble import make_predict_fn
-    from repro.kernels.ops import packed_predict_integer
 
     data = _datasets()["shuttle"]
     rf, packed, Xte, _ = _forest(data, 16, depth=6)
     Xte = Xte[:1024]
     fn = make_predict_fn(packed, "integer")
     scores_ref, _ = fn(Xte)
-    scores_k, _ = packed_predict_integer(packed, Xte, block_b=256)
+    scores_k = PallasBackend(packed, "integer").predict_partials(Xte)
     same = bool((np.asarray(scores_ref) == np.asarray(scores_k)).all())
     us = _time(fn, Xte, reps=3)
     emit("kernel_identity", us / len(Xte), f"bit_identical={same}")
@@ -531,40 +531,6 @@ def backend_matrix():
             f"{tag} small-batch cliff: b{batches[0]}={small:.0f}ns/row vs "
             f"b{batches[1]}={nxt:.0f}ns/row (> 3x)")
 
-    # autotuned rows next to the static defaults: the warm-time measured
-    # winner must never lose to the default it was picked against
-    # (min-of-rounds interleaved timing; 10% allowance for shared-host
-    # noise, 15% for interpret-mode pallas).
-    tuned_routes = [("pallas[leaf_major]", "pallas", 1.15,
-                     {"layout": "leaf_major",
-                      "backend_kwargs": {"impl": "leaf_major"}})]
-    if have_gcc:
-        tuned_routes.insert(0, ("native_c_table", "native_c_table", 1.10, {}))
-    for tag, name, tol, kwargs in tuned_routes:
-        tuned = TreeEngine(packed, mode="integer", backend=name,
-                           autotune=True, **kwargs)
-        tuned.warm(batches[-1])
-        static = TreeEngine(packed, mode="integer", backend=name, **kwargs)
-        scores, _ = tuned.predict_scores(probe)
-        assert (scores == ref_scores).all(), f"tuned {tag} diverged"
-        for batch in batches:
-            X = Xte[:batch]
-            t_tuned = t_static = float("inf")
-            for _ in range(3):
-                t_tuned = min(t_tuned, _time(tuned.predict_scores, X, reps=3))
-                t_static = min(t_static,
-                               _time(static.predict_scores, X, reps=3))
-            emit(
-                f"backend_tuned_{tag}_b{batch}", t_tuned,
-                f"ns_per_row={t_tuned * 1e3 / batch:.1f};"
-                f"tuned={tuned.tuned_config or '-'};"
-                f"static_ns_per_row={t_static * 1e3 / batch:.1f};"
-                f"isa={_isa_of(tuned)}",
-            )
-            assert t_tuned <= t_static * tol, (
-                f"tuned {tag} b{batch} slower than static default: "
-                f"{t_tuned:.1f}us vs {t_static:.1f}us")
-
     if have_gcc:
         # blocked-vs-scalar where row blocking actually bites: a deep forest
         # whose walks defeat branch prediction and exceed the fast caches.
@@ -676,22 +642,6 @@ def backend_bitvector():
         else:
             assert (scores == ref_scores).all(), f"{tag} diverged"
         engines[tag] = eng
-    if have_c_toolchain():
-        # autotuned twins of the two tunable C routes: warm() measures the
-        # candidate grid (block_rows for the table walk, the v-QuickScorer
-        # interleave width K for the bitvector scorer) and pins the winner,
-        # so the tuned_* rows make the autotune win a diffable number in
-        # BENCH_8.json next to the static-default rows
-        for tag, name in (("tuned_native_c_table", "native_c_table"),
-                          ("tuned_native_c_bitvector", "native_c_bitvector")):
-            t0 = time.perf_counter()
-            eng = TreeEngine(packed, mode="integer", backend=name,
-                             autotune=True)
-            eng.warm(batch)
-            scores, _ = eng.predict_scores(X[:64])
-            builds[tag] = time.perf_counter() - t0
-            assert (scores == ref_scores).all(), f"{tag} diverged"
-            engines[tag] = eng
     # interleaved min-of-rounds timing: on a noisy shared host a transient
     # slowdown (CPU steal, frequency dip) lasting one measurement would land
     # entirely on whichever engine happened to be under the timer, flipping
@@ -703,28 +653,13 @@ def backend_bitvector():
         for tag, eng in engines.items():
             times[tag] = min(times[tag], _time(eng.predict_scores, X, reps=3))
     for tag, us in times.items():
-        extra = ""
-        if tag.startswith("tuned_"):
-            extra = f";tuned={engines[tag].tuned_config or '-'}"
         emit(
             f"bitvector_{tag}_t{n_trees}d{depth}_b{batch}", us,
             f"ns_per_row={us * 1e3 / batch:.1f};isa={_isa_of(engines[tag])};"
-            f"build_s={builds[tag]:.1f}" + extra,
+            f"build_s={builds[tag]:.1f}",
         )
-    # the measured winner must never lose to the static default it was
-    # picked against (same min-of-rounds interleaved timing; 10% noise
-    # allowance on shared hosts)
-    for tag in [t for t in times if t.startswith("tuned_")]:
-        base = tag[len("tuned_"):]
-        assert times[tag] <= times[base] * 1.10, (
-            f"{tag} slower than static {base}: "
-            f"{times[tag]:.1f}us vs {times[base]:.1f}us")
-    # the crossover verdict stays a static-defaults comparison (the row
-    # BENCH_7/BENCH_8 are diffed on); tuned_* rows ride alongside
-    static_times = {t: u for t, u in times.items()
-                    if not t.startswith("tuned_")}
-    bv_routes = {t for t in static_times if "bitvector" in t}
-    others = {t: u for t, u in static_times.items() if t not in bv_routes}
+    bv_routes = {t for t in times if "bitvector" in t}
+    others = {t: u for t, u in times.items() if t not in bv_routes}
     if others:
         best_bv = min(bv_routes, key=times.get)
         best_other = min(others, key=others.get)

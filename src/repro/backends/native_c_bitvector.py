@@ -10,8 +10,7 @@ where the QuickScorer line of work wins on large-T shallow forests.
 
 ``interleave=K`` is the v-QuickScorer multi-tree blocking knob (default 8):
 the emitter pads each feature's ascending stream to K-entry groups and every
-block variant runs one early-exit test + K unrolled mask applies per group —
-the warm-time autotuner sweeps this grid and pins the measured winner.
+block variant runs one early-exit test + K unrolled mask applies per group.
 ``simd=False`` pins the scalar blocked path per instance (same macro as the
 degradation CI job, scoped to this build) so one process can measure
 dispatch variants against each other on identical artifacts.

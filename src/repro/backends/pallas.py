@@ -1,12 +1,13 @@
 """PallasBackend: the VMEM-tiled TPU kernel behind the TreeBackend protocol.
 
-Wraps ``repro.kernels.ops.packed_partials`` and owns the blocking
-decisions: the row/tree block sizes fed to the kernel (budgeted and
-tiling-aligned via ``pick_blocks``) and the ``preferred_block_rows`` hint
-that makes the serving layer pad batches to shapes aligned with the
-kernel's ``block_b`` tiling.  ``interpret=None`` (the default) compiles the
-kernel on TPU and interprets it on CPU; a kernel that fails to compile
-raises, with no fallback.
+Wraps ``repro.kernels.ops.packed_partials`` and is the one place that
+decides how a batch runs on the chip: which walk (:meth:`PallasBackend.walk`)
+and the tiling caps fed to the kernel (``block_b``, ``block_t``; the kernel
+wrapper aligns them to the budgets via ``pick_blocks``), plus the
+``preferred_block_rows`` hint that makes the serving layer pad batches to
+shapes aligned with the kernel's ``block_b`` tiling.  ``interpret=None``
+(the default) compiles the kernel on TPU and interprets it on CPU; a kernel
+that fails to compile raises, with no fallback.
 
 A batch is one device program: the rows are uploaded as float32 and the
 kernel's jitted call keys them (FlInt), pads them and runs the kernel; the
@@ -17,18 +18,18 @@ The node tables are device-resident: the first ``predict_partials`` places
 them on the default device (``kernels.ops.place_tables``, the ``place``
 stage) and every later batch walks that copy, uploading only its rows.
 The copy lives as long as the backend, i.e. its ``ModelVersion``'s
-engine, so a hot-swapped version places its own.  The kernel wrappers'
-host-array entry points still upload the tables with every call.
+engine, so a hot-swapped version places its own.
 
 Layout-specialized: the backend prefers the ``leaf_major`` layout, where the
 linear-scan kernel (``impl="leaf_major"``) walks each tree's internal-node
 prefix front-to-back with compare+select steps — no per-depth node-table
-gathers.  ``impl="auto"`` (the default) resolves per layout: linear scan on
-``leaf_major`` tables, the per-level ``gather`` walk on ``padded`` ones —
-i.e. pinning ``layout="padded"`` falls back to padded+gather untouched.
-Only the scan cuts the node axis, so it alone serves trees too large for
-one grid cell (unpruned forests); a gather or onehot walk that cannot hold
-a whole tree is refused at construction.
+gathers.  ``impl="auto"`` (the default) scans a scannable ``leaf_major``
+layout and runs the per-level ``gather`` walk on ``padded`` tables or where
+the node order cannot be scanned; small batches take the gather walk where
+whole trees fit (:meth:`PallasBackend.walk`).  Only the scan cuts the node
+axis, so it alone serves trees too large for one grid cell (unpruned
+forests); a gather or onehot walk that cannot hold a whole tree is refused
+at construction.
 
 The kernel implements exactly the paper's integer accumulation (int32 FlInt
 compares, uint32 fixed-point adds) — which, since the partials/finalize
@@ -53,13 +54,13 @@ from repro.obs import stage
 
 _DEFAULT_BLOCK_B = 256  # the kernel wrapper's row-tile default
 
-# when impl="auto" resolved to the linear scan, batches below this row count
+# when impl="auto" resolves to the linear scan, batches below this row count
 # run the gather walk instead: the scan's per-cell prefix pass costs the same
-# for 2 rows as for 256, so at tiny batches the cheaper per-call gather wins
-# (measured on the BENCH_7 b32 pathology).  Both impls produce identical
-# uint32 partials, so the switch is invisible to conformance.  Only forests
-# whose whole trees fit a grid cell take it: the gather walk cannot cut the
-# node axis as the scan does.
+# for 2 rows as for 256.  A host-clock cut from a CPU snapshot, not yet
+# measured on the chip.  Both impls produce identical uint32 partials, so the
+# switch is invisible to conformance.  Only forests whose whole trees fit a
+# grid cell take it: the gather walk cannot cut the node axis as the scan
+# does.
 _SMALL_BATCH_GATHER_ROWS = 64
 
 
@@ -85,41 +86,56 @@ class PallasBackend(TreeBackend):
         from repro.kernels.ops import holds_whole_trees
 
         super().__init__(packed, mode)
-        scannable = getattr(packed, "internal_counts", None) is not None
-        was_auto = impl == "auto"
-        if impl == "auto":
-            # the linear scan needs the layout's internal prefix AND its
-            # children-after-parents ordering (internal_counts is None when
-            # an imported forest violates it) — otherwise gather-walk the
-            # tables, which any node order satisfies
-            impl = "leaf_major" if self.layout == "leaf_major" and scannable \
-                else "gather"
-        if impl == "leaf_major" and not (self.layout == "leaf_major" and scannable):
-            raise ValueError(
-                "impl='leaf_major' scans the leaf_major internal-node prefix; "
-                f"this backend was materialized on the {self.layout!r} layout"
-                + ("" if scannable else " without a scannable node order")
-            )
         t, n = self.packed.feature.shape
         f, c = self.packed.n_features, self.packed.leaf_fixed.shape[-1]
-        if impl in ("gather", "onehot") and not holds_whole_trees(t, n, f, c):
+        self._shape = (t, n, f, c)
+        self._asked = impl
+        # the leaf_major internal prefix AND its children-after-parents order
+        # (internal_counts is None when an imported forest violates it)
+        self._scannable = getattr(packed, "internal_counts", None) is not None
+        # whole trees in one grid cell at the tiling that would run: the
+        # pinned block_t, or the smallest aligned one
+        self._whole_trees = holds_whole_trees(t, n, f, c, block_t)
+        self.impl = self.walk()  # raises for a walk these tables refuse
+        self._tiling = dict(block_b=block_b, block_t=block_t,
+                            interpret=interpret)
+        self._tables = None  # the device-resident node tables, once placed
+        self._place_lock = threading.Lock()  # shard threads share a backend
+
+    def walk(self, rows: Optional[int] = None) -> str:
+        """The walk a batch of ``rows`` rows takes (``rows=None``: any batch;
+        this is ``self.impl``).
+
+        ``impl="auto"`` scans a scannable ``leaf_major`` layout and gathers
+        on ``padded`` tables or where the node order cannot be scanned;
+        below ``_SMALL_BATCH_GATHER_ROWS`` rows it gathers instead of
+        scanning where whole trees fit.  A pinned walk runs every batch.
+        Refused with ``ValueError``: the scan of tables that cannot be
+        scanned, and a whole-tree walk (gather, onehot) of trees that do not
+        fit a grid cell.
+        """
+        scan = self.layout == "leaf_major" and self._scannable
+        impl = self._asked
+        if impl == "auto":
+            small = rows is not None and rows < _SMALL_BATCH_GATHER_ROWS
+            impl = ("leaf_major" if scan and not (small and self._whole_trees)
+                    else "gather")
+        if impl == "leaf_major":
+            if not scan:
+                raise ValueError(
+                    "impl='leaf_major' scans the leaf_major internal-node "
+                    f"prefix; this backend was materialized on the "
+                    f"{self.layout!r} layout"
+                    + ("" if self._scannable
+                       else " without a scannable node order"))
+        elif not self._whole_trees:
+            t, n, f, c = self._shape
             raise ValueError(
                 f"impl={impl!r} holds whole trees in a grid cell, and {t} "
                 f"trees of {n} nodes ({f} features, {c} classes) do not fit "
                 "one; the leaf_major scan (impl='leaf_major', or 'auto' on "
                 "the leaf_major layout) cuts the node axis into blocks")
-        self.impl = impl
-        # only an *auto* resolution may fall back per batch — an explicitly
-        # pinned impl is a routing decision the caller owns — and only where
-        # gather holds whole trees at the tiling it would run: the pinned
-        # block_t, or its own floor
-        self._auto_small_batch = (impl == "leaf_major" and was_auto
-                                  and holds_whole_trees(t, n, f, c, block_t))
-        self._kernel_kwargs = dict(
-            block_b=block_b, block_t=block_t, impl=impl, interpret=interpret
-        )
-        self._tables = None  # the device-resident node tables, once placed
-        self._place_lock = threading.Lock()  # shard threads share a backend
+        return impl
 
     def _resident_tables(self):
         """The node tables on the device, placed by the first call."""
@@ -134,10 +150,7 @@ class PallasBackend(TreeBackend):
     def predict_partials(self, X):
         from repro.kernels.ops import packed_partials
 
-        kw = self._kernel_kwargs
-        if self._auto_small_batch and len(X) < _SMALL_BATCH_GATHER_ROWS:
-            kw = dict(kw, impl="gather")
-        acc = packed_partials(self.packed, X, tables=self._resident_tables(),
-                              **kw)
+        acc = packed_partials(self.packed, X, impl=self.walk(len(X)),
+                              tables=self._resident_tables(), **self._tiling)
         with stage("fetch"):  # the wait on the device and the copy back
             return np.asarray(acc)

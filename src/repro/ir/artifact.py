@@ -22,24 +22,16 @@ canonical arrays stay shared.
 
 Versioning mirrors ``trees/io``: a newer *major* version is refused loudly
 (never half-parsed), unknown section names are skipped (minor versions may
-add sections), and required sections missing raise.  Two optional section
-families ride along:
-
-  * ``leaf_pack_*`` — the group-quantized leaf payload (``--pack-leaves``):
-    exact codec from :mod:`repro.ir.packed_leaf`; decoded on load (the one
-    deliberate copy of that path).
-  * ``tune_db`` — a JSON map ``{host_isa_key: {"backend|layout|mode":
-    kwargs}}`` of measured autotune winners.  ``register_artifact`` seeds
-    ``ModelVersion._tuned`` from the entry matching :func:`host_isa_key`,
-    so a warm-tuned config survives process restart; foreign-host entries
-    are carried but ignored (that host re-tunes).
+add sections), and required sections missing raise.  One optional section
+family rides along: ``leaf_pack_*``, the group-quantized leaf payload
+(``--pack-leaves``), the exact codec from :mod:`repro.ir.packed_leaf`,
+decoded on load (the one deliberate copy of that path).
 """
 from __future__ import annotations
 
 import json
 import mmap
 import os
-import platform
 import struct
 import tempfile
 
@@ -48,7 +40,6 @@ import numpy as np
 __all__ = [
     "ITRF_MAGIC", "ITRF_VERSION",
     "write_itrf", "read_itrf", "read_itrf_bytes", "inspect_itrf",
-    "update_tuned", "serialize_tuned", "deserialize_tuned", "host_isa_key",
 ]
 
 ITRF_MAGIC = b"ITRF"
@@ -56,7 +47,9 @@ ITRF_VERSION = (1, 0)  # (major, minor): major bumps break readers
 
 FLAG_FLOAT = 1  # threshold/leaf_probs sections present
 FLAG_PACKED_LEAVES = 2  # leaf_pack_* sections replace leaf_fixed
-FLAG_TUNED = 4  # a tune_db section is present
+# flag 4 is retired: it marked a "tune_db" section of host-clock tuning
+# winners.  Files that carry it still load (the reader ignores the bit and
+# skips the section like any unknown one); never reuse the bit.
 
 _ALIGN = 64
 _HEADER = struct.Struct("<4sHHIIIIQQI")  # 44 bytes, padded to _ALIGN
@@ -69,47 +62,6 @@ _NODE_SECTIONS = ("feature", "threshold_key", "left", "right",
 
 def _align(n: int) -> int:
     return -(-n // _ALIGN) * _ALIGN
-
-
-# ---------------------------------------------------------------------------
-# host identity (the tune_db key)
-# ---------------------------------------------------------------------------
-
-def host_isa_key() -> str:
-    """A stable name for this host's ISA capabilities, e.g.
-    ``"x86_64+avx2+avx512f"`` — the key autotune winners are stored under.
-    Same flags => same measured optimum is a reasonable prior; a host with
-    different flags ignores the entry and re-tunes."""
-    traits = []
-    try:
-        with open("/proc/cpuinfo") as fh:
-            flags: set = set()
-            for line in fh:
-                if line.lower().startswith(("flags", "features")):
-                    flags.update(line.split(":", 1)[1].split())
-        for t in ("avx2", "avx512f"):
-            if t in flags:
-                traits.append(t)
-        if {"neon", "asimd"} & flags:
-            traits.append("neon")
-    except OSError:
-        pass
-    return "+".join([platform.machine() or "unknown"] + traits)
-
-
-def serialize_tuned(tuned: dict) -> dict:
-    """``{(backend, layout, mode): kwargs}`` -> JSON-safe string keys."""
-    return {"|".join((b, l or "", m)): dict(kw)
-            for (b, l, m), kw in tuned.items()}
-
-
-def deserialize_tuned(entries: dict) -> dict:
-    """Inverse of :func:`serialize_tuned` (tuple keys, ``""`` -> None)."""
-    out = {}
-    for key, kw in entries.items():
-        backend, layout, mode = key.split("|")
-        out[(backend, layout or None, mode)] = dict(kw)
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -158,16 +110,14 @@ def _write_raw(path, header_fields: tuple, sections: list) -> None:
 
 
 def write_itrf(path, ir, *, include_float: bool = True,
-               pack_leaves: bool = False, tuned: dict = None,
-               group: int = None) -> dict:
+               pack_leaves: bool = False, group: int = None) -> dict:
     """Serialize ``ir`` (a ForestIR) as an ITRF file; returns a summary dict.
 
     ``include_float=False`` drops the float sections (threshold/leaf_probs)
     — a deterministic-serving artifact at roughly half the bytes; loading
     it yields zero float arrays, so only flint/integer routes may serve it.
     ``pack_leaves=True`` stores the leaf table through the exact group
-    codec.  ``tuned`` is a ``{(backend, layout, mode): kwargs}`` map written
-    to the ``tune_db`` section under this host's :func:`host_isa_key`.
+    codec.
     """
     from repro.ir.packed_leaf import GROUP_SIZE, pack_leaf_payload
 
@@ -200,11 +150,6 @@ def write_itrf(path, ir, *, include_float: bool = True,
     meta = {"group_size": group}
     sections.append(("meta", np.frombuffer(json.dumps(meta).encode(),
                                            np.uint8)))
-    if tuned:
-        flags |= FLAG_TUNED
-        db = {host_isa_key(): serialize_tuned(tuned)}
-        sections.append(("tune_db",
-                         np.frombuffer(json.dumps(db).encode(), np.uint8)))
     header = (*ITRF_VERSION, flags, ir.n_trees, ir.n_classes, ir.n_features,
               ir.total_nodes, int(ir.quant_scale or 0))
     _write_raw(path, header, sections)
@@ -312,16 +257,11 @@ def _parse(buf, *, copy: bool, source=None):
         n_features=head["n_features"],
         quant_scale=head["quant_scale"],
     )
-    tuned_db = {}
-    if "tune_db" in table:
-        tuned_db = json.loads(_section_array(buf, table["tune_db"],
-                                             copy=False).tobytes())
-    # artifact provenance, read by the registry (tune seeding, load ledger)
-    # and the remote plan (HELLO ships the raw artifact bytes)
+    # artifact provenance, read by the registry (load ledger) and the
+    # remote plan (HELLO ships the raw artifact bytes)
     ir.itrf_source = str(source) if source is not None else None
     ir.itrf_version = head["version"]
     ir.itrf_flags = head["flags"]
-    ir.itrf_tuned = tuned_db
     ir.itrf_bytes = np.frombuffer(buf, np.uint8)
     return ir
 
@@ -348,15 +288,11 @@ def read_itrf_bytes(data):
 
 
 def inspect_itrf(path) -> dict:
-    """Header + section table + tuned hosts, without touching array pages."""
+    """Header + section table, without touching array pages."""
     with open(path, "rb") as fh:
         mm = mmap.mmap(fh.fileno(), 0, access=mmap.ACCESS_READ)
         head = _parse_header(mm)
         table = _parse_sections(mm, head["n_sections"])
-        tuned_hosts = []
-        if "tune_db" in table:
-            tuned_hosts = sorted(json.loads(
-                _section_array(mm, table["tune_db"], copy=False).tobytes()))
         return {
             **{k: v for k, v in head.items() if k != "n_sections"},
             "file_bytes": os.path.getsize(path),
@@ -365,35 +301,4 @@ def inspect_itrf(path) -> dict:
                        "offset": off, "nbytes": nb}
                 for name, (dt, shape, off, nb) in table.items()
             },
-            "tuned_hosts": tuned_hosts,
         }
-
-
-def update_tuned(path, tuned: dict, *, host_key: str = None) -> None:
-    """Merge autotune winners into an existing artifact's ``tune_db``
-    section (atomic rewrite; all other sections are carried verbatim).
-
-    ``tuned`` uses the in-memory ``{(backend, layout, mode): kwargs}`` form
-    — normally ``ModelVersion._tuned`` — and lands under ``host_key``
-    (default: this host's :func:`host_isa_key`)."""
-    with open(path, "rb") as fh:
-        buf = fh.read()
-    head = _parse_header(buf)
-    table = _parse_sections(buf, head["n_sections"])
-    db = {}
-    if "tune_db" in table:
-        db = json.loads(_section_array(buf, table["tune_db"],
-                                       copy=False).tobytes())
-    key = host_key or host_isa_key()
-    db.setdefault(key, {}).update(serialize_tuned(tuned))
-    sections = [
-        (name, _section_array(buf, entry, copy=False))
-        for name, entry in table.items() if name != "tune_db"
-    ]
-    sections.append(("tune_db",
-                     np.frombuffer(json.dumps(db).encode(), np.uint8)))
-    vmaj, vmin = head["version"]
-    header = (vmaj, vmin, head["flags"] | FLAG_TUNED, head["n_trees"],
-              head["n_classes"], head["n_features"], head["total_nodes"],
-              int(head["quant_scale"] or 0))
-    _write_raw(path, header, sections)

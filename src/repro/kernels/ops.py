@@ -9,17 +9,16 @@ compiled on TPU, interpreted on CPU.
 
 Layout contract (ForestIR): the kernel consumes dense ``(T, N)`` node tables
 — the IR's ``padded`` or ``leaf_major`` materializations (the paper's codegen
-step re-targeted at tensors).  ``packed_partials`` accepts a
-``ForestIR`` directly and materializes the layout its resolved impl walks
-(``leaf_major`` for the linear-scan kernel, ``padded`` otherwise); the
-``ragged`` layout has no VMEM-tileable shape and belongs to the table-walk C
-backend instead.
+step re-targeted at tensors); the ``ragged`` layout has no VMEM-tileable
+shape and belongs to the table-walk C backend instead.  This module takes
+the walk (``impl``) as given: which walk a batch runs is the serving
+backend's choice (``repro.backends.pallas``), made from the budget facts
+:func:`holds_whole_trees` and :func:`pick_blocks` report.
 
 A batch is one device program: the kernel's jitted call (``_traverse``)
 takes the float32 rows and the tables, and keys the rows (``float_to_key``),
 pads them and the tables, lays them out and runs the kernel inside it.  No
-argmax is launched for the partials; ``packed_predict_integer`` takes its
-classes from the fetched partials on the host.
+argmax is launched for the partials.
 
 Host steps are marked as the shard call's stages (``repro.obs.stages``):
 handing host arrays to the device is ``upload`` (``bytes``), and the block
@@ -27,11 +26,11 @@ choice and the jitted call are ``launch`` (``programs=1``, the one program
 a batch; it also notes ``impl``, the walk, and ``node_blocks``, the scan's
 node blocks).
 
-The entry points take host arrays and upload them on every call.  A
-serving backend instead places its node tables once with
-:func:`place_tables` (the ``place`` stage) and hands the device-resident
-copy to ``packed_partials(..., tables=...)``; each batch then
-uploads only its rows.
+The key-level entry point, :func:`tree_predict_integer`, takes host arrays
+and uploads them on every call.  A serving backend instead places its node
+tables once with :func:`place_tables` (the ``place`` stage) and hands the
+device-resident copy to :func:`packed_partials`; each batch then uploads
+only its rows.
 """
 from __future__ import annotations
 
@@ -52,7 +51,8 @@ _VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 _SMEM_BUDGET_BYTES = 512 * 1024
 
 # below this many rows a full-forest grid cell pays the whole per-cell scan
-# for a handful of rows; block_t is scaled down proportionally instead
+# for a handful of rows; block_t is scaled down proportionally instead.  A
+# host-clock cut from a CPU snapshot, not yet measured on the chip.
 _TINY_BATCH_ROWS = 64
 
 
@@ -72,20 +72,14 @@ def _upload(*arrays, name="upload"):
                      for a in arrays)
 
 
-def _host_tables(packed):
-    """The node tables the kernel walks: feature, threshold key, left,
-    right, fixed-point leaves and the leaf_major internal counts (None off
-    that layout, or where its node order is not scannable)."""
-    return (packed.feature, packed.threshold_key, packed.left, packed.right,
-            packed.leaf_fixed, packed.internal_counts)
-
-
 def place_tables(packed):
     """``packed``'s node tables put on the default device as the ``place``
-    stage, for ``packed_partials``' ``tables``: the conversion each
-    call's upload makes, so the kernel's jitted call sees the same shapes
-    and dtypes either way."""
-    return _upload(*_host_tables(packed), name="place")
+    stage, for :func:`packed_partials`' ``tables``: feature, threshold key,
+    left, right, fixed-point leaves and the leaf_major internal counts
+    (None off that layout, or where its node order is not scannable)."""
+    return _upload(packed.feature, packed.threshold_key, packed.left,
+                   packed.right, packed.leaf_fixed, packed.internal_counts,
+                   name="place")
 
 
 def _block_words(block_b, block_t, n, f, c):
@@ -166,8 +160,8 @@ def pick_blocks(b, t, n, f, c, block_b=256, *, chunk_nodes=False):
     (128, min(t, 8), 128) raises ``ValueError``.
 
     Tiny batches (``b < 64``) additionally clamp ``block_t`` proportionally
-    to the rows that amortize it (a heuristic from host timings; the clamp
-    only ever shrinks, so the fit is preserved).
+    to the rows that amortize it (a host-clock cut, not yet measured on the
+    chip; the clamp only ever shrinks, so the fit is preserved).
 
     Memoized: a serving backend asks again at the same shapes every batch.
     """
@@ -205,37 +199,11 @@ def _pick_blocks(b, t, n, f, c, block_b, chunk_nodes, *budgets):
         block_b = _align_block_b(block_b // 2, b)
 
 
-def pick_blocks_candidates(b, t, n, f, c, block_b=256, *, chunk_nodes=False):
-    """The measured-autotune grid around the heuristic: the ``pick_blocks``
-    choice plus its aligned, budget-feasible half/double neighbours along
-    the row and tree axes, each with the ``block_n`` the budgets then give.
-
-    The heuristic optimizes a *budget*, not a runtime; ``TreeEngine.warm``'s
-    autotuner times these candidates on the live host and pins the winner's
-    (block_b, block_t).  Deduplicated, heuristic first (ties resolve to it),
-    every entry fits the budgets, so any candidate is safe to pin.  Node
-    blocks are offered only for trees that no whole-tree tiling holds: where
-    whole trees fit, a backend sends small batches to the gather walk at the
-    pinned ``block_t``, and that walk cannot cut the node axis.
-    """
-    chunk_nodes = chunk_nodes and not holds_whole_trees(t, n, f, c)
-    auto = pick_blocks(b, t, n, f, c, block_b, chunk_nodes=chunk_nodes)
-    auto_b, auto_t, _ = auto
-    cands = [auto]
-    for bb, bt in (
-        (auto_b, _align_block_t(auto_t // 2, t)),
-        (_align_block_b(auto_b // 2, b), auto_t),
-        (auto_b, _align_block_t(auto_t * 2, t)),
-    ):
-        bn = _pinned_node_block(bb, bt, n, f, c, chunk_nodes)
-        if bn is not None and (bb, bt, bn) not in cands:
-            cands.append((bb, bt, bn))
-    return cands
-
-
 def _pinned_node_block(block_b, block_t, n, f, c, chunk_nodes):
     """``block_n`` for a pinned (block_b, block_t): whole trees where they
-    fit, node blocks on the scan, None where nothing fits."""
+    fit, node blocks on the scan, None where nothing fits.  Only the kernel
+    tests pin ``block_t`` (the block-shape sweeps and the pinned tree-block
+    checks); no served route does."""
     if chunk_nodes:
         return _node_block(block_b, block_t, n, f, c)
     npad = _round_up(n, LANES)
@@ -291,8 +259,8 @@ def _launch(x, feature, threshold_key, left, right, leaf_fixed, nint, *,
     device arrays, one program; -> its (B, C) uint32 partials."""
     if impl == "leaf_major" and nint is None:
         raise ValueError(
-            "impl='leaf_major' needs the layout's internal_counts; "
-            "materialize the forest as leaf_major (see repro.ir.layouts)"
+            "impl='leaf_major' needs the layout's internal_counts, which "
+            "only the leaf_major layout records (see repro.ir.layouts)"
         )
     with stage("launch", programs=1) as launch:
         b, f = x.shape
@@ -354,58 +322,21 @@ def tree_predict_integer(
                    block_b=block_b, block_t=block_t, interpret=interpret)
 
 
-def packed_partials(packed, X, impl: str = "auto", tables=None, **kw):
-    """Node-table entry point: float features in, (B, C) uint32 partials
-    out, as one device program (:func:`_traverse` keys the rows itself).
+def packed_partials(packed, X, *, impl, tables, **kw):
+    """Walk ``packed``'s device-resident node ``tables`` (:func:`place_tables`
+    of this same artifact) over the float rows ``X`` with the walk
+    ``impl``, as one device program (:func:`_traverse` keys the rows
+    itself): -> (B, C) uint32 partials.  Only ``X`` is uploaded.
 
-    ``packed``: a node-table artifact (``PackedEnsemble`` in ``padded`` or
-    ``leaf_major`` layout) or a ``ForestIR``.  ``impl="auto"`` resolves per
-    layout — the linear-scan kernel on ``leaf_major`` tables, ``gather`` on
-    ``padded`` — and a ForestIR is materialized into whichever layout the
-    resolved impl walks (``leaf_major`` for the scan, ``padded`` otherwise).
-    Pinning ``impl="leaf_major"`` on a padded artifact re-materializes it as
-    leaf_major through the IR back-reference.  ``kw``: ``block_b``,
-    ``block_t`` and ``interpret``, as :func:`tree_predict_integer` takes
-    them.
-
-    ``tables``: :func:`place_tables` of this same artifact, walked in place
-    of its host arrays, so that only ``X`` is uploaded; without it the
-    tables are uploaded with every call.
+    ``packed``: the node-table artifact (``padded`` or ``leaf_major``
+    layout) the tables came from.  The walk is taken as given.  ``kw``:
+    ``block_b``, ``block_t`` and ``interpret``, as
+    :func:`tree_predict_integer` takes them.
     """
-    if hasattr(packed, "materialize"):  # a ForestIR: take the kernel's layout
-        packed = packed.materialize(
-            "leaf_major" if impl in ("auto", "leaf_major") else "padded"
-        )
-    layout = getattr(packed, "layout", "padded")
-    if layout not in ("padded", "leaf_major"):
-        raise ValueError(
-            f"the Pallas kernel walks (T, N) node tables, not the {layout!r} "
-            "layout; ragged belongs to the table-walk C backend"
-        )
-    if impl == "auto":
-        # the scan needs the leaf_major internal prefix and its children-
-        # after-parents order (internal_counts is None when an imported
-        # forest violates it); any node order gather-walks fine
-        impl = ("leaf_major"
-                if layout == "leaf_major"
-                and getattr(packed, "internal_counts", None) is not None
-                else "gather")
-    if impl == "leaf_major" and layout != "leaf_major":
-        from repro.ir import resolve_artifact
-
-        packed = resolve_artifact(packed, "leaf_major")
     # float32 on either side: the program keys float32 rows
     X = (jnp.asarray(X, jnp.float32) if isinstance(X, jax.Array)
          else np.asarray(X, np.float32))
-    *nodes, nint = tables or _host_tables(packed)
-    x, *nodes, nint = _upload(X, *nodes,
-                              nint if impl == "leaf_major" else None)
-    return _launch(x, *nodes, nint, depth=packed.max_depth, impl=impl, **kw)
-
-
-def packed_predict_integer(packed, X, impl: str = "auto", tables=None, **kw):
-    """:func:`packed_partials` and each row's class: (scores, preds).  The
-    class, the first largest score as ``argmax`` takes it, is read on the
-    host from the fetched partials, so the device runs the one program."""
-    acc = packed_partials(packed, X, impl, tables, **kw)
-    return acc, np.argmax(np.asarray(acc), axis=1).astype(np.int32)
+    *nodes, nint = tables
+    (x,) = _upload(X)
+    return _launch(x, *nodes, nint if impl == "leaf_major" else None,
+                   depth=packed.max_depth, impl=impl, **kw)

@@ -199,8 +199,6 @@ def resolve_gateway_spec(args):
         over["plan"] = None if args.gw_plan == "auto" else args.gw_plan
     if args.gw_shards is not None:
         over["shards"] = args.gw_shards
-    if args.gw_autotune:
-        over["autotune"] = True
     if args.gw_block_rows is not None:
         backend = over.get("backend", spec.backend)
         if backend != "native_c_table":
@@ -260,8 +258,7 @@ def serve_gateway(args):
         eng = registry.get(mid).engine(spec, plan_kwargs=plan_kwargs)
         eng.warm(args.gw_batch_rows)
     print(f"warmed shape buckets in {time.time()-t0:.1f}s "
-          f"(plan={eng.plan_name}, shards={eng.n_shards}, "
-          f"tuned={eng.tuned_config or '-'})")
+          f"(plan={eng.plan_name}, shards={eng.n_shards})")
 
     def _do_swap(gw):
         mv = gw.registry.register_forest(
@@ -337,7 +334,7 @@ def serve_lm(args):
     from repro.configs.base import get_config, smoke_config
     from repro.data.tokens import pipeline_for
     from repro.models import transformer as tfm
-    from repro.serve.engine import LMEngine
+    from repro.serve.lm_engine import LMEngine
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     params = tfm.init_params(cfg, jax.random.PRNGKey(0))
@@ -395,11 +392,6 @@ def main(argv=None):
                     help="execution plan behind the gateway (default: "
                          "single-shard; 'auto' selects by capability from "
                          "--gw-shards and the mode)")
-    ap.add_argument("--gw-autotune", action="store_true",
-                    help="measure backend construction knobs (table-walk "
-                         "block_rows, bitvector interleave width, Pallas "
-                         "block tiling) during warm and serve on the winner; "
-                         "REPRO_AUTOTUNE=0 disables globally")
     ap.add_argument("--gw-shards", type=int, default=None,
                     help="shard count for tree-/row-parallel plans (trees "
                          "are carved via ForestIR.subset; partial integer "

@@ -225,7 +225,7 @@ def render_prometheus(per_model: dict, *, namespace: str = "repro") -> str:
     head("bucket_compile_ms", "gauge",
          "compile/warm wall ms of each padded row bucket")
     for mid, s in per_model.items():
-        # buckets are int row counts plus the autotuner's "tune" entry
+        # buckets are int row counts plus named entries ("load", "remote")
         for bucket, ms in sorted(s.get("compile_ms_by_bucket", {}).items(),
                                  key=lambda kv: str(kv[0])):
             out.append(f'{namespace}_bucket_compile_ms'

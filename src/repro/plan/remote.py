@@ -37,8 +37,7 @@ Fleet semantics:
 
 Connect + handshake cost is recorded once under the ``"remote"`` key of
 the engine's compile/warm ledger (via ``drain_setup_timings``), landing in
-``compile_ms_by_bucket`` next to the jit buckets and the autotuner's
-``"tune"`` entry.
+``compile_ms_by_bucket`` next to the jit buckets.
 """
 from __future__ import annotations
 

@@ -21,7 +21,7 @@ _EXPORTS = {
     "AdmissionError": "repro.serve.queue",
     "EngineSpec": "repro.serve.spec",
     "Gateway": "repro.serve.gateway",
-    "LMEngine": "repro.serve.engine",
+    "LMEngine": "repro.serve.lm_engine",
     "MetricsRegistry": "repro.serve.metrics",
     "MicroBatcher": "repro.serve.queue",
     "ModelMetrics": "repro.serve.metrics",
@@ -39,8 +39,9 @@ __all__ = sorted(_EXPORTS)
 
 if TYPE_CHECKING:  # pragma: no cover - static analysis only
     from repro.serve.cache import QuantizedKeyCache, row_keys  # noqa: F401
-    from repro.serve.engine import LMEngine, TreeEngine, bucket_rows  # noqa: F401
+    from repro.serve.engine import TreeEngine, bucket_rows  # noqa: F401
     from repro.serve.gateway import Gateway  # noqa: F401
+    from repro.serve.lm_engine import LMEngine  # noqa: F401
     from repro.serve.metrics import MetricsRegistry, ModelMetrics  # noqa: F401
     from repro.serve.queue import AdmissionError, MicroBatcher  # noqa: F401
     from repro.serve.registry import ModelRegistry, ModelVersion  # noqa: F401

@@ -1,7 +1,4 @@
-"""Serving engines.
-
-``LMEngine``: batched prefill + greedy/temperature decode for the LM archs
-(jitted prefill and decode steps, KV/state cache carried on device).
+"""The tree serving engine.
 
 ``TreeEngine``: the paper's serving path — a thin shape-bucketing wrapper
 over one :class:`~repro.plan.ExecutionPlan`, which in turn drives any
@@ -33,40 +30,7 @@ from __future__ import annotations
 import time
 from typing import Optional
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-
-from repro.configs.base import ModelConfig
-from repro.models import transformer as tfm
-
-
-class LMEngine:
-    def __init__(self, cfg: ModelConfig, params, *, max_seq: int = 1024):
-        self.cfg = cfg
-        self.params = params
-        self.max_seq = max_seq
-        self._prefill = jax.jit(lambda p, b: tfm.prefill(cfg, p, b, max_seq=max_seq))
-        self._decode = jax.jit(lambda p, c, t: tfm.decode_step(cfg, p, c, t))
-
-    def generate(self, batch: dict, n_tokens: int, *, temperature: float = 0.0,
-                 seed: int = 0):
-        """Greedy (T=0) or sampled decode.  Returns (B, n_tokens) int32."""
-        logits, cache = self._prefill(self.params, batch)
-        key = jax.random.PRNGKey(seed)
-        toks = []
-        b = logits.shape[0]
-        for i in range(n_tokens):
-            if temperature > 0:
-                key, sub = jax.random.split(key)
-                nxt = jax.random.categorical(sub, logits / temperature, axis=-1)
-            else:
-                nxt = jnp.argmax(logits, axis=-1)
-            nxt = nxt.astype(jnp.int32).reshape(b, 1)
-            toks.append(nxt)
-            logits, cache = self._decode(self.params, cache, nxt)
-        return jnp.concatenate(toks, axis=1)
-
 
 def bucket_rows(b: int, *, max_bucket: int = 4096) -> int:
     """Padded row count for a batch of ``b`` rows: the next power of two,
@@ -84,7 +48,7 @@ class TreeEngine:
 
     ``packed`` is a :class:`~repro.ir.ForestIR` or any materialized layout
     artifact.  The route — mode, backend(s), layout, plan, shards, backend
-    kwargs, autotune — is one :class:`~repro.serve.spec.EngineSpec`, passed
+    kwargs — is one :class:`~repro.serve.spec.EngineSpec`, passed
     as ``spec`` (an EngineSpec, a dict, or a spec string like
     ``"integer:bitvector@leaf_major+tree_parallel:4"``); the individual
     keyword arguments survive as a deprecation shim that warns once per
@@ -110,55 +74,23 @@ class TreeEngine:
     line up with the backends' internal tiling.  Engines whose plan owns
     executors (thread pools, remote workers) release them via
     :meth:`close`.
-
-    ``autotune=True`` measures the serving backend's construction knobs
-    (table-walk ``block_rows``, bitvector ``interleave``, Pallas block tiling
-    — see :mod:`repro.serve.autotune`) during :meth:`warm` and rebuilds the
-    plan on the measured winner; single-shard string-backend routes only, and
-    knobs the caller already pinned via ``backend_kwargs`` are never
-    overridden.  ``tuned_store`` (a mutable dict, normally the owning
-    ``ModelVersion``'s) caches winners per (backend, layout, mode) route so a
-    hot-swapped version or a rebuilt engine skips re-measuring; the
-    ``REPRO_AUTOTUNE=0`` env var disables tuning globally.
     """
 
     def __init__(self, packed=None, spec=None, *, mode: Optional[str] = None,
                  backend=None, backend_kwargs: Optional[dict] = None,
                  max_bucket: Optional[int] = None, layout: Optional[str] = None,
                  plan: Optional[str] = None, shards: Optional[int] = None,
-                 plan_kwargs: Optional[dict] = None, autotune=None,
-                 tuned_store: Optional[dict] = None):
-        from repro.plan import create_plan, select_plan
-        from repro.serve.autotune import TUNABLE_BACKENDS, autotune_enabled, \
-            config_str
+                 plan_kwargs: Optional[dict] = None):
+        from repro.plan import create_plan
         from repro.serve.spec import EngineSpec
 
         spec = EngineSpec.coerce(spec, caller="TreeEngine", mode=mode,
                                  backend=backend, layout=layout, plan=plan,
-                                 shards=shards, backend_kwargs=backend_kwargs,
-                                 autotune=autotune)
+                                 shards=shards, backend_kwargs=backend_kwargs)
         self.spec = spec
         mode, backend, layout = spec.mode, spec.backend, spec.layout
-        plan, shards, autotune = spec.plan, spec.shards, spec.autotune
+        plan, shards = spec.plan, spec.shards
         backend_kwargs = dict(spec.backend_kwargs) if spec.backend_kwargs else None
-        self._ctor = dict(packed=packed, mode=mode, backend=backend,
-                          backend_kwargs=backend_kwargs, layout=layout,
-                          plan=plan, shards=shards, plan_kwargs=plan_kwargs)
-        self._tuned_store = tuned_store if tuned_store is not None else {}
-        self._tuned_config: Optional[str] = None
-        self._pending_tune = False
-        if autotune_enabled(autotune) and isinstance(backend, str) \
-                and backend in TUNABLE_BACKENDS \
-                and select_plan(plan, mode=mode, backend=backend,
-                                shards=shards, model=packed) == "single":
-            winner = self._tuned_store.get(self._tune_key())
-            if winner is not None:
-                # a cached measurement (hot-swap, rebuilt engine): apply it
-                # now — caller-pinned kwargs still win on key collisions
-                backend_kwargs = {**winner, **(backend_kwargs or {})}
-                self._tuned_config = config_str(winner)
-            else:
-                self._pending_tune = True
         self.plan = create_plan(
             plan, packed, mode=mode, backend=backend, shards=shards,
             layout=layout, backend_kwargs=backend_kwargs,
@@ -169,17 +101,12 @@ class TreeEngine:
         self.max_bucket = max_bucket or self.plan.preferred_block_rows or 4096
         self.compiled_buckets: set[int] = set()
         # first-execution wall ms per bucket (jit compile / native build /
-        # warm cost) plus the autotune measuring cost under the "tune" key
-        # and the registry's artifact-load ms under "load", drained by the
-        # gateway into per-model metrics
+        # warm cost) plus the registry's artifact-load ms under "load",
+        # drained by the gateway into per-model metrics
         self._compile_ms: dict = {}
         # set by close(); the registry's retention policy closes engines of
         # released versions and the gateway prunes closed engines
         self.closed = False
-
-    def _tune_key(self):
-        c = self._ctor
-        return (c["backend"], c["layout"], c["mode"])
 
     @property
     def backend(self):
@@ -220,48 +147,6 @@ class TreeEngine:
         fn = getattr(self.backend, "simd_isa", None)
         return fn() if fn is not None else None
 
-    @property
-    def tuned_config(self) -> Optional[str]:
-        """The autotuned backend config serving this engine (e.g.
-        ``"interleave=4"``), or ``None`` when untuned (autotune off, tuning
-        still pending, or a knob the caller pinned)."""
-        return self._tuned_config
-
-    def _run_autotune(self, max_rows: int) -> None:
-        """Measure the backend's candidate grid and rebuild the plan on the
-        winner (see :mod:`repro.serve.autotune`).  Runs at most once, at the
-        start of the first :meth:`warm`; the measuring wall-ms lands in the
-        compile ledger under ``"tune"`` and the winner in ``tuned_store``."""
-        from repro.plan import create_plan
-        from repro.serve import autotune as at
-
-        self._pending_tune = False
-        c = self._ctor
-        user_kw = c["backend_kwargs"] or {}
-        backend = self.backend  # builds the default-config backend
-        grid = at.candidate_grid(self.backend_name, backend.packed)
-        if not grid or set(grid[0]) & set(user_kw):
-            return  # nothing to sweep, or the caller pinned the knob
-        t0 = time.perf_counter()
-        winner, winner_backend, _ = at.tune_backend(
-            self.backend_name, backend.packed, self.mode,
-            rows=min(max(max_rows, 1), at._TUNE_ROWS), baseline=backend,
-        )
-        self._compile_ms["tune"] = (time.perf_counter() - t0) * 1e3
-        if winner is None:
-            return
-        self._tuned_store[self._tune_key()] = winner
-        self._tuned_config = at.config_str(winner)
-        if winner_backend is not backend:
-            # serve on the measured winner: rebuild the plan around the
-            # already-built winning backend (no recompile)
-            self.plan = create_plan(
-                c["plan"], c["packed"], mode=c["mode"],
-                backend=winner_backend, shards=c["shards"],
-                layout=c["layout"], **(c["plan_kwargs"] or {})
-            )
-            self.compiled_buckets.clear()
-
     def drain_shard_timings(self) -> dict:
         """Per-shard wall time since the last drain (``{label: (ms, calls)}``)
         — what the gateway records into ``serve.metrics`` per batch."""
@@ -274,9 +159,8 @@ class TreeEngine:
 
     def drain_compile_timings(self) -> dict:
         """First-execution (compile/warm) wall ms per bucket since the last
-        drain: ``{bucket_rows: ms}``, plus the autotuner's ``"tune"`` entry
-        and the plan's one-time setup cost (the remote plan's
-        connect + handshake ms under ``"remote"``)."""
+        drain: ``{bucket_rows: ms}``, plus the plan's one-time setup cost
+        (the remote plan's connect + handshake ms under ``"remote"``)."""
         out, self._compile_ms = self._compile_ms, {}
         out.update(self.plan.drain_setup_timings())
         return out
@@ -310,13 +194,7 @@ class TreeEngine:
         rows for row-parallel, full buckets per tree shard) — no shard is
         left to compile on the first live request.  For shape-oblivious plans
         one call builds every shard's artifact (e.g. compiles the native
-        libraries) and no further shapes exist.
-
-        When autotuning is armed, the candidate sweep runs first — warm is
-        the one moment the engine may measure and swap its backend without a
-        request in flight — and the buckets below warm whatever won."""
-        if self._pending_tune:
-            self._run_autotune(max_rows)
+        libraries) and no further shapes exist."""
         zeros = lambda nb: np.zeros((nb, self.packed.n_features), np.float32)
         if not self.plan.compiles_per_shape:
             self.predict(zeros(1))

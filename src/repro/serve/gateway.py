@@ -57,7 +57,7 @@ class Gateway:
                  backend=None, layout: str = None,
                  backend_kwargs: dict = None,
                  plan: str = None, shards: int = None,
-                 autotune: bool = None, plan_kwargs: dict = None,
+                 plan_kwargs: dict = None,
                  max_batch_rows: int = 256,
                  max_delay_ms: float = 2.0, max_queue_rows: int = 4096,
                  cache_rows: int = 65536, tracer=None):
@@ -72,8 +72,7 @@ class Gateway:
         # keyword arguments remain as the deprecation-shimmed pre-spec API
         spec = EngineSpec.coerce(spec, caller="Gateway", mode=mode,
                                  backend=backend, layout=layout, plan=plan,
-                                 shards=shards, backend_kwargs=backend_kwargs,
-                                 autotune=autotune)
+                                 shards=shards, backend_kwargs=backend_kwargs)
         self.spec = spec
         self.mode = spec.mode
         self.backend = spec.backend
@@ -96,9 +95,6 @@ class Gateway:
         # deployment knobs for the plan (e.g. the remote plan's ``workers`` /
         # ``deadline_ms``) — forwarded to every engine this gateway builds
         self.plan_kwargs = plan_kwargs
-        # arm warm-time measured autotuning on every engine this gateway
-        # builds (single-shard tunable routes; see repro.serve.autotune)
-        self.autotune = bool(spec.autotune)
         resolved_plan = select_plan(spec.plan, mode=spec.mode,
                                     backend=spec.backend,
                                     shards=spec.shards)  # raises on unknowns
@@ -199,10 +195,8 @@ class Gateway:
         mm.record_stages(eng.drain_stage_timings())
         mm.record_compiles(eng.drain_compile_timings())
         # dispatched SIMD ISA (free here: the batch above already built the
-        # backend, so the probe never triggers a compile) + the autotuned
-        # config the engine is serving on, if any
+        # backend, so the probe never triggers a compile)
         mm.record_isa(eng.simd_isa())
-        mm.record_tuned(eng.tuned_config)
         mm.record_spec(str(self.spec))
         # meta = the version that actually computed, so cache fills are keyed
         # consistently even when a hot-swap lands between submit and dispatch

@@ -62,9 +62,6 @@ class ModelMetrics:
     # for C backends; "-" before the first batch and for backends without
     # the surface) — recorded by the gateway after each dispatch
     isa: str = "-"
-    # the warm-time autotuner's chosen backend config (e.g. "interleave=4");
-    # "-" when the route is untuned or tuning hasn't run yet
-    tuned: str = "-"
     # the canonical EngineSpec string the gateway serves this model on
     # (e.g. "integer:reference@padded+tree_parallel:2"); "-" pre-dispatch
     spec: str = "-"
@@ -135,11 +132,6 @@ class ModelMetrics:
         if isa:
             self.isa = str(isa)
 
-    def record_tuned(self, config) -> None:
-        """Record the engine's autotuned config string (None keeps "-")."""
-        if config:
-            self.tuned = str(config)
-
     def record_spec(self, spec) -> None:
         """Record the canonical serving-route spec string (None keeps "-")."""
         if spec:
@@ -176,15 +168,14 @@ class ModelMetrics:
             "cache_hit_rate": self.cache_hits / probed if probed else 0.0,
             "cache_hits": self.cache_hits,
             "isa": self.isa,
-            "tuned": self.tuned,
             "spec": self.spec,
             # the per-stage attribution columns: mean wall ms per stage
             # sample — where a request's latency actually went
             **{f"{stage}_ms": self._stage_mean(stage) for stage in _STAGE_COLUMNS},
             "latency": self.latency.snapshot(),
             "stages": {name: h.snapshot() for name, h in sorted(self.stages.items())},
-            # keys are int row buckets plus the autotuner's "tune" entry —
-            # sort on the string form so the mix stays orderable
+            # keys are int row buckets plus named entries ("load",
+            # "remote") — sort on the string form so the mix stays orderable
             "compile_ms_by_bucket": dict(
                 sorted(self.compile_ms.items(), key=lambda kv: str(kv[0]))
             ),
@@ -211,7 +202,7 @@ _TABLE_COLS = (
     ("queue_ms", "queue_ms"), ("pad_ms", "pad_ms"), ("shard_ms", "shard_ms"),
     ("final_ms", "finalize_ms"), ("occup", "batch_occupancy"),
     ("pad_eff", "pad_efficiency"), ("hit_rate", "cache_hit_rate"),
-    ("isa", "isa"), ("tuned", "tuned"), ("shards", "shards"),
+    ("isa", "isa"), ("shards", "shards"),
     # last column on purpose: the canonical spec string is long and would
     # misalign everything to its right
     ("spec", "spec"),

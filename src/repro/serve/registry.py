@@ -11,7 +11,7 @@ Models enter through any boundary the repo supports:
     the hot-swap-back case — reuses the already-parsed IR *object*, layouts
     and all, so a swap costs microseconds.  The measured load wall-ms rides
     the compile/warm ledger as the ``"load"`` bucket of the version's first
-    engine, next to the existing ``"tune"``/``"remote"`` entries.
+    engine, next to the remote plan's ``"remote"`` entry.
 
 Each ``register_*`` call creates a new immutable :class:`ModelVersion` and
 atomically repoints the model id at it (hot-swap).  In-flight batches formed
@@ -24,7 +24,7 @@ padded tables carry the canonical IR, so every layout materializes from one
 quantization.
 
 Retention: superseded versions used to stay resident forever (engines,
-compiled C libraries, tuned caches).  The registry now keeps the newest
+compiled C libraries).  The registry now keeps the newest
 ``retain`` versions per model id (default 2: current + previous, so
 in-flight batches on the just-swapped-out version still finish) and
 releases anything older — :meth:`ModelVersion.release` closes and drops
@@ -67,16 +67,12 @@ class ModelVersion:
     # wall-ms spent constructing each route's engine (backend builds, native
     # compiles) — the cold-start cost ``describe()`` surfaces per model
     _build_ms: dict = field(default_factory=dict, repr=False)
-    # measured autotune winners per (backend, layout, mode) route — written
-    # by TreeEngine warm-time tuning, copied forward across hot-swaps by the
-    # registry so a swapped-in version reuses the measurement
-    _tuned: dict = field(default_factory=dict, repr=False)
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
 
     def engine(self, spec=None, *, mode: str = None, backend=None,
                layout: str = None, backend_kwargs: dict = None,
                plan: str = None, shards: int = None,
-               autotune=None, plan_kwargs: dict = None) -> TreeEngine:
+               plan_kwargs: dict = None) -> TreeEngine:
         """The memoized TreeEngine for one route.
 
         The route is an :class:`~repro.serve.spec.EngineSpec` (object, dict,
@@ -90,10 +86,7 @@ class ModelVersion:
         sequence of backend names (heterogeneous tree-parallel) memoizes
         under the tuple.  ``backend_kwargs`` only apply on the call that
         first builds the engine; later lookups for the same route return it
-        as-is.  ``autotune`` arms warm-time measured tuning (memoized
-        separately, so tuned and untuned routes never alias); winners land
-        in this version's ``_tuned`` cache and survive hot-swaps.
-        ``plan_kwargs`` carries plan deployment knobs (e.g. the remote
+        as-is.  ``plan_kwargs`` carries plan deployment knobs (e.g. the remote
         plan's ``workers``) and participates in the memo key; the remote
         plan additionally receives this version's identity so its handshake
         carries the model id + version.
@@ -108,13 +101,12 @@ class ModelVersion:
             # route kwargs it is the pre-spec positional call
             # engine("integer", backend=...) and goes through the shim
             loose = (backend, layout, plan, shards, backend_kwargs)
-            if any(v is not None for v in loose) or autotune is not None:
+            if any(v is not None for v in loose):
                 mode, spec = spec, None
         spec = EngineSpec.coerce(spec, caller="ModelVersion.engine",
                                  mode=mode, backend=backend, layout=layout,
                                  plan=plan, shards=shards,
-                                 backend_kwargs=backend_kwargs,
-                                 autotune=autotune)
+                                 backend_kwargs=backend_kwargs)
         if isinstance(spec.backend, str):
             resolved = spec.layout or \
                 backend_class(spec.backend).capabilities.preferred_layout
@@ -131,7 +123,7 @@ class ModelVersion:
                                     model=self.packed)
         key = (spec.mode, backend_key, resolved, resolved_plan,
                None if resolved_plan == "single" else spec.shards,
-               bool(spec.autotune), _freeze(plan_kwargs))
+               _freeze(plan_kwargs))
         with self._lock:
             if self.released:
                 raise RuntimeError(
@@ -148,11 +140,11 @@ class ModelVersion:
                     pk.setdefault("version", self.version)
                 eng = TreeEngine(
                     self.packed, spec.replace(layout=resolved),
-                    plan_kwargs=pk or None, tuned_store=self._tuned,
+                    plan_kwargs=pk or None,
                 )
                 if self._load_ms is not None:
                     # the artifact load cost surfaces once, through the same
-                    # ledger compile/tune/remote costs already ride
+                    # ledger compile/remote costs already ride
                     eng._compile_ms["load"] = self._load_ms
                     self._load_ms = None
                 self._engines[key] = eng
@@ -196,12 +188,6 @@ class ModelRegistry:
             version = self._history.get(model_id, 0) + 1
             mv = ModelVersion(model_id=model_id, version=version, packed=packed,
                               source=source)
-            prev = self._models.get(model_id)
-            if prev is not None:
-                # carry measured autotune winners across the hot-swap: the
-                # host didn't change, so the new version serves on the tuned
-                # config immediately instead of re-measuring during warm
-                mv._tuned.update(prev._tuned)
             self._history[model_id] = version
             self._models[model_id] = mv  # atomic repoint = hot-swap
             window = self._versions.setdefault(model_id, {})
@@ -229,14 +215,9 @@ class ModelRegistry:
         With ``mmap=True`` the version's ForestIR is zero-copy read-only
         views over the file mapping; every process registering the same file
         shares one page cache.  The measured load wall-ms lands in the first
-        engine's compile ledger under ``"load"``.  If the artifact carries a
-        ``tune_db`` entry for this host's ISA (see
-        :func:`repro.ir.artifact.host_isa_key`), the autotune winners seed
-        the version's ``_tuned`` cache, so warm-time tuning is skipped;
-        entries recorded on hosts with different CPU flags are ignored.
+        engine's compile ledger under ``"load"``.
         """
-        from repro.ir.artifact import deserialize_tuned, host_isa_key, \
-            read_itrf
+        from repro.ir.artifact import read_itrf
 
         t0 = time.perf_counter()
         cache_key = None
@@ -258,23 +239,7 @@ class ModelRegistry:
         load_ms = (time.perf_counter() - t0) * 1e3
         mv = self._install(model_id, ir, "artifact")
         mv._load_ms = load_ms
-        for route, kwargs in deserialize_tuned(
-                getattr(ir, "itrf_tuned", {}).get(host_isa_key(), {})).items():
-            # live measurements carried across the swap still win
-            mv._tuned.setdefault(route, kwargs)
         return mv
-
-    def export_tuned(self, model_id: str, path) -> None:
-        """Persist the current version's measured autotune winners into an
-        existing ITRF file's ``tune_db`` section (keyed by this host's ISA),
-        so the next process to ``register_artifact`` it starts warm-tuned."""
-        from repro.ir.artifact import update_tuned
-
-        mv = self.get(model_id)
-        with mv._lock:
-            tuned = dict(mv._tuned)
-        if tuned:
-            update_tuned(path, tuned)
 
     def release(self, model_id: str, version: int) -> None:
         """Free a retained, non-current version explicitly (its engines

@@ -1,7 +1,7 @@
 """`EngineSpec` — the one serializable description of an engine route.
 
 Every layer that used to take loose route kwargs (``mode=``, ``backend=``,
-``layout=``, ``plan=``, ``shards=``, ``backend_kwargs=``, ``autotune=``)
+``layout=``, ``plan=``, ``shards=``, ``backend_kwargs=``)
 now accepts a single spec — as an :class:`EngineSpec`, a dict, or the
 compact string grammar — and the loose kwargs survive only as a
 deprecation shim that warns once per call site.  The spec is also what the
@@ -19,9 +19,8 @@ String grammar (every part optional)::
     pallas|native_c+tree_parallel:2   (heterogeneous shard backends)
 
 ``+auto:N`` pins a shard count while leaving plan selection to
-``select_plan`` (it renders back the same way).  The reserved query key
-``autotune=1`` arms the warm-time autotuner; every other query key lands
-in ``backend_kwargs`` with int/float/bool literals parsed.
+``select_plan`` (it renders back the same way).  Query keys land in
+``backend_kwargs`` with int/float/bool literals parsed.
 """
 from __future__ import annotations
 
@@ -38,8 +37,17 @@ __all__ = ["EngineSpec", "MODES"]
 MODES = ("float", "flint", "integer")
 
 _LOOSE_KEYS = ("mode", "backend", "layout", "plan", "shards",
-               "backend_kwargs", "autotune")
+               "backend_kwargs")
+# a retired route key: a spec naming it is refused rather than handed to a
+# backend's constructor as a kwarg
+_RETIRED_KEY = "autotune"
 _warned_callers: set = set()
+
+
+def _refuse_unknown(keys) -> None:
+    extra = set(keys) - set(_LOOSE_KEYS)
+    if extra:
+        raise ValueError(f"unknown EngineSpec keys {sorted(extra)}")
 
 
 def _parse_literal(text: str):
@@ -80,11 +88,13 @@ class EngineSpec:
     plan: Optional[str] = None
     shards: Optional[int] = None
     backend_kwargs: Optional[dict] = None
-    autotune: bool = False
 
     def __post_init__(self):
         if isinstance(self.backend, list):
             object.__setattr__(self, "backend", tuple(self.backend))
+        if self.backend_kwargs and _RETIRED_KEY in self.backend_kwargs:
+            raise ValueError(f"route key {_RETIRED_KEY!r} no longer exists; "
+                             "drop it from the spec")
 
     # -- construction ------------------------------------------------------
 
@@ -129,7 +139,6 @@ class EngineSpec:
             if plan in ("", "auto"):
                 plan = None  # shards pinned, plan auto-selected
         backend_kwargs: dict = {}
-        autotune = False
         if query:
             for item in query.split(","):
                 if not item:
@@ -137,13 +146,9 @@ class EngineSpec:
                 k, sep, v = item.partition("=")
                 if not sep:
                     raise ValueError(f"bad query item {item!r} in spec {text!r}")
-                if k == "autotune":
-                    autotune = bool(_parse_literal(v))
-                else:
-                    backend_kwargs[k] = _parse_literal(v)
+                backend_kwargs[k] = _parse_literal(v)
         spec = cls(mode=mode, backend=backend, layout=layout, plan=plan,
-                   shards=shards, backend_kwargs=backend_kwargs or None,
-                   autotune=autotune)
+                   shards=shards, backend_kwargs=backend_kwargs or None)
         if validate:
             spec.validate()
         return spec
@@ -151,14 +156,10 @@ class EngineSpec:
     @classmethod
     def from_dict(cls, d: Mapping) -> "EngineSpec":
         """Inverse of :meth:`to_dict` (extra keys rejected)."""
-        extra = set(d) - set(_LOOSE_KEYS)
-        if extra:
-            raise ValueError(f"unknown EngineSpec keys {sorted(extra)}")
+        _refuse_unknown(d)
         kw = {k: d[k] for k in _LOOSE_KEYS if d.get(k) is not None}
         if isinstance(kw.get("backend"), list):
             kw["backend"] = tuple(kw["backend"])
-        if "autotune" in kw:
-            kw["autotune"] = bool(kw["autotune"])
         return cls(**kw)
 
     @classmethod
@@ -171,8 +172,8 @@ class EngineSpec:
         per call site.  Mixing a spec with loose kwargs is an error — there
         would be no unambiguous precedence.
         """
-        loose = {k: v for k, v in loose.items()
-                 if v is not None and not (k == "autotune" and v is False)}
+        _refuse_unknown(loose)
+        loose = {k: v for k, v in loose.items() if v is not None}
         if spec is None:
             if loose and caller not in _warned_callers:
                 _warned_callers.add(caller)
@@ -245,7 +246,6 @@ class EngineSpec:
             "plan": self.plan,
             "shards": self.shards,
             "backend_kwargs": dict(self.backend_kwargs) if self.backend_kwargs else None,
-            "autotune": bool(self.autotune),
         }
 
     def canonical(self) -> str:
@@ -265,8 +265,6 @@ class EngineSpec:
         elif self.shards:
             out += f"+auto:{self.shards}"
         q = dict(sorted((self.backend_kwargs or {}).items()))
-        if self.autotune:
-            q["autotune"] = True
         if q:
             out += "?" + ",".join(f"{k}={_fmt_literal(v)}" for k, v in q.items())
         return out

@@ -10,8 +10,8 @@ Closes the paper's dataset -> deployable-artifact loop at the command line:
 ``--strip-float`` drops the float threshold/leaf-probability sections
 (deterministic-serving artifact, roughly half the bytes); ``--pack-leaves``
 stores the fixed-point leaf table through the exact group codec
-(:mod:`repro.ir.packed_leaf`).  ``--inspect`` dumps the header, the section
-table, and any tuned-host entries without loading array pages.
+(:mod:`repro.ir.packed_leaf`).  ``--inspect`` dumps the header and the
+section table without loading array pages.
 
 ``--selftest`` is the end-to-end proof CI runs: train a small forest, write
 its JSON, convert, then digest the source forest and the mmap-reloaded

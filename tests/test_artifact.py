@@ -4,8 +4,8 @@ Round-trip bit-identity (every IR array, dtype and value, including the
 degenerate forests and a multi-word-bitvector chain), loud refusal of
 newer-major artifacts (mirroring the trees/io schema gating), mmap read-only
 safety, the packed-leaf group/dictionary codec's exactness at its edges,
-registry retention + hot-swap page reuse, tune-DB persistence across
-process-like reloads, the worker HELLO artifact-bytes fast path, and the
+registry retention + hot-swap page reuse, older files carrying the retired
+tune-DB section, the worker HELLO artifact-bytes fast path, and the
 converter CLI.
 """
 import gc
@@ -20,13 +20,10 @@ from repro.ir import ForestIR
 from repro.ir.artifact import (
     FLAG_FLOAT,
     FLAG_PACKED_LEAVES,
-    FLAG_TUNED,
     ITRF_VERSION,
-    host_isa_key,
     inspect_itrf,
     read_itrf,
     read_itrf_bytes,
-    update_tuned,
     write_itrf,
 )
 from repro.ir.packed_leaf import (
@@ -395,40 +392,55 @@ def test_gateway_prunes_closed_engines(artifact_path, shuttle_small):
     asyncio.run(gw.close())
 
 
-# --------------------------------------------------------- tune-db sidecar
+# --------------------------------------------------- retired tune-db section
 
-def test_tune_db_persists_and_foreign_hosts_ignore(trained_ir, tmp_path):
+def _with_retired_tune_db(src, dst):
+    """``src`` rewritten as older writers left it after recording host-clock
+    tuning winners: every section verbatim plus a JSON ``tune_db`` section,
+    and flag bit 4 set."""
+    from repro.ir import artifact as art
+
+    with open(src, "rb") as fh:
+        buf = fh.read()
+    head = art._parse_header(buf)
+    table = art._parse_sections(buf, head["n_sections"])
+    db = {"x86_64+avx2": {"pallas|leaf_major|integer":
+                          {"block_b": 128, "block_t": 8}}}
+    sections = [(name, art._section_array(buf, entry, copy=False))
+                for name, entry in table.items()]
+    sections.append(("tune_db", np.frombuffer(json.dumps(db).encode(),
+                                              np.uint8)))
+    vmaj, vmin = head["version"]
+    art._write_raw(dst, (vmaj, vmin, head["flags"] | 4, head["n_trees"],
+                         head["n_classes"], head["n_features"],
+                         head["total_nodes"], int(head["quant_scale"] or 0)),
+                   sections)
+
+
+def test_retired_tune_db_section_loads_and_serves_bit_identically(
+        artifact_path, tmp_path, shuttle_small):
     from repro.serve.registry import ModelRegistry
 
-    path = tmp_path / "tuned.itrf"
-    winners = {("native_c_table", None, "integer"): {"block_rows": 8}}
-    trained_ir.to_itrf(str(path), tuned=winners)
-    info = inspect_itrf(str(path))
-    assert info["flags"] & FLAG_TUNED
-    assert info["tuned_hosts"] == [host_isa_key()]
-    # this host's entry seeds the version's tuned cache on load
-    mv = ModelRegistry().register_artifact("m", str(path))
-    assert mv._tuned == winners
-    # a foreign host's winners are carried but never applied here
-    update_tuned(str(path), {("bitvector", None, "flint"): {"interleave": 4}},
-                 host_key="riscv64+vext")
-    assert sorted(inspect_itrf(str(path))["tuned_hosts"]) == \
-           sorted([host_isa_key(), "riscv64+vext"])
-    mv2 = ModelRegistry().register_artifact("m", str(path))
-    assert mv2._tuned == winners  # unchanged: foreign flags, host re-tunes
-
-
-def test_export_tuned_round_trips_through_registry(artifact_path):
-    from repro.serve.registry import ModelRegistry
-
+    _, _, Xte, _ = shuttle_small
+    rows = Xte[:70]  # one batch on the scan, and the 6-row tail gathers
+    old = str(tmp_path / "old.itrf")
+    _with_retired_tune_db(artifact_path, old)
+    info = inspect_itrf(old)
+    assert info["flags"] & 4 and "tune_db" in info["sections"]
     reg = ModelRegistry()
-    mv = reg.register_artifact("m", artifact_path)
-    mv._tuned[("native_c_bitvector", None, "integer")] = {"interleave": 8}
-    reg.export_tuned("m", artifact_path)
-    # a "fresh process": a new registry mapping the updated file starts warm
-    mv2 = ModelRegistry().register_artifact("m", artifact_path)
-    assert mv2._tuned == {("native_c_bitvector", None, "integer"):
-                          {"interleave": 8}}
+    got, want = [], []
+    for mid, path, out in (("old", old, got), ("new", artifact_path, want)):
+        mv = reg.register_artifact(mid, path)
+        eng = mv.engine("integer:pallas@leaf_major")
+        # the recorded winners are not applied: the default tiling serves
+        assert eng.backend._tiling["block_b"] == 256
+        assert eng.backend._tiling["block_t"] is None
+        for part in (rows[:64], rows[64:]):
+            out.append(eng.predict_scores(part))
+    for (s_got, p_got), (s_want, p_want) in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(s_got), np.asarray(s_want))
+        np.testing.assert_array_equal(np.asarray(p_got), np.asarray(p_want))
+    assert reg.get("old").packed.itrf_flags & 4
 
 
 # ------------------------------------------------- worker HELLO fast path

@@ -354,6 +354,48 @@ def test_pallas_auto_falls_back_to_gather_on_unscannable_order():
         create_backend("pallas", lm, mode="integer", impl="leaf_major")
 
 
+# case -> (the forest's artifact, the batch's rows, the walk it takes)
+_WALK_RULE = {
+    "63_rows_gather": ("leaf_major", 63, "gather"),
+    "64_rows_scan": ("leaf_major", 64, "leaf_major"),
+    "too_large_for_a_cell_scan": ("deep", 1, "leaf_major"),
+    "padded_gather": ("padded", 256, "gather"),
+    "unscannable_gather": ("unscannable", 256, "gather"),
+}
+
+
+@pytest.mark.parametrize("case", list(_WALK_RULE))
+def test_pallas_walk_rule(case, request, small_packed, monkeypatch):
+    """The backend's one walk rule with ``impl="auto"``: the scan on a
+    scannable leaf_major layout, gather on padded or where the node order
+    cannot be scanned, gather below 64 rows where whole trees fit.  The walk
+    asked of ``walk`` is the one the kernel's call is launched with."""
+    import jax.numpy as jnp
+
+    import repro.kernels.ops as ops
+
+    art, rows, want = _WALK_RULE[case]
+    if art == "deep":
+        request.getfixturevalue("small_smem")
+        art = request.getfixturevalue("deep")[1].materialize("leaf_major")
+    elif art == "unscannable":
+        art = ForestIR.from_forest(
+            _child_before_parent_forest()).materialize("leaf_major")
+    else:
+        art = small_packed.to_ir().materialize(art)
+    launched = []
+
+    def stub(x, *tables, impl, **kw):  # the choice is checked, not the kernel
+        launched.append(impl)
+        return jnp.zeros((x.shape[0], tables[4].shape[-1]), jnp.uint32)
+
+    monkeypatch.setattr(ops, "_traverse", stub)
+    backend = create_backend("pallas", art, mode="integer")
+    assert backend.walk(rows) == want
+    backend.predict_partials(np.zeros((rows, art.n_features), np.float32))
+    assert launched == [want]
+
+
 def test_pallas_places_its_tables_once(random_case, monkeypatch):
     """The serving backend puts its node tables on the device on its first
     batch and walks that one copy after, through the scan and the
@@ -366,9 +408,9 @@ def test_pallas_places_its_tables_once(random_case, monkeypatch):
     eng = TreeEngine(packed.to_ir(), "integer:pallas@leaf_major")
     walked, real = [], ops.packed_partials
 
-    def spy(packed, X, **kw):
-        walked.append((kw["impl"], kw["tables"]))
-        return real(packed, X, **kw)
+    def spy(packed, X, *, impl, tables, **kw):
+        walked.append((impl, tables))
+        return real(packed, X, impl=impl, tables=tables, **kw)
 
     monkeypatch.setattr(ops, "packed_partials", spy)
     for n in (3, 97, 17, 40):  # buckets 4, 128, 32, 64: gather, scan, gather, scan

@@ -7,7 +7,8 @@ import pytest
 from repro.configs.base import smoke_config
 from repro.data.tokens import TokenPipeline, pipeline_for
 from repro.models import transformer as tfm
-from repro.serve.engine import LMEngine, TreeEngine
+from repro.serve.engine import TreeEngine
+from repro.serve.lm_engine import LMEngine
 
 
 def test_pipeline_deterministic_across_restarts():

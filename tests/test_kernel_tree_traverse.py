@@ -17,7 +17,7 @@ from repro.core.packing import pack_forest
 from repro.kernels import ops
 from repro.kernels.ops import (
     _VMEM_BUDGET_BYTES, _align_block_t, _block_words, _fits, _smem_words,
-    packed_predict_integer, pick_blocks, tree_predict_integer,
+    pick_blocks, tree_predict_integer,
 )
 from repro.kernels.ref import tree_predict_integer_ref
 from repro.trees.forest import RandomForestClassifier
@@ -87,13 +87,18 @@ def test_kernel_block_shapes_property(bb, bt, rows):
 
 
 def test_packed_entry_point(small_packed, shuttle_small):
+    """Float rows over placed padded tables, the walk given: the partials
+    and their first-largest classes equal the integer reference."""
     from repro.core.ensemble import predict_integer
 
     _, _, Xte, _ = shuttle_small
     acc_ref, pred_ref = predict_integer(small_packed, Xte[:200])
-    acc_k, pred_k = packed_predict_integer(small_packed, Xte[:200], block_b=32)
+    acc_k = ops.packed_partials(small_packed, Xte[:200], impl="gather",
+                                tables=ops.place_tables(small_packed),
+                                block_b=32)
     np.testing.assert_array_equal(np.asarray(acc_k), np.asarray(acc_ref))
-    np.testing.assert_array_equal(np.asarray(pred_k), np.asarray(pred_ref))
+    np.testing.assert_array_equal(np.argmax(np.asarray(acc_k), axis=1),
+                                  np.asarray(pred_ref))
 
 
 def _aligned(bb, bt, t):
@@ -175,21 +180,24 @@ def test_leaf_major_impl_requires_internal_counts():
 
 
 def test_packed_entry_point_auto_impl(small_packed, shuttle_small):
-    """``impl="auto"`` resolves per layout and stays bit-identical; pinning
-    ``impl="leaf_major"`` on a padded artifact re-materializes via the IR."""
+    """The serving backend resolves ``impl="auto"`` per layout — the scan on
+    leaf_major, gather on padded — and stays bit-identical; a scan pinned
+    on a padded backend is refused."""
+    from repro.backends import create_backend
     from repro.core.ensemble import predict_integer
 
     _, _, Xte, _ = shuttle_small
-    acc_ref, pred_ref = predict_integer(small_packed, Xte[:150])
+    acc_ref, _ = predict_integer(small_packed, Xte[:150])
     lm = small_packed.to_ir().materialize("leaf_major")
-    for packed, kw in (
-        (lm, {}),                            # auto on leaf_major -> scan
-        (small_packed, {}),                  # auto on padded -> gather
-        (small_packed, {"impl": "leaf_major"}),  # pinned: re-materializes
-    ):
-        acc, pred = packed_predict_integer(packed, Xte[:150], block_b=32, **kw)
-        np.testing.assert_array_equal(np.asarray(acc), np.asarray(acc_ref))
-        np.testing.assert_array_equal(np.asarray(pred), np.asarray(pred_ref))
+    for packed, walk in ((lm, "leaf_major"), (small_packed, "gather")):
+        backend = create_backend("pallas", packed, mode="integer",
+                                 block_b=32)
+        assert backend.impl == backend.walk(150) == walk
+        np.testing.assert_array_equal(backend.predict_partials(Xte[:150]),
+                                      np.asarray(acc_ref))
+    with pytest.raises(ValueError, match="'padded' layout"):
+        create_backend("pallas", small_packed, mode="integer",
+                       impl="leaf_major")
 
 
 # FlInt's edge cases: both zeros (one key), infinities, subnormals of both
@@ -290,6 +298,7 @@ def test_fused_path_traces_the_key_transform_once(small_packed, shuttle_small,
         float_to_key(jnp.asarray(Xte[:37])), *_args(small_packed),
         small_packed.max_depth)
     for _ in range(2):
-        got = ops.packed_partials(lm, Xte[:37], tables=tables)
+        got = ops.packed_partials(lm, Xte[:37], impl="leaf_major",
+                                  tables=tables)
         np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     assert seen == [True]

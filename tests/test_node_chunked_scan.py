@@ -28,9 +28,9 @@ def walks(monkeypatch):
     """The ``impl`` of every kernel call the Pallas backend makes."""
     seen, sound = [], ops.packed_partials
 
-    def spy(packed, X, impl="auto", **kw):
+    def spy(packed, X, *, impl, tables, **kw):
         seen.append(impl)
-        return sound(packed, X, impl=impl, **kw)
+        return sound(packed, X, impl=impl, tables=tables, **kw)
 
     monkeypatch.setattr(ops, "packed_partials", spy)
     return seen
@@ -195,21 +195,17 @@ def tilings(monkeypatch):
 
 
 def test_pinned_tree_blocks_serve_every_warm_bucket(esa_forest, tilings):
-    """The autotuner pins (block_b, block_t) alone.  On intreeger-rf's shape
-    it offers only whole-tree candidates, as before the node axis was cut,
-    and a block_t pinned by hand past what gather can hold (64 trees of 512
-    nodes need 1 MiB of SMEM) keeps small batches on the scan: every bucket
-    the warm-up compiles, 1 to 256 rows, finds a tiling."""
-    from repro.serve import autotune as at
-
+    """On intreeger-rf's shape the budgets' own tiling holds whole trees at
+    every warm bucket, as before the node axis was cut, and a block_t
+    pinned by hand past what gather can hold (64 trees of 512 nodes need
+    1 MiB of SMEM) keeps small batches on the scan: every bucket the warm-up
+    compiles, 1 to 256 rows, finds a tiling."""
     t, n, f, c = _shape(esa_forest)
     assert not ops.holds_whole_trees(t, n, f, c, 64)
     npad = -(-n // 128) * 128
-    grid = at.candidate_grid("pallas", esa_forest)
-    for cand in ops.pick_blocks_candidates(at._TUNE_ROWS, t, n, f, c,
-                                           chunk_nodes=True):
-        assert cand[2] == npad, cand
-    for pinned in grid + [{"block_b": 256, "block_t": 64}]:
+    for rows in (1, 2, 4, 8, 16, 32, 64, 128, 256):
+        assert ops.pick_blocks(rows, t, n, f, c, chunk_nodes=True)[2] == npad
+    for pinned in ({}, {"block_b": 256, "block_t": 64}):
         backend = create_backend("pallas", esa_forest, mode="integer", **pinned)
         tilings.clear()
         for rows in (1, 2, 4, 8, 16, 32, 64, 128, 256):

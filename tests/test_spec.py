@@ -19,7 +19,7 @@ from repro.serve.spec import MODES, EngineSpec
     "integer:native_c_table?block_rows=8",
     "integer:reference+auto:3",
     "integer:reference|native_c+tree_parallel:2",
-    "integer:reference?autotune=true",
+    "integer:pallas@leaf_major",
 ])
 def test_parse_canonical_roundtrip(text):
     spec = EngineSpec.parse(text, validate=False)
@@ -57,12 +57,33 @@ def test_auto_shards_renders_auto():
     assert EngineSpec.parse(s.canonical(), validate=False) == s
 
 
-def test_query_literals_and_autotune():
+def test_query_literals():
     s = EngineSpec.parse(
-        "integer:native_c_table?block_rows=8,impl=jit,scale=0.5,autotune=true",
+        "integer:native_c_table?block_rows=8,impl=jit,scale=0.5",
         validate=False)
     assert s.backend_kwargs == {"block_rows": 8, "impl": "jit", "scale": 0.5}
-    assert s.autotune is True
+
+
+def test_retired_autotune_key_is_refused_in_the_string_form():
+    """A spec string still naming ``autotune`` fails with the key named and
+    never hands it to a backend's constructor as a kwarg."""
+    for text in ("integer:pallas@leaf_major?autotune=true",
+                 "integer:native_c_table?block_rows=8,autotune=1"):
+        with pytest.raises(ValueError, match="autotune"):
+            EngineSpec.parse(text, validate=False)
+
+
+def test_retired_autotune_key_is_refused_in_dict_and_keyword_forms():
+    with pytest.raises(ValueError, match="autotune"):
+        EngineSpec.from_dict({"mode": "integer", "backend": "pallas",
+                              "autotune": True})
+    with pytest.raises(ValueError, match="autotune"):
+        EngineSpec.coerce({"backend": "pallas", "autotune": False})
+    with pytest.raises(ValueError, match="autotune"):
+        EngineSpec.coerce(None, caller="test", backend="pallas",
+                          autotune=True)
+    with pytest.raises(ValueError, match="autotune"):
+        EngineSpec(backend="pallas", backend_kwargs={"autotune": True})
 
 
 def test_parse_errors():
